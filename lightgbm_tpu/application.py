@@ -353,6 +353,8 @@ def run(argv: Optional[List[str]] = None) -> int:
 
 
 def main() -> None:
+    from .utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(run())
 
 

@@ -10,6 +10,7 @@ toolchain degrades gracefully.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -25,20 +26,32 @@ _LIB_FAILED = False
 
 def compile_and_load(src_name: str, so_name: str) -> ctypes.CDLL:
     """Compile a C++ source in this directory into a cached shared
-    object (rebuilt when the source is newer) and dlopen it. Shared by
-    every native component; raises on a missing/broken toolchain (each
-    caller decides how to degrade). The .tmp rename keeps a concurrent
-    builder in another process from dlopening a half-written file."""
+    object and dlopen it. The object is rebuilt whenever it is absent
+    or the source hash recorded beside it (``<so>.sha256``) differs
+    from the committed source's — mtimes say nothing in a copied tree.
+    Shared by every native component; raises on a missing/broken
+    toolchain (each caller decides how to degrade). The .tmp renames
+    keep a concurrent builder in another process from dlopening a
+    half-written file."""
     src = os.path.join(_HERE, src_name)
     so = os.path.join(_HERE, so_name)
-    if (not os.path.exists(so)
-            or os.path.getmtime(so) < os.path.getmtime(src)):
+    stamp = so + ".sha256"
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()
+    built = None
+    if os.path.exists(so) and os.path.exists(stamp):
+        with open(stamp) as f:
+            built = f.read().strip()
+    if built != digest:
         tmp = so + ".%d.tmp" % os.getpid()
         subprocess.check_call(
             ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
              "-o", tmp, src],
             stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
         os.replace(tmp, so)
+        with open(tmp, "w") as f:
+            f.write(digest + "\n")
+        os.replace(tmp, stamp)
     return ctypes.CDLL(so)
 
 
@@ -79,6 +92,12 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
             log.warning("native parser unavailable (g++ build failed); "
                         "falling back to numpy text parsing")
         return _LIB
+
+
+def available() -> bool:
+    """True when the native library built and loaded (binning and text
+    parsing then take the C++ paths, else their Python fallbacks)."""
+    return _build_and_load() is not None
 
 
 def parse_dense(path: str, delim: str, skip_rows: int

@@ -1,6 +1,6 @@
 """Backend health events + SLO watchdogs.
 
-Round-5 evidence (BENCH_r05.json) motivated this module: a silent CPU
+A round-5 bench artifact motivated this module: a silent CPU
 fallback — "tpu backend probe failed/timed out (3 attempts)" — whose
 only trace was a substring in a free-text unit field. Backend state is
 now a first-class, machine-readable event:

@@ -22,19 +22,29 @@ docs/GPU-Performance.rst precedent).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Rows per one-hot tile. VMEM footprint of the one-hot is
 # ROW_TILE * F * B * 4 bytes per scan step; XLA additionally tiles the
 # contraction, so this just bounds the scan carry granularity.
 DEFAULT_ROW_TILE = 512
 
-# Rows per Pallas grid step (the kernel's VMEM working set scales with
-# this; 2048 rows × 28 features ≈ 1.2 MB of transients).
+# Rows per Pallas grid step for f32 gh rows.
 PALLAS_ROW_TILE = 2048
+
+# int8 gh rows: 1-byte blocks and one-hot factors let 4x the rows ride
+# each grid step
+PALLAS_ROW_TILE_INT = 4 * PALLAS_ROW_TILE
+
+# The scoped-VMEM limit pallas_call hands the compiler
+# (CompilerParams.vmem_limit_bytes) and _pallas_fits budgets against.
+# 32 MiB is above Mosaic's 16 MiB default scope and a quarter of a v5e
+# core's 128 MiB.
+PALLAS_VMEM_LIMIT = 32 * 1024 * 1024
 
 
 def resolve_hist_impl(backend: str = "auto",
@@ -72,24 +82,64 @@ def resolve_hist_impl(backend: str = "auto",
     return backend, bool(f64), quant_bits
 
 
-# VMEM budget for the Pallas kernel's resident blocks (accumulator +
-# row tile + transients). Real cores have ~128 MiB; stay well under so
-# Mosaic's own spills/copies fit too.
-PALLAS_VMEM_BUDGET = 64 * 1024 * 1024
+def _ceil_to(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _sublanes(itemsize: int) -> int:
+    """Rows of one (sublane, 128-lane) tile: 8 of f32, 32 of int8."""
+    return 8 * (4 // itemsize)
+
+
+def _hi_rows(num_bins: int, itemsize: int) -> int:
+    """Rows of the hi-nibble one-hot (``bin >> 4``), padded to the
+    sublane tile of the gh dtype so the matmul's M dimension is
+    tile-aligned; the pad rows match no bin."""
+    return _ceil_to(-(-num_bins // 16), _sublanes(itemsize))
+
+
+def _pallas_row_tile(gh_dtype) -> int:
+    return (PALLAS_ROW_TILE_INT
+            if jnp.issubdtype(jnp.dtype(gh_dtype), jnp.integer)
+            else PALLAS_ROW_TILE)
+
+
+def _pallas_vmem_bytes(F: int, num_bins: int, C: int, T: int,
+                       gh_itemsize: int, bins_itemsize: int = 1) -> int:
+    """Upper bound on the VMEM the compiled kernel holds at once,
+    counted the way Mosaic lays arrays out: a [rows, T] array pads its
+    rows to the dtype's sublane tile, a minor dimension pads to 128
+    lanes, and every BlockSpec'd operand is double-buffered. Transients
+    are counted as if each were materialized whole (the compiler keeps
+    most of them in vregs, so the real need is lower — an AOT bisection
+    of ``vmem_limit_bytes`` at the Higgs shape found 576 KiB for f32
+    and 1.8 MiB for int8 against this bound's 3.3 / 11.4 MiB)."""
+    H = _hi_rows(num_bins, gh_itemsize)
+
+    def rows_by_T(rows: int, itemsize: int) -> int:
+        return _ceil_to(rows, _sublanes(itemsize)) * T * itemsize
+
+    blocks = 2 * (rows_by_T(F, bins_itemsize) + rows_by_T(C, gh_itemsize))
+    acc = 2 * F * H * _ceil_to(16 * C, 128) * 4
+    scratch = rows_by_T(F, 4)                    # int32 copy of the bins
+    # per feature: the bin row and its nibbles, both int32 iotas and
+    # compare masks, the hi one-hot, W's C selected pieces and their
+    # concatenation at 4 bytes, and the matmul's W operand
+    trans = (3 * rows_by_T(1, 4)
+             + 2 * (rows_by_T(H, 4) + rows_by_T(16, 4))
+             + rows_by_T(H, gh_itemsize)
+             + 2 * rows_by_T(16 * C, 4)
+             + rows_by_T(16 * C, gh_itemsize))
+    return blocks + acc + scratch + trans
 
 
 def _pallas_fits(F: int, num_bins: int, C: int,
-                 T: int = PALLAS_ROW_TILE, itemsize: int = 4) -> bool:
-    """Static VMEM bound for the kernel's working set: the [F*H, 16*C]
-    accumulator (always 4-byte f32/int32) stays resident across the
-    grid, plus the per-step row tile and its one-hot/replicated
-    transients at the input itemsize (1 byte in int8 mode — which is
-    what lets the quantized kernel run a 4x wider row tile)."""
-    H = -(-num_bins // 16)
-    acc = F * H * 16 * C * 4
-    tile = T * F * itemsize + T * C * itemsize   # bins + gh blocks
-    trans = T * 16 * C * itemsize * 2 + T * H * itemsize  # g_rep, W, A
-    return acc + tile + trans <= PALLAS_VMEM_BUDGET
+                 T: int = PALLAS_ROW_TILE, itemsize: int = 4,
+                 bins_itemsize: int = 1) -> bool:
+    """Static gate: the kernel's VMEM bound stays under the limit
+    pallas_call passes the compiler."""
+    return (_pallas_vmem_bytes(F, num_bins, C, T, itemsize,
+                               bins_itemsize) <= PALLAS_VMEM_LIMIT)
 
 
 def _warn_once(msg: str, component: str = "ops.histogram") -> None:
@@ -132,37 +182,6 @@ from ..obs import compile as obs_compile  # noqa: E402
 from ..obs.registry import add_reset_hook  # noqa: E402
 
 add_reset_hook(_reset_warn_once)
-
-
-@functools.lru_cache(maxsize=1)
-def _use_pallas() -> bool:
-    """Pallas path only on real TPU backends; the einsum-scan fallback
-    serves CPU tests and interpret-mode debugging. A tiny probe kernel
-    runs once per process so a Mosaic compile/runtime failure degrades
-    to the fallback instead of killing training."""
-    if os.environ.get("LGBM_TPU_NO_PALLAS"):
-        return False
-    try:
-        if jax.default_backend() != "tpu" or _pl is None:
-            return False
-        probe = _pallas_histogram(
-            jnp.zeros((PALLAS_ROW_TILE, 2), dtype=jnp.uint8),
-            jnp.ones((PALLAS_ROW_TILE, 4), dtype=jnp.float32),
-            16, PALLAS_ROW_TILE)
-        # jaxlint: disable=JLT001 -- one-shot backend-selection probe
-        # (lru_cached once per process), not a training hot path
-        ok = float(jax.device_get(probe)[0, 0, 3]) == float(
-            PALLAS_ROW_TILE)
-        if not ok:
-            from ..utils import log
-            log.warning("Pallas histogram probe produced wrong sums; "
-                        "using the einsum fallback")
-        return ok
-    except Exception as e:  # pragma: no cover - depends on runtime
-        from ..utils import log
-        log.warning("Pallas histogram unavailable (%s); using the "
-                    "einsum fallback" % type(e).__name__)
-        return False
 
 
 def _acc_dtype_of(gh_dtype):
@@ -220,101 +239,130 @@ def _tile_histogram(bins_tile: jnp.ndarray, gh_tile: jnp.ndarray,
         preferred_element_type=acc_dtype)
 
 
-def _hist_kernel_body(T: int, F: int, H: int, C: int, bins_ref, gh_ref,
-                      out_ref):
-    """Pallas TPU kernel: one grid step accumulates a [T, F] row tile
-    into the [F*H, 16*C] VMEM-resident histogram accumulator.
+def _hist_kernel_body(F: int, H: int, C: int, bins_ref, gh_ref, out_ref,
+                      bins32_ref):
+    """Pallas TPU kernel: one grid step accumulates a feature-major
+    [F, T] row tile into the [F, H, 16*C] VMEM-resident accumulator.
 
     The bin index factorizes as ``bin = hi*16 + lo``; per feature the
-    contribution is ``A_f^T @ W_f`` where ``A_f[t, hi]`` is the hi-nibble
-    one-hot and ``W_f[t, lo*C+c] = (lo_f[t] == lo) * gh[t, c]``. This
-    shapes the MXU matmul as [H, T] x [T, 16*C] — N = 16*C lanes instead
-    of the naive one-hot's N = C, and the one-hot factors never leave
-    VMEM (the einsum fallback materializes S*F*B floats through HBM).
-    Equivalent of the reference's shared-memory histogram kernels
-    (cuda_histogram_constructor.cu:18, ocl/histogram256.cl).
+    contribution is ``A_f @ W_f^T`` where ``A_f[hi, t]`` is the
+    hi-nibble one-hot and ``W_f[c*16+lo, t] = (lo_f[t] == lo) *
+    gh[c, t]``. Rows ride the lanes of both operands, so the MXU
+    contracts over lanes ([H, T] x [16*C, T]^T, the q.k^T form) with
+    N = 16*C output lanes instead of the naive one-hot's N = C, and
+    the one-hot factors never leave VMEM (the einsum path materializes
+    S*F*B floats through HBM). Equivalent of the reference's
+    shared-memory histogram kernels (cuda_histogram_constructor.cu:18,
+    ocl/histogram256.cl).
+
+    Feature ``f`` is a LEADING-axis index into refs (``bins32_ref[f]``
+    row, ``out_ref[f]`` slab), so the loop stays a ``fori_loop`` whose
+    code size does not grow with F. Mosaic cannot take a dynamic
+    sublane offset into a packed (1- or 2-byte) ref, hence the int32
+    copy of the bin tile in scratch.
 
     The body is dtype-generic: quantized int8 gh rows contract as
-    int8 x int8 MXU matmuls into an int32 accumulator (the one-hot
-    factors and transients ride the 1-byte row dtype, which is what
-    lets the quantized caller run the 4x wider PALLAS_ROW_TILE_INT in
-    the same VMEM budget); f32 rows keep the f32 accumulator."""
-    @_pl.when(_pl.program_id(0) == 0)
+    int8 x int8 MXU matmuls into an int32 accumulator; f32 rows
+    contract at fp32 precision into f32. The int8 ``where`` is taken
+    in int32 and narrowed after — Mosaic cannot move an int32 compare
+    mask onto int8's (32, 128) tiling."""
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    b = bins_ref[...].astype(jnp.int32)          # [T, F]
-    g = gh_ref[...]                              # [T, C]
-    hi = b >> 4
-    lo = b & 15
-    g_rep = jnp.tile(g, (1, 16))                 # [T, 16*C]
-    lane_lo = (jax.lax.broadcasted_iota(jnp.int32, (1, 16 * C), 1)
-               // C)                             # [1, 16*C]
-    iota_h = jax.lax.broadcasted_iota(jnp.int32, (1, H), 1)
-    zero = jnp.zeros((), dtype=g.dtype)
-    acc_t = (jnp.int32 if jnp.issubdtype(g.dtype, jnp.integer)
-             else jnp.float32)
+    T = bins_ref.shape[1]
+    bins32_ref[...] = bins_ref[...].astype(jnp.int32)
+    g = gh_ref[...]                                      # [C, T]
+    quantized = jnp.issubdtype(g.dtype, jnp.integer)
+    g_sel = g.astype(jnp.int32) if quantized else g
+    zero = jnp.zeros((), dtype=g_sel.dtype)
+    iota_hi = jax.lax.broadcasted_iota(jnp.int32, (H, T), 0)
+    iota_lo = jax.lax.broadcasted_iota(jnp.int32, (16, T), 0)
 
     def body(f, carry):
-        hi_f = jax.lax.dynamic_slice(hi, (0, f), (T, 1))     # [T, 1]
-        lo_f = jax.lax.dynamic_slice(lo, (0, f), (T, 1))
-        A = (hi_f == iota_h).astype(g.dtype)                 # [T, H]
-        W = jnp.where(lo_f == lane_lo, g_rep, zero)          # [T, 16C]
-        acc = jax.lax.dot_general(
-            A, W, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=acc_t)                    # [H, 16C]
-        out_ref[_pl.ds(f * H, H), :] += acc
+        row = bins32_ref[pl.ds(f, 1), :]                 # [1, T]
+        A = ((row >> 4) == iota_hi).astype(g.dtype)      # [H, T]
+        lo_hit = (row & 15) == iota_lo                   # [16, T]
+        W = jnp.concatenate(
+            [jnp.where(lo_hit, g_sel[c:c + 1, :], zero)
+             for c in range(C)], axis=0).astype(g.dtype)  # [16C, T]
+        out_ref[f] += jax.lax.dot_general(
+            A, W, dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=(None if quantized
+                       else jax.lax.Precision.HIGHEST),
+            preferred_element_type=out_ref.dtype)        # [H, 16C]
         return carry
 
     jax.lax.fori_loop(0, F, body, 0)
 
 
-try:  # Pallas is TPU-only machinery; import lazily-tolerantly
-    from jax.experimental import pallas as _pl
-    from jax.experimental.pallas import tpu as _pltpu
-except Exception:  # pragma: no cover
-    _pl = None
-    _pltpu = None
-
-
-# int8 rows: 1-byte tiles/transients let 4x the rows sit in the same
-# VMEM working set as the f32 kernel's PALLAS_ROW_TILE
-PALLAS_ROW_TILE_INT = 4 * PALLAS_ROW_TILE
-
-
 def _pallas_histogram_body(bins: jnp.ndarray, gh: jnp.ndarray,
-                           num_bins: int, row_tile: int) -> jnp.ndarray:
+                           num_bins: int, row_tile: int,
+                           interpret: bool = False) -> jnp.ndarray:
+    """[S, F] bins x [S, C] gh -> [F, B, C] through the Pallas kernel.
+    ``interpret`` exists for the CPU parity test only: the jitted
+    product entry ``_pallas_histogram`` does not expose it."""
     S, F = bins.shape
     C = gh.shape[1]
-    H = -(-num_bins // 16)                       # hi-nibble width
+    H = _hi_rows(num_bins, gh.dtype.itemsize)
     T = row_tile
     pad = (-S) % T
     if pad:
         bins = jnp.concatenate(
             [bins, jnp.zeros((pad, F), dtype=bins.dtype)])
         gh = jnp.concatenate([gh, jnp.zeros((pad, C), dtype=gh.dtype)])
-    n_tiles = bins.shape[0] // T
     quantized = jnp.issubdtype(gh.dtype, jnp.integer)
     out_dtype = jnp.int32 if quantized else jnp.float32
-    kernel = functools.partial(_hist_kernel_body, T, F, H, C)
-    out = _pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
+    out = pl.pallas_call(
+        functools.partial(_hist_kernel_body, F, H, C),
+        grid=(bins.shape[0] // T,),
         in_specs=[
-            _pl.BlockSpec((T, F), lambda i: (i, 0)),
-            _pl.BlockSpec((T, C), lambda i: (i, 0)),
+            pl.BlockSpec((F, T), lambda i: (0, i)),
+            pl.BlockSpec((C, T), lambda i: (0, i)),
         ],
-        out_specs=_pl.BlockSpec((F * H, 16 * C), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((F * H, 16 * C), out_dtype),
-    )(bins, gh)
-    # [F*H, 16*C] -> [F, H*16, C] -> [F, B, C]
-    hist = out.reshape(F, H, 16, C).reshape(F, H * 16, C)
-    return hist[:, :num_bins, :]
+        out_specs=pl.BlockSpec((F, H, 16 * C), lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((F, H, 16 * C), out_dtype),
+        scratch_shapes=[pltpu.VMEM((F, T), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=PALLAS_VMEM_LIMIT),
+        interpret=interpret,
+    )(bins.T, gh.T)
+    # [F, H, C*16] -> [F, H*16, C] -> [F, B, C]
+    hist = out.reshape(F, H, C, 16).transpose(0, 1, 3, 2)
+    return hist.reshape(F, H * 16, C)[:, :num_bins, :]
 
 
 _pallas_histogram = obs_compile.instrument_jit(
     "ops.pallas_histogram", _pallas_histogram_body,
     static_argnums=(2, 3))
+
+
+def _pallas_excluded(S: int, F: int, num_bins: int, C: int, gh_dtype,
+                     bins_itemsize: int, pallas_ok: bool, f64: bool,
+                     backend: str):
+    """Why the static gate keeps this call off the Pallas kernel, or
+    None when it admits it — a pure function of the caller's mesh
+    (``pallas_ok``), the configured backend, dtypes and shapes. The
+    int16 quantized mode's int64 accumulator has no kernel variant."""
+    gh_dtype = jnp.dtype(gh_dtype)
+    T = _pallas_row_tile(gh_dtype)
+    if backend in ("onehot", "scatter"):
+        return "hist_backend=%s" % backend
+    if not pallas_ok:
+        return "sharded-mesh caller"
+    if f64:
+        return "f64 histograms"
+    if jnp.issubdtype(gh_dtype, jnp.integer) and gh_dtype != jnp.int8:
+        return "int16 quantized rows (int64 accumulation)"
+    if S < T:
+        return "S=%d < %d row tile" % (S, T)
+    if C > 8:
+        return "C=%d > 8 stat columns" % C
+    if not _pallas_fits(F, num_bins, C, T, gh_dtype.itemsize,
+                        bins_itemsize):
+        return "VMEM bound (F=%d B=%d)" % (F, num_bins)
+    return None
 
 
 def build_histogram(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
@@ -349,41 +397,21 @@ def build_histogram(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
     quantized = jnp.issubdtype(jnp.dtype(gh.dtype), jnp.integer)
     if quantized:
         f64 = False
-    # quantized Pallas: int8 rows only (the int16 mode's int64
-    # accumulator has no kernel variant; it takes the einsum path)
-    p_tile = PALLAS_ROW_TILE_INT if quantized else PALLAS_ROW_TILE
-    p_item = 1 if quantized else 4
-    want_pallas = (pallas_ok and not f64
-                   and backend not in ("onehot", "scatter")
-                   and (not quantized or gh.dtype == jnp.int8)
-                   and S >= p_tile and C <= 8
-                   and _pallas_fits(F, num_bins, C, p_tile, p_item))
-    if backend == "pallas" and not (want_pallas and _use_pallas()):
-        # Explicit request could not be honored — say why (round-3
-        # advisor: a silent downgrade skews kernel benchmarks).
-        why = ("sharded-mesh caller" if not pallas_ok else
-               "f64 histograms" if f64 else
-               "int16 quantized rows (int64 accumulation)"
-               if quantized and gh.dtype != jnp.int8 else
-               "S=%d < %d row tile" % (S, p_tile)
-               if S < p_tile else
-               "C=%d > 8 stat columns" % C if C > 8 else
-               "VMEM bound (F=%d B=%d)" % (F, num_bins)
-               if not _pallas_fits(F, num_bins, C, p_tile, p_item) else
-               "no TPU backend / probe failed")
+    excluded = _pallas_excluded(S, F, num_bins, C, gh.dtype,
+                                bins.dtype.itemsize, pallas_ok, f64,
+                                backend)
+    on_tpu = jax.default_backend() == "tpu"
+    if backend == "pallas" and (excluded or not on_tpu):
+        # Explicit request could not be honored — say why (a silent
+        # downgrade skews kernel benchmarks).
         _warn_once("hist_backend=pallas requested but unavailable here "
-                   "(%s); using the einsum path" % why)
-    if want_pallas and _use_pallas():
-        if isinstance(bins, jax.core.Tracer):
-            return _pallas_histogram(bins, gh, num_bins, p_tile)
-        try:  # concrete call: compile failures are catchable — degrade
-            return _pallas_histogram(bins, gh, num_bins, p_tile)
-        except Exception as e:  # pragma: no cover - runtime-dependent
-            _warn_once("Pallas histogram failed at shape F=%d B=%d (%s); "
-                       "einsum fallback for this and later calls"
-                       % (F, num_bins, type(e).__name__))
-            _use_pallas.cache_clear()
-            os.environ["LGBM_TPU_NO_PALLAS"] = "1"
+                   "(%s); using the einsum path"
+                   % (excluded or "no TPU backend"))
+    if on_tpu and excluded is None:
+        # chosen by shape: a kernel that does not compile is an error
+        # that reaches the user, never a silent einsum run
+        return _pallas_histogram(bins, gh, num_bins,
+                                 _pallas_row_tile(gh.dtype))
     if f64:
         gh = gh.astype(jnp.float64)
     if backend == "scatter" or (backend == "auto"

@@ -23,10 +23,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..io.dataset import BinnedDataset
@@ -116,7 +112,7 @@ class VotingParallelTreeLearner(DataParallelTreeLearner):
             vmask = jnp.zeros(F, dtype=bool).at[voted].set(True)
             return full, vmask
 
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(axis, None), P(axis, None), P(), P()),
             out_specs=(P(), P()))(bins, gh_masked, feature_mask, qscale)

@@ -73,10 +73,10 @@ def compile_predict_with_plan(fn: Callable, mesh: Any, *,
         return obs_compile.instrument_jit(
             name, fn, in_shardings=in_shardings,
             out_shardings=out_shardings, donate_argnums=donate_argnums)
-    from jax.experimental.shard_map import shard_map
+    import jax
     from jax.sharding import PartitionSpec as P
-    mapped = shard_map(fn, mesh=mesh, in_specs=P(axis),
-                       out_specs=P(axis), check_rep=False)
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=P(axis),
+                           out_specs=P(axis), check_vma=False)
     return obs_compile.instrument_jit(
         name, mapped, donate_argnums=donate_argnums)
 

@@ -67,10 +67,10 @@ Fault sites ``serve_admit`` and ``serve_dispatch`` (obs/faults.py)
 gate the two hot paths; injected faults flow through exactly the same
 shedding / breaker / rollback machinery as real ones.
 
-No TPU? The server keeps serving on whatever backend jax resolved and
-emits the existing ``backend_fallback`` health event (never silent —
-the round-5 lesson), since the stacked predictor lowers to plain XLA
-gathers that run anywhere.
+The stacked predictor lowers to plain XLA gathers that run on any
+backend; a caller that must not serve off its accelerator passes
+``require_backend="tpu"`` and the constructor raises when jax resolved
+anything else.
 """
 from __future__ import annotations
 
@@ -84,7 +84,6 @@ import numpy as np
 
 from ..obs import events as obs_events
 from ..obs import faults as obs_faults
-from ..obs import health as obs_health
 from ..obs.registry import registry as obs
 from ..utils import locktrace
 from ..utils import log
@@ -538,6 +537,12 @@ class PredictServer:
                 self.registry.load(name, booster=model)
         if overflow not in ("reject", "block"):
             raise ValueError("overflow must be 'reject' or 'block'")
+        import jax
+        if require_backend is not None \
+                and jax.default_backend() != require_backend:
+            raise RuntimeError(
+                "PredictServer(require_backend=%r): jax resolved the %r "
+                "backend" % (require_backend, jax.default_backend()))
         self.name = name
         self.max_batch = max(int(max_batch), 1)
         self.max_wait = max(float(max_wait_ms), 0.0) / 1e3
@@ -554,7 +559,6 @@ class PredictServer:
         # per device; admission (queue/shedding/deadlines), the breaker,
         # and canary accounting stay GLOBAL so overload and rollback
         # semantics are unchanged — only dispatch capacity scales
-        import jax
         devices = jax.devices()
         if replicas in ("auto", 0, None):
             replicas = len(devices)
@@ -584,13 +588,6 @@ class PredictServer:
             for k in range(self.replicas)]
         self.predictor = self.predictors[0]
         obs.gauge("serve/replicas", self.replicas)
-        if require_backend is not None:
-            actual = jax.default_backend()
-            if actual != require_backend:
-                obs_health.record_backend_fallback(
-                    "serve: %s backend unavailable, serving on %s"
-                    % (require_backend, actual),
-                    requested=require_backend, actual=actual)
         self._queue: deque = deque()
         self._pending_rows = 0
         self._cond = threading.Condition()

@@ -27,9 +27,8 @@ def create_tree_learner(config, dataset, mesh=None):
         from .sharded import ShardedTreeLearner
         return ShardedTreeLearner(config, dataset)
     if name in ("serial",):
-        # On an accelerator the serial learner's per-split host
-        # round-trips dominate (a remote chip charges ~27 ms each; 254
-        # splits/tree — measured round 3). The 1-device-mesh data
+        # On an accelerator the serial learner pays a host round-trip
+        # per split batch (254 splits/tree). The 1-device-mesh data
         # learner grows the whole tree in ONE dispatch and is pinned
         # bit-exact to serial (tests/test_parallel_learners.py), so the
         # DEFAULT promotes — an explicitly requested serial learner is

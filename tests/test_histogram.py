@@ -1,10 +1,16 @@
 """Histogram op vs np.add.at oracle (the reference's scatter-add semantics,
 src/io/dense_bin.hpp:99, reproduced exactly by the one-hot contraction)."""
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from lightgbm_tpu.ops.histogram import build_histogram, subtract_histogram
+from lightgbm_tpu.ops.histogram import (PALLAS_ROW_TILE,
+                                        PALLAS_ROW_TILE_INT,
+                                        _pallas_histogram_body,
+                                        _segment_histogram,
+                                        build_histogram,
+                                        subtract_histogram)
 
 
 def oracle(bins, gh, B):
@@ -57,3 +63,40 @@ def test_count_channel_exact():
     hist = np.asarray(build_histogram(jnp.asarray(bins), jnp.asarray(gh), B))
     assert np.all(hist[..., 2] == np.round(hist[..., 2]))
     assert hist[..., 2].sum(axis=1).max() == S
+
+
+# ----------------------------------------------------------------------
+# Pallas kernel: the CPU cannot run it, but it can (i) push it through
+# the Pallas -> Mosaic lowering for a TPU target and (ii) interpret the
+# same body — so a CPU-only change cannot break the kernel unseen
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("gh_dtype,tile", [
+    (jnp.float32, PALLAS_ROW_TILE), (jnp.int8, PALLAS_ROW_TILE_INT)])
+def test_pallas_kernel_lowers_for_tpu(gh_dtype, tile):
+    """Higgs width (F=28, B=255, C=4) at the product row tiles."""
+    bins = jax.ShapeDtypeStruct((2 * tile, 28), jnp.uint8)
+    gh = jax.ShapeDtypeStruct((2 * tile, 4), gh_dtype)
+    jax.jit(lambda b, g: _pallas_histogram_body(b, g, 255, tile)) \
+        .trace(bins, gh).lower(lowering_platforms=("tpu",))
+
+
+@pytest.mark.parametrize("S,F,B,tile", [(512, 5, 64, 256),
+                                        (1000, 3, 255, 256)])
+def test_pallas_kernel_interpreted_matches_segment_sum(S, F, B, tile):
+    """interpret=True is an argument only this test passes. int8 rows
+    must match exactly; f32 up to accumulation order. The second case
+    has a ragged row count (1000 rows in 256-row tiles)."""
+    rng = np.random.RandomState(4)
+    bins = jnp.asarray(rng.randint(0, B, size=(S, F)).astype(np.uint8))
+    gh_f = jnp.asarray(rng.randn(S, 4).astype(np.float32))
+    gh_i = jnp.asarray(
+        rng.randint(-127, 128, size=(S, 4)).astype(np.int8))
+    got_i = _pallas_histogram_body(bins, gh_i, B, tile, interpret=True)
+    assert got_i.dtype == jnp.int32
+    np.testing.assert_array_equal(
+        np.asarray(got_i), np.asarray(_segment_histogram(bins, gh_i, B)))
+    got_f = _pallas_histogram_body(bins, gh_f, B, tile, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(got_f), np.asarray(_segment_histogram(bins, gh_f, B)),
+        rtol=1e-5, atol=1e-4)

@@ -353,18 +353,15 @@ def test_model_registry_hot_swap(shared, tmp_path):
     assert swaps[0]["num_trees"] == 3 and swaps[1]["source"] == "string"
 
 
-def test_predict_server_backend_fallback_event(shared, tmp_path):
-    path = str(tmp_path / "fallback_events.jsonl")
-    events.configure(path)
+def test_predict_server_require_backend_raises(shared):
+    """A required backend that jax did not resolve is an error at
+    construction — never a server quietly running somewhere else."""
     X, bst, host = shared
+    with pytest.raises(RuntimeError, match="require_backend='tpu'"):
+        PredictServer(StackedForest.from_gbdt(bst),
+                      require_backend="tpu", autostart=False)
     srv = PredictServer(StackedForest.from_gbdt(bst),
-                        require_backend="tpu", autostart=False)
-    events.configure(None)
-    fb = [r for r in events.read_jsonl(path)
-          if r["event"] == "backend_fallback"]
-    assert fb and fb[0]["requested"] == "tpu" and fb[0]["actual"] == "cpu"
-    # degraded, not dead: the server still serves on the actual backend
-    srv.start()
+                        require_backend="cpu")
     try:
         out = srv.predict(X[0], timeout=60)
     finally:

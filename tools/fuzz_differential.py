@@ -7,7 +7,7 @@ configs x random datasets; for each case assert
 2. training quality tracks the reference's on the same data/params
    (loose bar — tie-breaking legitimately diverges).
 
-Usage: tools/cpupy.sh tools/fuzz_differential.py [n_cases] [seed] [ref_bin]
+Usage: JAX_PLATFORMS=cpu python tools/fuzz_differential.py [n_cases] [seed] [ref_bin]
 Prints one line per case; exits nonzero if any case fails.
 """
 import json

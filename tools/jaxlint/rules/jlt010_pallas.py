@@ -14,7 +14,8 @@ a wrong-dtype accumulation. This rule pins them statically:
 - **call arity**: ``pallas_call(...)(args)`` passes exactly
   ``len(in_specs)`` arrays, and a resolvable kernel function (a name
   or ``functools.partial(name, ...)``) has exactly
-  ``in_specs + outputs`` ref parameters after the partial-bound ones;
+  ``in_specs + outputs + scratch_shapes`` ref parameters after the
+  partial-bound ones;
 - **accumulator dtype**: ``dot``/``dot_general``/``einsum``/``matmul``
   inside a kernel body must pass ``preferred_element_type`` — the
   default accumulates int8×int8 into int8 and bf16×bf16 into bf16,
@@ -187,18 +188,22 @@ class PallasInvariantsRule(Rule):
                 names.add(k.id)
                 fi = ctx.project.resolve_symbol(ctx, k.id) \
                     if ctx.project else None
-                if fi is not None and in_specs:
+                scratch = _kw(call, "scratch_shapes")
+                if fi is not None and in_specs and isinstance(
+                        scratch, (type(None), ast.List, ast.Tuple)):
                     n_out = 1 if len(out_specs) <= 1 else len(out_specs)
+                    n_scratch = len(scratch.elts) if scratch else 0
                     n_refs = len(fi.params) - bound
-                    want = len(in_specs) + n_out
+                    want = len(in_specs) + n_out + n_scratch
                     if n_refs != want:
                         out.append(self.finding(
                             ctx, call,
                             "kernel %s has %d ref parameter(s) after "
                             "%d partial-bound, but this pallas_call "
-                            "supplies %d (in_specs=%d + outputs=%d)"
+                            "supplies %d (in_specs=%d + outputs=%d + "
+                            "scratch=%d)"
                             % (fi.qualname, n_refs, bound, want,
-                               len(in_specs), n_out)))
+                               len(in_specs), n_out, n_scratch)))
         return names
 
     # -- kernel bodies -------------------------------------------------

@@ -3,7 +3,7 @@
 equal iteration count; report both test AUCs and the delta.
 
 Usage:
-    tools/cpupy.sh tools/parity_run.py [rows] [iters] [ref_bin]
+    JAX_PLATFORMS=cpu python tools/parity_run.py [rows] [iters] [ref_bin]
 
 Writes a JSON line and appends a stage log to /tmp/parity_stages.log so
 a late failure keeps the evidence.
