@@ -1,0 +1,91 @@
+"""Where the benchmark's data files are, found by name.
+
+A later PR adds a configuration (``configs/<name>.json``), a traffic mix
+(``traffic/<name>.json``), a cell's limits (``limits/<cell>.json``) or a
+per-layer metric (``metrics/<name>.py``) as a new file and appends an
+entry to ``BENCHMARK.json``; nothing here names any of them.
+"""
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CHECKOUT = os.path.dirname(BENCH_DIR)
+
+
+class SpecError(Exception):
+    """``BENCHMARK.json`` or a file it names is missing or inconsistent."""
+
+
+def _load_json(path: str) -> dict:
+    try:
+        with open(path) as f:
+            return json.load(f)
+    except (OSError, ValueError) as e:
+        raise SpecError("cannot read %s: %s" % (path, e)) from e
+
+
+class Spec:
+    """``BENCHMARK.json`` of one checkout and the files under ``bench_dir``."""
+
+    def __init__(self, checkout: str = CHECKOUT, bench_dir: str = BENCH_DIR):
+        self.checkout = checkout
+        self.bench_dir = bench_dir
+        self.doc = _load_json(os.path.join(checkout, "BENCHMARK.json"))
+
+    def cell(self, name: str) -> dict:
+        """The cell's entry with its configuration and traffic mix loaded."""
+        for w in self.doc["workloads"]:
+            if w["name"] == name:
+                break
+        else:
+            raise SpecError("no workload %r in BENCHMARK.json (has: %s)" % (
+                name, ", ".join(w["name"] for w in self.doc["workloads"])))
+        for c in self.doc["configs"]:
+            if c["name"] == w["config"]:
+                break
+        else:
+            raise SpecError("workload %r names configuration %r, which "
+                            "BENCHMARK.json lacks" % (name, w["config"]))
+        return {
+            "name": name,
+            "chips": int(w["chips"]),
+            "config": _load_json(os.path.join(self.checkout, c["file"])),
+            "traffic": _load_json(os.path.join(
+                self.bench_dir, "traffic", w["traffic"] + ".json")),
+            "limits": _load_json(os.path.join(
+                self.bench_dir, "limits", name + ".json"))["limits"],
+        }
+
+    def _reports(self, metric: dict, cell: str, moves=None) -> bool:
+        if "workloads" in metric:
+            return cell in metric["workloads"]
+        return moves is None or moves in self.end_to_end(cell)
+
+    def end_to_end(self, cell: str) -> list:
+        return [m["name"] for m in self.doc["end_to_end"]
+                if self._reports(m, cell)]
+
+    def per_layer(self, cell: str) -> list:
+        return [m["name"] for m in self.doc["per_layer"]
+                if self._reports(m, cell, m["moves"])]
+
+    def unit(self, metric: str) -> str:
+        for m in self.doc["end_to_end"] + self.doc["per_layer"]:
+            if m["name"] == metric:
+                return m["unit"]
+        raise SpecError("no metric %r in BENCHMARK.json" % metric)
+
+    def reader(self, metric: str):
+        """``read(run)`` of ``metrics/<metric>.py``."""
+        path = os.path.join(self.bench_dir, "metrics", metric + ".py")
+        mod_spec = importlib.util.spec_from_file_location(
+            "benchmark_metric_" + metric.replace(".", "_").replace("-", "_"),
+            path)
+        if mod_spec is None or not os.path.exists(path):
+            raise SpecError("metric %r has no reader at %s" % (metric, path))
+        mod = importlib.util.module_from_spec(mod_spec)
+        mod_spec.loader.exec_module(mod)
+        return mod.read
