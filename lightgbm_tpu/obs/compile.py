@@ -8,8 +8,7 @@ reaching into jax internals, and surfaces unexpected retraces: a
 function that keeps re-tracing is burning compile time the device
 trace will never show. The recorded seconds cover the Python trace
 only — XLA lowering + backend compilation happen after the body
-returns, so ``trace_seconds`` is a lower bound / proxy, not the full
-compile cost (which on a remote TPU can be 100x the trace).
+returns and are read from jax's own monitoring events instead (below).
 
 :func:`instrument_jit` goes further: it owns the ``jax.jit`` call and,
 when cost capture is on (``LIGHTGBM_TPU_COMPILE_COST=1`` or an active
@@ -23,6 +22,16 @@ re-lowering hits jax's shared jaxpr cache and re-runs nothing.
 
 The per-name counters live in the metrics registry under
 ``jit_trace/<name>``; each trace also emits a ``jit_trace`` event.
+
+What a compile costs after the trace comes from ``jax.monitoring``,
+whichever module asked for the compilation and only when jax compiles
+(nothing runs per dispatch). While the stage timer is on, lowering and
+backend compilation (a read of the persistent cache included) aggregate
+as the stage totals ``jit_lower_s/<fun_name>`` and
+``jit_backend_compile_s/<fun_name>`` under jax's own name of the program
+(``jit(_tree_impl)``), each compile shows in a device trace as a
+``jit::compile <fun_name>`` range, and the persistent cache's hits and
+the entries it wrote count as ``jit_cache_hits`` / ``jit_cache_misses``.
 The learners legitimately compile several shape variants (the serial
 learner's ~log2(N) gather buckets), so the retrace warning fires only
 past ``LIGHTGBM_TPU_RETRACE_WARN`` traces of one name (default 32;
@@ -39,7 +48,7 @@ from typing import Callable, Dict
 
 from ..utils import log
 from . import events
-from .registry import add_reset_hook, registry
+from .registry import _get_profiler, add_reset_hook, registry
 
 _WARNED = set()
 
@@ -125,6 +134,77 @@ def traced(name: str) -> Callable:
                 record_trace(name, time.perf_counter() - t0)
         return wrapper
     return deco
+
+
+# ----------------------------------------------------------------------
+# lowering / backend-compile seconds and persistent-cache outcomes
+# ----------------------------------------------------------------------
+
+# jax's event -> the stage-total prefix it aggregates under
+COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit_lower_s/",
+    "/jax/core/compile/backend_compile_duration": "jit_backend_compile_s/",
+}
+CACHE_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": "jit_cache_hits",
+    "/jax/compilation_cache/cache_misses": "jit_cache_misses",
+}
+_listening = False
+
+
+def _on_compile_start(event: str, value, fun_name: str = "?",
+                      **kwargs) -> None:
+    """jax records a scalar as it enters a timed compile phase: open
+    the phase's range in the profiler's trace. From here on the
+    persistent cache's key holds the programs' metadata too: the device
+    stages are read from the ``obs_*`` names in it, and jax's default key
+    would hand a profiled run an entry compiled from other source, with
+    that source's names (the key is computed inside the backend phase,
+    after this). Runs that never switch the timer on keep jax's key and
+    share entries whatever the names."""
+    if event not in COMPILE_STAGES or not registry.timer.enabled:
+        return
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    profiler = _get_profiler()
+    if profiler is None:
+        return
+    annotation = profiler.TraceAnnotation("jit::compile %s" % fun_name)
+    annotation.__enter__()
+    _tls.compiling = annotation
+
+
+def _on_compile_end(event: str, duration: float, fun_name: str = "?",
+                    **kwargs) -> None:
+    prefix = COMPILE_STAGES.get(event)
+    if prefix is None:
+        return
+    annotation = getattr(_tls, "compiling", None)
+    if annotation is not None:
+        _tls.compiling = None
+        annotation.__exit__(None, None, None)
+    if registry.timer.enabled:
+        registry.timer.record(prefix + fun_name, duration)
+
+
+def _on_cache_event(event: str, **kwargs) -> None:
+    name = CACHE_COUNTERS.get(event)
+    if name is not None and registry.timer.enabled:
+        registry.inc(name)
+
+
+def _listen() -> None:
+    """Register the three listeners with ``jax.monitoring``, once per
+    process; every ``instrument_jit`` site calls this before it can
+    compile."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    from jax import monitoring
+    monitoring.register_scalar_listener(_on_compile_start)
+    monitoring.register_event_duration_secs_listener(_on_compile_end)
+    monitoring.register_event_listener(_on_cache_event)
 
 
 # ----------------------------------------------------------------------
@@ -225,6 +305,7 @@ def instrument_jit(name: str, fun: Callable, **jit_kwargs) -> Callable:
     fresh trace was observed), so steady-state dispatches stay
     unperturbed even while profiling."""
     import jax
+    _listen()
     jitted = jax.jit(traced(name)(fun), **jit_kwargs)
 
     @functools.wraps(fun)

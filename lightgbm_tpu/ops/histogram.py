@@ -194,6 +194,7 @@ def _acc_dtype_of(gh_dtype):
     return jnp.float64 if gh_dtype == jnp.float64 else jnp.float32
 
 
+@jax.named_scope("obs_hist_scatter")
 def _segment_histogram(bins: jnp.ndarray, gh: jnp.ndarray,
                        num_bins: int) -> jnp.ndarray:
     """Scatter-add formulation via a flat segment-sum — the direct
@@ -315,6 +316,7 @@ def _pallas_histogram_body(bins: jnp.ndarray, gh: jnp.ndarray,
     out_dtype = jnp.int32 if quantized else jnp.float32
     out = pl.pallas_call(
         functools.partial(_hist_kernel_body, F, H, C),
+        name="hist_kernel",
         grid=(bins.shape[0] // T,),
         in_specs=[
             pl.BlockSpec((F, T), lambda i: (0, i)),
@@ -410,13 +412,23 @@ def build_histogram(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
     if on_tpu and excluded is None:
         # chosen by shape: a kernel that does not compile is an error
         # that reaches the user, never a silent einsum run
-        return _pallas_histogram(bins, gh, num_bins,
-                                 _pallas_row_tile(gh.dtype))
+        with jax.named_scope("obs_hist_pallas"):
+            return _pallas_histogram(bins, gh, num_bins,
+                                     _pallas_row_tile(gh.dtype))
     if f64:
         gh = gh.astype(jnp.float64)
     if backend == "scatter" or (backend == "auto"
                                 and jax.default_backend() == "cpu"):
         return _segment_histogram(bins, gh, num_bins)
+    return _einsum_histogram(bins, gh, num_bins, row_tile, quantized)
+
+
+@jax.named_scope("obs_hist_einsum")
+def _einsum_histogram(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
+                      row_tile: int, quantized: bool) -> jnp.ndarray:
+    """One-hot contraction over ``row_tile``-row tiles, scanned."""
+    S, F = bins.shape
+    C = gh.shape[1]
     acc_dtype = _acc_dtype_of(gh.dtype)
     out_dtype = acc_dtype if quantized else jnp.float32
     if S <= row_tile:
@@ -441,6 +453,7 @@ def build_histogram(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
     return hist.astype(out_dtype)
 
 
+@jax.named_scope("obs_hist_subtract")
 def subtract_histogram(parent: jnp.ndarray, child: jnp.ndarray) -> jnp.ndarray:
     """Sibling histogram via subtraction (reference:
     serial_tree_learner.cpp:421-424 ``larger.Subtract(smaller)``)."""

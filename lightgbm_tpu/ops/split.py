@@ -273,6 +273,7 @@ def make_rand_bins(key, meta: "FeatureMeta", params: SplitParams):
     return rand_num, rand_oh, rand_sorted
 
 
+@jax.named_scope("obs_split_scan")
 def find_best_split(hist: jnp.ndarray,
                     sum_grad: jnp.ndarray,
                     sum_hess: jnp.ndarray,

@@ -325,11 +325,12 @@ class DataParallelTreeLearner(CapabilityMixin):
                              meta.missing_type[f], meta.num_bin[f] - 1,
                              meta.zero_bin[f], rec.is_categorical,
                              rec.cat_mask)
-        on_leaf = state.leaf_of_row == leaf
-        leaf_of_row = jnp.where(valid & on_leaf & ~gl, new_leaf,
-                                state.leaf_of_row)
-        leaf_of_row = jax.lax.with_sharding_constraint(
-            leaf_of_row, self.row_sharding)
+        with jax.named_scope("obs_partition"):
+            on_leaf = state.leaf_of_row == leaf
+            leaf_of_row = jnp.where(valid & on_leaf & ~gl, new_leaf,
+                                    state.leaf_of_row)
+            leaf_of_row = jax.lax.with_sharding_constraint(
+                leaf_of_row, self.row_sharding)
 
         smaller_is_left = rec.left_total_count <= rec.right_total_count
         (hist_left, hist_right, mask_left,
@@ -382,10 +383,24 @@ class DataParallelTreeLearner(CapabilityMixin):
             hist_small = self._mesh_hist(
                 bins, mask_gh(state.gh, small_sel), small_totals)
         hist_large = subtract_histogram(state.hists[leaf], hist_small)
-        hist_left = jnp.where(smaller_is_left, hist_small, hist_large)
-        hist_right = jnp.where(smaller_is_left, hist_large, hist_small)
+        with jax.named_scope("obs_hist_subtract"):
+            hist_left = jnp.where(smaller_is_left, hist_small, hist_large)
+            hist_right = jnp.where(smaller_is_left, hist_large,
+                                   hist_small)
         return hist_left, hist_right, mask_left, mask_right
 
+    def _compact_sizes(self) -> list:
+        """The compaction ladder's bucket sizes, largest first: half
+        the rows, then a quarter of the last until 16,384 or under."""
+        sizes = []
+        s = -(-self.R // 2)
+        while s > 16384:
+            sizes.append(s)
+            s = -(-s // 4)
+        sizes.append(s)
+        return sizes
+
+    @jax.named_scope("obs_compact")
     def _compact_child_hist(self, bins, gh, mask, totals):
         """Gather the smaller child's rows into a static power-ladder
         bucket (``lax.switch`` over compiled sizes) and histogram only
@@ -399,17 +414,13 @@ class DataParallelTreeLearner(CapabilityMixin):
         (compaction across shards would need an all-to-all; each shard
         already scans only its local rows)."""
         R = bins.shape[0]
-        sizes = []
-        s = -(-R // 2)
-        while s > 16384:
-            sizes.append(s)
-            s = -(-s // 4)
-        sizes.append(s)
+        sizes = self._compact_sizes()
         count = totals[3].astype(jnp.int32)     # rows on the leaf
         pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
         rows = jnp.arange(R, dtype=jnp.int32)
 
         def make_branch(S):
+            @jax.named_scope("obs_bucket_%d" % S)
             def branch(_):
                 idx = jnp.zeros((S,), dtype=jnp.int32)
                 idx = idx.at[jnp.where(mask, pos, S)].set(rows,
@@ -426,6 +437,7 @@ class DataParallelTreeLearner(CapabilityMixin):
             0, len(sizes) - 1)
         return jax.lax.switch(k, [make_branch(S) for S in sizes], 0)
 
+    @jax.named_scope("obs_hist_store")
     def _update_hist_store(self, state, leaf, new_leaf, hist_left,
                            hist_right, valid):
         """Per-leaf histogram pool update (the subtraction trick reads
@@ -452,11 +464,12 @@ class DataParallelTreeLearner(CapabilityMixin):
 
         def body(carry):
             i, state, recs, _ = carry
-            best = jnp.argmax(state.gain).astype(jnp.int32)
-            rec = _record_at(state, best)
-            valid = rec_valid(rec)
-            recs = jax.tree_util.tree_map(
-                lambda buf, v: buf.at[i].set(v), recs, rec)
+            with jax.named_scope("obs_pick_leaf"):
+                best = jnp.argmax(state.gain).astype(jnp.int32)
+                rec = _record_at(state, best)
+                valid = rec_valid(rec)
+                recs = jax.tree_util.tree_map(
+                    lambda buf, v: buf.at[i].set(v), recs, rec)
             new_leaf = (i + 1).astype(jnp.int32)
             state = self._mesh_split_body(bins, state, rec, best,
                                           new_leaf, valid, feature_mask,
@@ -778,12 +791,38 @@ class DataParallelTreeLearner(CapabilityMixin):
             # tree's split records read back in one hop (scope comment)
             recs_h = jax.device_get(recs)
         with obs.scope("tree::apply_records"):
+            applied = 0
             for i in range(self.L - 1):
                 r = jax.tree_util.tree_map(lambda a: a[i], recs_h)
                 if not record_is_valid(r):
                     break
                 apply_split_record(tree, self.dataset, r)
+                applied += 1
+            if obs.enabled:
+                self._count_hist_rows(recs_h, applied)
         return tree, self._finalize_partition(state.leaf_of_row)
+
+    def _count_hist_rows(self, recs_h, applied: int) -> None:
+        """``grow/hist_rows_needed``: rows of the smaller child of each
+        applied split, which a histogram has to visit;
+        ``grow/hist_rows_bucketed``: rows the learner's passes visited
+        for them."""
+        small = np.minimum(recs_h.left_total_count[:applied],
+                           recs_h.right_total_count[:applied])
+        obs.inc("grow/hist_rows_needed", int(small.sum()))
+        obs.inc("grow/hist_rows_bucketed",
+                int(self._hist_rows_bucketed(small)))
+
+    def _hist_rows_bucketed(self, small: np.ndarray) -> int:
+        """Rows ``_children_histograms`` passes over for splits whose
+        smaller children hold ``small`` rows: the bucket
+        ``_compact_child_hist`` picks on one device, the whole masked
+        row space on a sharded mesh."""
+        if self.mesh.devices.size != 1:
+            return self.R * len(small)
+        sizes = np.asarray(self._compact_sizes()[::-1])
+        return sizes[np.minimum(np.searchsorted(sizes, small),
+                                len(sizes) - 1)].sum()
 
     # --- device-resident multi-iteration batching ---------------------
     # Every dispatch and every host sync costs the device idle time

@@ -135,6 +135,10 @@ class VotingParallelTreeLearner(DataParallelTreeLearner):
         return (hist_left, hist_right, mask_left & voted_l,
                 mask_right & voted_r)
 
+    def _hist_rows_bucketed(self, small) -> int:
+        # both children, each over the whole masked row space
+        return 2 * self.R * len(small)
+
     def _update_hist_store(self, state, leaf, new_leaf, hist_left,
                            hist_right, valid):
         # histograms are re-voted fresh per leaf; nothing reads the store

@@ -160,6 +160,7 @@ def _empty_records(k: int, B: int) -> SplitRecord:
         right_total_count=zf, right_output=zf)
 
 
+@jax.named_scope("obs_split_scan")
 def _store_info(state: GrowState, leaf, info: SplitInfo, allowed,
                 valid=True) -> GrowState:
     """Write a leaf's candidate split; ``allowed`` zeroes the gain
@@ -269,6 +270,7 @@ def apply_split_record(tree: Tree, dataset: BinnedDataset, rec) -> None:
             default_left=bool(rec.default_left), **common)
 
 
+@jax.named_scope("obs_partition")
 def _go_left_by_bin(col: jnp.ndarray, tbin, default_left,
                     missing_type, nan_bin, zero_bin,
                     is_categorical=None, cat_mask=None) -> jnp.ndarray:
@@ -399,6 +401,7 @@ def build_bundle_tables(dataset: BinnedDataset, Fp: int, Gp: int,
         gidx_b=jnp.asarray(gidx_b), zero_fix=jnp.asarray(zero_fix))
 
 
+@jax.named_scope("obs_partition")
 def _partition_col(bins, f, meta, btab, bundled: bool):
     """The split feature's ORIGINAL bin value per row (unbundling via the
     member/unmap LUTs when bundled; identity otherwise)."""
@@ -421,14 +424,16 @@ def _finish_split(state: GrowState, rec: SplitRecord, leaf, new_leaf,
     mesh-parallel learners (only the child-histogram computation
     differs). ``children_allowed`` None means: derive from the
     device-side leaf_depth against the static max_depth."""
-    child_depth = state.leaf_depth[leaf] + 1
-    leaf_depth = state.leaf_depth \
-        .at[leaf].set(jnp.where(valid, child_depth,
-                                state.leaf_depth[leaf])) \
-        .at[new_leaf].set(jnp.where(valid, child_depth,
-                                    state.leaf_depth[new_leaf]))
-    if children_allowed is None:
-        children_allowed = (max_depth <= 0) | (child_depth < max_depth)
+    with jax.named_scope("obs_split_scan"):
+        child_depth = state.leaf_depth[leaf] + 1
+        leaf_depth = state.leaf_depth \
+            .at[leaf].set(jnp.where(valid, child_depth,
+                                    state.leaf_depth[leaf])) \
+            .at[new_leaf].set(jnp.where(valid, child_depth,
+                                        state.leaf_depth[new_leaf]))
+        if children_allowed is None:
+            children_allowed = ((max_depth <= 0)
+                                | (child_depth < max_depth))
 
     left_info = find_best_split(
         hist_left, rec.left_sum_grad, rec.left_sum_hess,
@@ -482,9 +487,10 @@ def _split_body(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
                          meta.missing_type[f], meta.num_bin[f] - 1,
                          meta.zero_bin[f], rec.is_categorical,
                          rec.cat_mask)
-    on_leaf = state.leaf_of_row == leaf
-    leaf_of_row = jnp.where(valid & on_leaf & ~gl, new_leaf,
-                            state.leaf_of_row)
+    with jax.named_scope("obs_partition"):
+        on_leaf = state.leaf_of_row == leaf
+        leaf_of_row = jnp.where(valid & on_leaf & ~gl, new_leaf,
+                                state.leaf_of_row)
 
     smaller_is_left = rec.left_total_count <= rec.right_total_count
     small_id = jnp.where(smaller_is_left, leaf, new_leaf)
@@ -499,12 +505,14 @@ def _split_body(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
     # bundled zero-bin fix needs exact int sums — _leaf_histogram
     # recomputes them from the gathered integer rows
     def hist_at(size: int):
-        (idx,) = jnp.nonzero(leaf_of_row == small_id, size=size,
-                             fill_value=R - 1)
-        return _leaf_histogram(bins[idx], state.gh[idx], meta, btab,
-                               B=B, Bg=Bg, bundled=bundled,
-                               totals=small_totals,
-                               hist_impl=hist_impl)
+        with jax.named_scope("obs_compact"), \
+                jax.named_scope("obs_bucket_%d" % size):
+            (idx,) = jnp.nonzero(leaf_of_row == small_id, size=size,
+                                 fill_value=R - 1)
+            return _leaf_histogram(bins[idx], state.gh[idx], meta, btab,
+                                   B=B, Bg=Bg, bundled=bundled,
+                                   totals=small_totals,
+                                   hist_impl=hist_impl)
 
     ladder = S if isinstance(S, tuple) else (S,)
     if len(ladder) == 1:
@@ -522,12 +530,15 @@ def _split_body(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
         hist_small = jax.lax.switch(
             k, [lambda _, s=s: hist_at(s) for s in ladder], 0)
     hist_large = subtract_histogram(state.hists[leaf], hist_small)
-    hist_left = jnp.where(smaller_is_left, hist_small, hist_large)
-    hist_right = jnp.where(smaller_is_left, hist_large, hist_small)
-    hists = state.hists \
-        .at[leaf].set(jnp.where(valid, hist_left, state.hists[leaf])) \
-        .at[new_leaf].set(
-            jnp.where(valid, hist_right, state.hists[new_leaf]))
+    with jax.named_scope("obs_hist_subtract"):
+        hist_left = jnp.where(smaller_is_left, hist_small, hist_large)
+        hist_right = jnp.where(smaller_is_left, hist_large, hist_small)
+    with jax.named_scope("obs_hist_store"):
+        hists = state.hists \
+            .at[leaf].set(jnp.where(valid, hist_left,
+                                    state.hists[leaf])) \
+            .at[new_leaf].set(
+                jnp.where(valid, hist_right, state.hists[new_leaf]))
 
     state = state._replace(leaf_of_row=leaf_of_row, hists=hists)
     return _finish_split(state, rec, leaf, new_leaf, valid, hist_left,
@@ -847,11 +858,12 @@ def _batch_fn_cached(S: int, kb: int, B: int, Bg: int, bundled: bool,
               feature_mask, rand_seed, qscale, meta, params, btab):
         def body(i, carry):
             state, recs = carry
-            best = jnp.argmax(state.gain).astype(jnp.int32)
-            rec = _record_at(state, best)
-            valid = rec_valid(rec) & (i < max_splits)
-            recs = jax.tree_util.tree_map(
-                lambda buf, v: buf.at[i].set(v), recs, rec)
+            with jax.named_scope("obs_pick_leaf"):
+                best = jnp.argmax(state.gain).astype(jnp.int32)
+                rec = _record_at(state, best)
+                valid = rec_valid(rec) & (i < max_splits)
+                recs = jax.tree_util.tree_map(
+                    lambda buf, v: buf.at[i].set(v), recs, rec)
             new_leaf = (start_leaf + i).astype(jnp.int32)
             state = _split_body(bins, state, rec, best, new_leaf, valid,
                                 feature_mask, feature_mask, meta, params,
@@ -910,11 +922,12 @@ def _fused_fn_cached(L: int, B: int, Bg: int, bundled: bool,
 
         def body(carry):
             i, state, recs, _ = carry
-            best = jnp.argmax(state.gain).astype(jnp.int32)
-            rec = _record_at(state, best)
-            valid = rec_valid(rec) & (i < max_splits)
-            recs = jax.tree_util.tree_map(
-                lambda buf, v: buf.at[i].set(v), recs, rec)
+            with jax.named_scope("obs_pick_leaf"):
+                best = jnp.argmax(state.gain).astype(jnp.int32)
+                rec = _record_at(state, best)
+                valid = rec_valid(rec) & (i < max_splits)
+                recs = jax.tree_util.tree_map(
+                    lambda buf, v: buf.at[i].set(v), recs, rec)
             new_leaf = (start_leaf + i).astype(jnp.int32)
             state = _split_body(bins, state, rec, best, new_leaf, valid,
                                 feature_mask, feature_mask, meta, params,

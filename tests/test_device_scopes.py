@@ -1,0 +1,359 @@
+"""The grower's stages are named on the device clock.
+
+Every stage of the device programs sits in a ``jax.named_scope`` named
+``obs_<stage>`` (docs/OBSERVABILITY.md "Device traces"); the benchmark's
+``benchmark/trace/scopes.py`` reads them back from a profiler trace. A
+refactor that drops a scope, a reader that loses time, a compile that is
+not accounted and a bucket counter that drifts from the trees all fail
+here, on the CPU.
+"""
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.trace import scopes, work, xplane
+from lightgbm_tpu.config import Config
+from lightgbm_tpu.io.dataset import BinnedDataset
+from lightgbm_tpu.obs import compile as obs_compile
+from lightgbm_tpu.obs.registry import registry
+from lightgbm_tpu.ops import histogram
+from lightgbm_tpu.parallel.data_parallel import (DataParallelTreeLearner,
+                                                 make_mesh)
+from lightgbm_tpu.treelearner.serial import SerialTreeLearner
+
+SMALL_TRACE = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "tests", "data",
+    "small.xplane.pb")
+
+
+# --- (a) every scope of the table is in the lowered programs -------------
+
+def _dataset(hist_backend):
+    rng = np.random.RandomState(0)
+    cfg = Config.from_params({"num_leaves": 31, "max_bin": 63,
+                              "verbosity": -1,
+                              "hist_backend": hist_backend})
+    return cfg, BinnedDataset.from_matrix(rng.randn(40000, 16), cfg)
+
+
+def _mesh_programs(hist_backend):
+    cfg, ds = _dataset(hist_backend)
+    lrn = DataParallelTreeLearner(cfg, ds, make_mesh(1))
+    lrn._ensure_compiled()
+    args = (lrn.bins, jax.ShapeDtypeStruct((lrn.R, 4), jnp.float32),
+            lrn._sample_features(), jnp.int32(1), lrn._qs_ones)
+    state, _ = jax.eval_shape(lrn._root_fn, *args)
+    tree = lrn._tree_fn.lower(lrn.bins, state, *args[2:])
+    return (lrn._root_fn.lower(*args).as_text(debug_info=True),
+            tree.as_text(debug_info=True))
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    root, tree = _mesh_programs("auto")
+    _, tree_onehot = _mesh_programs("onehot")
+    cfg, ds = _dataset("auto")
+    serial = SerialTreeLearner(cfg, ds)
+    r = -(-(serial.N + 1) // 4096) * 4096
+    sds = jax.ShapeDtypeStruct
+    root_args = (sds((r, serial.Fp), serial.bins.dtype),
+                 sds((r, 4), jnp.float32), sds((r,), jnp.int32),
+                 sds((serial.Fp,), jnp.bool_), sds((), jnp.bool_),
+                 sds((), jnp.int32), sds((2,), jnp.float32), serial.meta,
+                 serial.params, serial._btab)
+    state, _ = jax.eval_shape(serial._root_fn, *root_args)
+    batch, _ = serial._batch_fn(4096)
+    serial_batch = batch.lower(
+        root_args[0], state, sds((), jnp.int32), sds((), jnp.int32),
+        root_args[3], sds((), jnp.int32), sds((2,), jnp.float32),
+        serial.meta, serial.params, serial._btab)
+    return {"root": root, "tree": tree, "tree_onehot": tree_onehot,
+            "serial_batch": serial_batch.as_text(debug_info=True)}
+
+
+@pytest.mark.parametrize("program,scope", [
+    ("root", "obs_hist_scatter"),
+    ("root", "obs_split_scan"),
+    ("tree", "obs_pick_leaf"),
+    ("tree", "obs_partition"),
+    ("tree", "obs_compact"),
+    ("tree", "obs_hist_scatter"),
+    ("tree", "obs_hist_subtract"),
+    ("tree", "obs_hist_store"),
+    ("tree", "obs_split_scan"),
+    ("tree", "obs_psum_histogram"),
+    ("tree_onehot", "obs_hist_einsum"),
+    ("serial_batch", "obs_pick_leaf"),
+    ("serial_batch", "obs_partition"),
+    ("serial_batch", "obs_compact"),
+    ("serial_batch", "obs_hist_subtract"),
+    ("serial_batch", "obs_hist_store"),
+    ("serial_batch", "obs_split_scan"),
+])
+def test_scope_is_in_the_lowered_program(lowered, program, scope):
+    """``mesh.root``/``mesh.tree`` (``_root_impl``, ``_tree_impl``) on one
+    device, and the serial learner's batched step, which share the scoped
+    functions."""
+    assert re.search(r'[/"]%s[/"]' % scope, lowered[program]), (
+        "%s has no operation under %s" % (program, scope))
+
+
+def test_ladder_branches_are_tagged_inside_the_compaction(lowered):
+    """40,000 rows: buckets of 20,000 and 5,000, each its own branch of
+    the switch, with the histogram's scope inside."""
+    for branch, size in ((0, 20000), (1, 5000)):
+        assert re.search(
+            r"while/body/obs_compact/cond/branch_%d_fun/obs_bucket_%d/"
+            r"obs_hist_scatter/" % (branch, size), lowered["tree"])
+
+
+def test_pallas_path_is_scoped_and_the_kernel_named(monkeypatch):
+    """The path ``build_histogram`` takes on a TPU, lowered for one."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bins = jax.ShapeDtypeStruct((2 * histogram.PALLAS_ROW_TILE, 28),
+                                jnp.uint8)
+    gh = jax.ShapeDtypeStruct((2 * histogram.PALLAS_ROW_TILE, 4),
+                              jnp.float32)
+    text = jax.jit(lambda b, g: histogram.build_histogram(b, g, 255)) \
+        .trace(bins, gh).lower(lowering_platforms=("tpu",)) \
+        .as_text(debug_info=True)
+    assert "/obs_hist_pallas/jit(_pallas_histogram_body)" in text
+    assert '"hist_kernel/pallas_call"' in text
+    assert "tpu_custom_call" in text
+
+
+# --- (b) the reader ------------------------------------------------------
+
+def test_reader_finds_the_name_stack_in_the_event_metadata():
+    ops = scopes.load_ops(SMALL_TRACE)
+    by_name = {}
+    for name, tf_op in zip(ops.line.names, ops.tf_op):
+        by_name.setdefault(xplane.short_name(name).split()[0],
+                           set()).add(tf_op)
+    assert by_name["%fusion.8"] == {
+        "jit(bench_small_loop)/while/body/closed_call/dot_general:"}
+    # an operation XLA added itself has no name stack
+    assert by_name["%copy.11"] == {""}
+
+
+def test_reader_agrees_with_profile_data_and_loses_no_time():
+    ops = scopes.load_ops(SMALL_TRACE)
+    trace = xplane.load(SMALL_TRACE)
+    assert ops.line.names == trace.ops().names
+    np.testing.assert_array_equal(ops.line.start, trace.ops().start)
+    np.testing.assert_array_equal(ops.line.dur, trace.ops().dur)
+    assert ops.modules.names == trace.modules().names
+    times = scopes.stage_times(ops)
+    assert set(times.stages) == {scopes.UNSCOPED}
+    assert times.total_s == pytest.approx(xplane.union_s(trace.ops()),
+                                          rel=1e-9)
+    # clipped to one program, what lies between its operations included
+    clipped = scopes.stage_times(ops, r"^jit_bench_small_loop")
+    assert clipped.total_s == pytest.approx(xplane.union_s(
+        trace.modules().matching(r"^jit_bench_small_loop")), rel=1e-9)
+    assert clipped.unscoped_ops[0][0].startswith("%fusion.8")
+
+
+def _hand_made():
+    """Two runs of ``jit__tree_impl`` and one of another program. Times in
+    ns; ``while`` [0, 1000) holds the body's operations."""
+    p = "jit(_tree_impl)/while/body/"
+    events = [
+        ("%while.1 = while()", 0, 1000, ""),
+        ("%fusion.1 = f32[8]{0} fusion()", 0, 100, p + "obs_pick_leaf/argmax"),
+        ("%fusion.2 = pred[8]{0} fusion()", 100, 200, p + "obs_partition/le"),
+        ("%fusion.3 = s32[8]{0} fusion()", 300, 100,
+         p + "obs_compact/cumsum"),
+        ("%conditional.1 = conditional()", 400, 400, p + "obs_compact/cond"),
+        ("%fusion.4 = u8[8]{0} fusion()", 400, 100,
+         p + "obs_compact/cond/branch_1_fun/obs_bucket_5000/gather"),
+        ("%fusion.5 = f32[8]{0} fusion()", 500, 250,
+         p + "obs_compact/cond/branch_1_fun/obs_bucket_5000/"
+             "obs_hist_einsum/obs_psum_histogram/dot_general:"),
+        ("%copy.1 = f32[8]{0} copy()", 800, 100, ""),
+        ("%fusion.6 = f32[8]{0} fusion()", 900, 50,
+         p + "obs_split_scan/obs_made_up/reduce_max"),
+        # the second run: [2000, 2100)
+        ("%fusion.7 = f32[8]{0} fusion()", 2000, 80,
+         "jit(_tree_impl)/obs_hist_store/dynamic_update_slice"),
+        # another program's operation, [3000, 3500)
+        ("%fusion.8 = f32[8]{0} fusion()", 3000, 500,
+         "jit(other)/obs_partition/le"),
+    ]
+    line = xplane.Line([e[0] for e in events],
+                       np.asarray([e[1] for e in events], np.int64),
+                       np.asarray([e[2] for e in events], np.int64))
+    modules = xplane.Line(
+        ["jit__tree_impl(1)", "jit__tree_impl(1)", "jit_other(2)"],
+        np.asarray([0, 2000, 3000], np.int64),
+        np.asarray([1000, 100, 500], np.int64))
+    return scopes.Ops(line, [e[3] for e in events], modules)
+
+
+def test_innermost_stage_wins_and_the_stages_add_up():
+    times = scopes.stage_times(_hand_made(), r"^jit__tree_impl")
+    ns = {k: round(v * 1e9) for k, v in times.stages.items()}
+    assert ns == {
+        "obs_pick_leaf": 100, "obs_partition": 200,
+        # the cumsum, the gather, and the conditional's own 50 ns
+        "obs_compact": 100 + 100 + 50,
+        # under obs_compact and obs_psum_histogram, but a stage of its own
+        "obs_hist_einsum": 250,
+        "obs_split_scan": 50, "obs_hist_store": 80,
+        # the copy, the while's own 50 ns, and the second run's last 20 ns
+        scopes.UNSCOPED: 100 + 50 + 20,
+    }
+    assert round(times.total_s * 1e9) == 1000 + 100
+    assert {b: {k: round(v * 1e9) for k, v in per.items()}
+            for b, per in times.buckets.items()} == {
+        5000: {"obs_compact": 100, "obs_hist_einsum": 250}}
+    assert [[n, round(s * 1e9)] for n, s in times.unscoped_ops] == [
+        ["%copy.1 f32[8] copy", 100], ["%while.1 = while()", 50],
+        [scopes.BETWEEN_OPS, 20]]
+
+
+@pytest.mark.parametrize("tf_op,expected", [
+    ("", (None, None)),
+    ("jit(f)/while/body/add:", (None, None)),
+    ("jit(f)/obs_psum_histogram/all-reduce", (None, None)),
+    ("jit(f)/obs_compact/cond/branch_0_fun/obs_bucket_12500/gather",
+     ("obs_compact", 12500)),
+    ("jit(f)/obs_compact/obs_bucket_7/obs_hist_pallas/jit(g)/hist_kernel:",
+     ("obs_hist_pallas", 7)),
+    ("jit(f)/obs_split_scan", ("obs_split_scan", None)),
+])
+def test_stage_of_a_name_stack(tf_op, expected):
+    assert scopes.stage_of(tf_op) == expected
+
+
+# --- (c) compile accounting ----------------------------------------------
+
+@pytest.fixture
+def timer_on():
+    was = registry.timer.enabled
+    registry.timer.enable()
+    try:
+        yield registry.timer
+    finally:
+        registry.timer.enabled = was
+
+
+def test_a_compile_is_accounted_once_under_jaxs_name(timer_on):
+    def scopes_fresh_fn(x):
+        return x * 3 + 1
+
+    fn = obs_compile.instrument_jit("test.scopes_fresh", scopes_fresh_fn)
+    names = ["jit_lower_s/jit(scopes_fresh_fn)",
+             "jit_backend_compile_s/jit(scopes_fresh_fn)"]
+    assert not any(n in timer_on.stats() for n in names)
+    fn(jnp.ones(7)).block_until_ready()
+    first = timer_on.stats()
+    assert all(first[n][0] > 0 and first[n][1] == 1 for n in names)
+    fn(jnp.ones(7)).block_until_ready()
+    second = timer_on.stats()
+    assert all(second[n][:2] == first[n][:2] for n in names)
+    # none of it under the prefix the retrace budget counts
+    assert not [k for k in registry.counters
+                if k.startswith("jit_trace/jit")]
+
+
+def test_compiles_are_not_accounted_while_the_timer_is_off():
+    assert not registry.timer.enabled
+
+    def scopes_quiet_fn(x):
+        return x - 2
+
+    fn = obs_compile.instrument_jit("test.scopes_quiet", scopes_quiet_fn)
+    fn(jnp.ones(5)).block_until_ready()
+    assert not [k for k in registry.timer.stats() if "scopes_quiet_fn" in k]
+
+
+_CACHE_CHILD = """
+import sys
+import jax, jax.numpy as jnp
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from lightgbm_tpu.obs import compile as obs_compile
+from lightgbm_tpu.obs.registry import registry
+counted = []
+jax.monitoring.register_event_listener(lambda e, **kw: counted.append(e))
+if sys.argv[2] == "profiled":
+    registry.timer.enable()
+def cache_probe_fn(x):
+    with jax.named_scope("obs_probe"):
+        return jnp.sin(x) * 2
+obs_compile.instrument_jit("probe", cache_probe_fn)(jnp.ones(16))
+registry.timer.disable()
+print(sum(e.endswith("/cache_hits") for e in counted),
+      sum(e.endswith("/cache_misses") for e in counted),
+      registry.count("jit_cache_hits"), registry.count("jit_cache_misses"))
+"""
+
+
+def test_a_profiled_run_reads_no_cache_entry_of_an_unprofiled_one(tmp_path):
+    """jax's cache key leaves metadata out, so an entry compiled before a
+    scope would come back without it; with the stage timer on the key
+    holds the metadata. Fresh interpreters: the key is per process."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def child(mode):
+        out = subprocess.run(
+            [sys.executable, "-c", _CACHE_CHILD, str(tmp_path), mode],
+            env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=repo,
+            capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return [int(v) for v in out.stdout.split()[-4:]]
+
+    hits, misses, counted_hits, counted_misses = child("plain")
+    assert hits == 0 and misses >= 1
+    # counted only while the timer is on
+    assert counted_hits == counted_misses == 0
+    assert child("plain")[:2] == [misses, 0]
+    assert child("profiled") == [0, misses, 0, misses]
+    assert child("profiled") == [misses, 0, misses, 0]
+
+
+# --- (d) the bucket counters ---------------------------------------------
+
+def _train_data_learner():
+    import lightgbm_tpu as lgb
+    rng = np.random.RandomState(3)
+    X = rng.randn(40000, 8)
+    y = (X[:, 0] * X[:, 1] + X[:, 2] > 0).astype(float)
+    return lgb.train({"objective": "binary", "verbose": -1,
+                      "num_leaves": 31, "max_bin": 63,
+                      "tree_learner": "data", "num_machines": 1},
+                     lgb.Dataset(X, label=y), num_boost_round=3)
+
+
+def _hist_rows():
+    return (registry.count("grow/hist_rows_needed"),
+            registry.count("grow/hist_rows_bucketed"))
+
+
+def test_bucket_counters_follow_the_trees(timer_on):
+    needed0, bucketed0 = _hist_rows()
+    bst = _train_data_learner()
+    assert type(bst.inner.learner).__name__ == "DataParallelTreeLearner"
+    needed, bucketed = _hist_rows()
+    counts = work.tree_counts_from_model_text(bst.model_to_string())
+    assert len(counts) == 3
+    assert needed - needed0 == sum(sum(smaller) for _, smaller in counts)
+    # every split is padded to a bucket of 20,000 or 5,000 rows
+    splits = sum(len(smaller) for _, smaller in counts)
+    assert bucketed - bucketed0 >= max(needed - needed0, 5000 * splits)
+    assert (bucketed - bucketed0) % 5000 == 0
+
+
+def test_bucket_counters_stay_still_while_the_timer_is_off():
+    assert not registry.timer.enabled
+    before = _hist_rows()
+    _train_data_learner()
+    assert _hist_rows() == before
