@@ -54,7 +54,6 @@ from ..models.tree import Tree
 from ..obs import compile as obs_compile
 from ..obs.registry import registry as obs
 from ..ops.histogram import (build_histogram, mask_gh,
-                             subtract_histogram,
                              unpack_bundle_histogram)
 from ..ops.quantize import dequantize_sums, sum_gh
 from ..ops.split import (FeatureMeta, SplitParams, calculate_leaf_output,
@@ -64,7 +63,8 @@ from ..treelearner.capabilities import (CapabilityMixin, train_cegb,
 from ..treelearner.serial import (GrowState, SplitRecord, _cegb_penalty,
                                   _empty_records, _finish_split,
                                   _go_left_by_bin, _maybe_rand_bins,
-                                  _partition_col, _record_at, _store_info,
+                                  _partition_col, _record_at,
+                                  _split_hist_store, _store_info,
                                   apply_split_record, build_bundle_tables,
                                   make_root_state, rec_valid,
                                   record_is_valid)
@@ -333,12 +333,10 @@ class DataParallelTreeLearner(CapabilityMixin):
                 leaf_of_row, self.row_sharding)
 
         smaller_is_left = rec.left_total_count <= rec.right_total_count
-        (hist_left, hist_right, mask_left,
+        (hists, hist_left, hist_right, mask_left,
          mask_right) = self._children_histograms(
             bins, state, rec, leaf, new_leaf, leaf_of_row,
-            smaller_is_left, mask_left, mask_right, qscale)
-        hists = self._update_hist_store(state, leaf, new_leaf, hist_left,
-                                        hist_right, valid)
+            smaller_is_left, valid, mask_left, mask_right, qscale)
         state = state._replace(leaf_of_row=leaf_of_row, hists=hists)
         return _finish_split(
             state, rec, leaf, new_leaf, valid, hist_left, hist_right,
@@ -350,15 +348,19 @@ class DataParallelTreeLearner(CapabilityMixin):
             pen_left=pen_left, pen_right=pen_right, qscale=qscale)
 
     def _children_histograms(self, bins, state, rec, leaf, new_leaf,
-                             leaf_of_row, smaller_is_left, mask_left,
-                             mask_right, qscale=None):
-        """Cross-device-summed child histograms + the per-child scan
-        masks. Base learner: masked histogram of the smaller child over
-        the full sharded row space (the analogue of the reference ranks
-        histogramming their local leaf rows then ReduceScatter-summing,
+                             leaf_of_row, smaller_is_left, valid,
+                             mask_left, mask_right, qscale=None):
+        """The updated per-leaf store, the cross-device-summed child
+        histograms and the per-child scan masks. Base learner: masked
+        histogram of the smaller child over the full sharded row space
+        (the analogue of the reference ranks histogramming their local
+        leaf rows then ReduceScatter-summing,
         data_parallel_tree_learner.cpp:185), sibling by subtraction —
-        BIT-EXACT in quantized-integer mode. Voting-parallel overrides
-        this with the reduced-comm vote."""
+        BIT-EXACT in quantized-integer mode — and both children stored
+        by the serial learner's ``_split_hist_store``, which reads the
+        store before it writes it (why: its docstring). Voting-parallel
+        overrides this with the reduced-comm vote and skips the
+        store."""
         small_id = jnp.where(smaller_is_left, leaf, new_leaf)
         small_sel = leaf_of_row == small_id
         small_totals = jnp.stack([
@@ -382,12 +384,10 @@ class DataParallelTreeLearner(CapabilityMixin):
             # integer gh rows)
             hist_small = self._mesh_hist(
                 bins, mask_gh(state.gh, small_sel), small_totals)
-        hist_large = subtract_histogram(state.hists[leaf], hist_small)
-        with jax.named_scope("obs_hist_subtract"):
-            hist_left = jnp.where(smaller_is_left, hist_small, hist_large)
-            hist_right = jnp.where(smaller_is_left, hist_large,
-                                   hist_small)
-        return hist_left, hist_right, mask_left, mask_right
+        hists, hist_left, hist_right = _split_hist_store(
+            state.hists, leaf, new_leaf, hist_small, smaller_is_left,
+            valid)
+        return hists, hist_left, hist_right, mask_left, mask_right
 
     def _compact_sizes(self) -> list:
         """The compaction ladder's bucket sizes, largest first: half
@@ -436,17 +436,6 @@ class DataParallelTreeLearner(CapabilityMixin):
             jnp.sum(jnp.asarray(sizes, dtype=jnp.int32) >= count) - 1,
             0, len(sizes) - 1)
         return jax.lax.switch(k, [make_branch(S) for S in sizes], 0)
-
-    @jax.named_scope("obs_hist_store")
-    def _update_hist_store(self, state, leaf, new_leaf, hist_left,
-                           hist_right, valid):
-        """Per-leaf histogram pool update (the subtraction trick reads
-        these; the voting learner overrides this to skip the store)."""
-        return state.hists \
-            .at[leaf].set(jnp.where(valid, hist_left,
-                                    state.hists[leaf])) \
-            .at[new_leaf].set(jnp.where(valid, hist_right,
-                                        state.hists[new_leaf]))
 
     # ------------------------------------------------------------------
     def _tree_impl(self, bins, state: GrowState, feature_mask, rand_seed,

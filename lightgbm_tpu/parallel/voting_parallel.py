@@ -118,8 +118,8 @@ class VotingParallelTreeLearner(DataParallelTreeLearner):
             out_specs=(P(), P()))(bins, gh_masked, feature_mask, qscale)
 
     def _children_histograms(self, bins, state, rec, leaf, new_leaf,
-                             leaf_of_row, smaller_is_left, mask_left,
-                             mask_right, qscale=None):
+                             leaf_of_row, smaller_is_left, valid,
+                             mask_left, mask_right, qscale=None):
         left_id = leaf  # left child keeps the split leaf's id
         if qscale is None:
             qscale = self._qs_ones
@@ -132,14 +132,12 @@ class VotingParallelTreeLearner(DataParallelTreeLearner):
             bins, gh_l, mask_left, qscale)
         hist_right, voted_r = self._voted_reduced_histogram(
             bins, gh_r, mask_right, qscale)
-        return (hist_left, hist_right, mask_left & voted_l,
+        # histograms are re-voted fresh per leaf; nothing reads the
+        # store, so it is handed back untouched
+        return (state.hists, hist_left, hist_right, mask_left & voted_l,
                 mask_right & voted_r)
 
     def _hist_rows_bucketed(self, small) -> int:
         # both children, each over the whole masked row space
         return 2 * self.R * len(small)
 
-    def _update_hist_store(self, state, leaf, new_leaf, hist_left,
-                           hist_right, valid):
-        # histograms are re-voted fresh per leaf; nothing reads the store
-        return state.hists

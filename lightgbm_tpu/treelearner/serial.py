@@ -413,6 +413,36 @@ def _partition_col(bins, f, meta, btab, bundled: bool):
     return jnp.where(owner == f, btab.unmap[g][raw], meta.zero_bin[f])
 
 
+def _split_hist_store(hists, leaf, new_leaf, hist_small, smaller_is_left,
+                      valid):
+    """Subtract the sibling from the parent's stored histogram and store
+    both children: ``(hists, hist_left, hist_right)``. The one place
+    where a split step touches the per-leaf store ``[L, F, B, 4]``
+    (serial and mesh learners). An invalid step writes the old slices
+    back, so the store stays bit for bit what it was.
+
+    Both old slices are read once, before the first write, and held
+    behind an ``optimization_barrier`` so that XLA cannot re-derive
+    them from the store inside the update fusions: a read of the old
+    store ordered after a write keeps the carried buffer live across
+    that write, and on the v5e the whole store was then copied twice
+    per split (two ``copy`` of ``f32[L,F,B,4]`` in the ``while`` body,
+    a fifth to a quarter of an iteration; ISSUE 28,
+    tests/test_hist_store_inplace.py). Read nothing of ``hists`` after
+    the first ``.at[].set`` here."""
+    old_leaf, old_new = jax.lax.optimization_barrier(
+        (hists[leaf], hists[new_leaf]))
+    hist_large = subtract_histogram(old_leaf, hist_small)
+    with jax.named_scope("obs_hist_subtract"):
+        hist_left = jnp.where(smaller_is_left, hist_small, hist_large)
+        hist_right = jnp.where(smaller_is_left, hist_large, hist_small)
+    with jax.named_scope("obs_hist_store"):
+        hists = hists \
+            .at[leaf].set(jnp.where(valid, hist_left, old_leaf)) \
+            .at[new_leaf].set(jnp.where(valid, hist_right, old_new))
+    return hists, hist_left, hist_right
+
+
 def _finish_split(state: GrowState, rec: SplitRecord, leaf, new_leaf,
                   valid, hist_left, hist_right, mask_left, mask_right,
                   meta, params, *, max_depth: int, extra_trees: bool,
@@ -529,16 +559,8 @@ def _split_body(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
             0, len(ladder) - 1).astype(jnp.int32)
         hist_small = jax.lax.switch(
             k, [lambda _, s=s: hist_at(s) for s in ladder], 0)
-    hist_large = subtract_histogram(state.hists[leaf], hist_small)
-    with jax.named_scope("obs_hist_subtract"):
-        hist_left = jnp.where(smaller_is_left, hist_small, hist_large)
-        hist_right = jnp.where(smaller_is_left, hist_large, hist_small)
-    with jax.named_scope("obs_hist_store"):
-        hists = state.hists \
-            .at[leaf].set(jnp.where(valid, hist_left,
-                                    state.hists[leaf])) \
-            .at[new_leaf].set(
-                jnp.where(valid, hist_right, state.hists[new_leaf]))
+    hists, hist_left, hist_right = _split_hist_store(
+        state.hists, leaf, new_leaf, hist_small, smaller_is_left, valid)
 
     state = state._replace(leaf_of_row=leaf_of_row, hists=hists)
     return _finish_split(state, rec, leaf, new_leaf, valid, hist_left,
