@@ -5,7 +5,9 @@ steps and what the plain reference produces from the same table: the
 training loss after each checked step, the norm of the scores' change after
 the first step and after the last (the gap between the two norms, not the
 norm of the difference), and the loss on held-out rows of the model cut to
-the checked steps. Every number has a limit of its own in
+the checked steps. A scoring cell compares the raw scores of the answers
+its window was given with the reference's, row by row
+(``compare_scores``). Every number has a limit of its own in
 ``limits/<cell>.json``.
 """
 from __future__ import annotations
@@ -39,6 +41,21 @@ def compare(loss_fn, y, init, got_scores, ref_scores, y_hold, got_hold,
     out["holdout_loss%d" % (last + 1)] = _rel(loss_fn(got_hold, y_hold),
                                              loss_fn(ref_hold, y_hold))
     return out
+
+
+def compare_scores(got: list, ref: list) -> dict:
+    """A scoring cell's numbers. ``got`` holds ``(block, scores)`` of every
+    answer compared, on that block's checked rows, and ``ref[block]`` the
+    reference's raw scores of the same rows: the largest and the
+    root-mean-square gap between them over all the answers, relative to the
+    root-mean-square of the reference's scores."""
+    if not got:
+        return {}
+    scale = _norm(np.concatenate(ref)) / np.sqrt(sum(len(r) for r in ref))
+    gaps = np.concatenate([np.asarray(g, dtype=np.float64) - ref[b]
+                           for b, g in got])
+    return {"score_max_gap": float(np.max(np.abs(gaps)) / scale),
+            "score_rms_gap": float(_norm(gaps) / np.sqrt(len(gaps)) / scale)}
 
 
 def judge(numbers: dict, limits: dict) -> tuple:

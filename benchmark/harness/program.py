@@ -1,10 +1,11 @@
 """The system under test, and nothing of the benchmark's own arithmetic.
 
 The one module that imports ``lightgbm_tpu``: the entry a user calls
-(``lgb.Dataset``, ``lgb.Booster``, ``Booster.update``, ``Booster.predict``),
-the state the checks read (the training rows' raw scores), its counters
-(``jit_trace/<name>``) and its spans. The tests break the timed path by
-patching ``Program.update``'s callee underneath.
+(``lgb.Dataset``, ``lgb.Booster``, ``Booster.update``, ``Booster.predict``,
+``PredictServer.submit``), the state the checks read (the training rows' raw
+scores), its counters (``jit_trace/<name>``) and its spans. The tests break
+the timed path by patching the callee of ``Program.update`` or
+``Scorer.submit`` underneath.
 """
 from __future__ import annotations
 
@@ -43,10 +44,12 @@ class Program:
         self.valid_set = None
         self.booster = None
 
-    def bin(self, X, y, X_valid=None, y_valid=None) -> None:
+    def bin(self, X, y, X_valid=None, y_valid=None, **dataset_kw) -> None:
+        """``dataset_kw``: what else the generator says a ``Dataset`` of
+        this deployment needs (``categorical_feature``, ``group``, ...)."""
         import lightgbm_tpu as lgb
-        self.train_set = lgb.Dataset(X, label=y,
-                                     params=dict(self.params)).construct()
+        self.train_set = lgb.Dataset(X, label=y, params=dict(self.params),
+                                     **dataset_kw).construct()
         if X_valid is not None:
             self.valid_set = lgb.Dataset(
                 X_valid, label=y_valid, reference=self.train_set).construct()
@@ -117,3 +120,24 @@ class Program:
         self.booster = None
         self.train_set = None
         self.valid_set = None
+
+
+class Scorer:
+    """A model as a user serves it: LightGBM model text loaded through
+    ``lgb.Booster(model_str=...)`` into a ``PredictServer``."""
+
+    def __init__(self, model_text: str, server: dict):
+        import lightgbm_tpu as lgb
+        from lightgbm_tpu.serve.server import PredictServer
+        self.booster = lgb.Booster(model_str=model_text)
+        self.server = PredictServer(self.booster, **server)
+
+    def submit(self, block: np.ndarray):
+        """A ``Future`` of the block's answer, as a client gets it."""
+        return self.server.submit(block)
+
+    def stop(self) -> None:
+        """Drains the queue and joins the server's worker."""
+        self.server.stop()
+        self.server = None
+        self.booster = None

@@ -16,7 +16,7 @@ import shutil
 import sys
 import time
 
-from . import check, program, traffic
+from . import check, program
 from .spec import CHECKOUT
 
 LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
@@ -62,7 +62,7 @@ class CompileCounter:
             self.count += 1
 
 
-def _start_trace(name: str) -> str:
+def start_trace(name: str) -> str:
     import jax
     log_dir = os.path.join(TRACE_DIR, name)
     shutil.rmtree(log_dir, ignore_errors=True)
@@ -73,11 +73,10 @@ def _start_trace(name: str) -> str:
     return log_dir
 
 
-def _reference_scores(variant, X, y, params, steps, X_hold):
+def _reference_scores(reference, variant, X, y, params, steps, X_hold):
     import jax.numpy as jnp
-    from ..reference import gbdt
-    ref = gbdt.Reference(
-        X, y, gbdt.Params.from_dict(params),
+    ref = reference.Reference(
+        X, y, reference.Params.from_dict(params),
         gh_dtype=jnp.bfloat16 if variant == "ref-bf16" else jnp.float32,
         drop_odd_rows=variant == "ref-half",
         freeze_scores=variant == "ref-frozen")
@@ -91,7 +90,6 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, device,
     caller picks by ``BENCHMARK.json``'s lists from ``Run.end_to_end`` or
     the per-layer readers, and ``compared``, which goes last on the line."""
     import jax
-    from ..reference import gbdt
     from ..trace import work, xplane
 
     cfg, mix = cell["config"], cell["traffic"]
@@ -102,6 +100,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, device,
     if variant == "quantized":
         params["use_quantized_grad"] = True
     ref_params = dict(cfg["defaults_in_force"], **cfg["params"])
+    reference = cell["spec"].reference(cell)
+    make_table = cell["spec"].make_table(mix["data"])
 
     this = Run(rows, features, peaks)
     compiles = CompileCounter()
@@ -111,11 +111,11 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, device,
         program.enable_spans()
 
     with this.phase("generate"):
-        X_all, y_all = traffic.make_table(rows + hold, features, seed,
-                                          mix["data"])
+        X_all, y_all, dataset_kw = make_table(rows + hold, features, seed,
+                                              mix["data"])
         X, y, X_hold, y_hold = (X_all[:rows], y_all[:rows], X_all[rows:],
                                 y_all[rows:])
-    init = gbdt.init_score(y)
+    init = reference.init_score(y)
 
     got_scores = []
     prog = None
@@ -123,9 +123,9 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, device,
         prog = program.Program(params)
         with this.phase("bin"):
             if mix["validate"]:
-                prog.bin(X, y, X_hold, y_hold)
+                prog.bin(X, y, X_hold, y_hold, **dataset_kw)
             else:
-                prog.bin(X, y)
+                prog.bin(X, y, **dataset_kw)
         with this.phase("upload"):
             prog.build()
         with this.phase("compile + first step"):
@@ -146,7 +146,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, device,
     log_dir = None
     if prog is not None and seconds > 0:
         if trace:
-            log_dir = _start_trace(cell["name"])
+            log_dir = start_trace(cell["name"])
         compiles.listening = True
         t0 = time.perf_counter()
         while time.perf_counter() - t0 < seconds:
@@ -189,20 +189,20 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, device,
 
     with this.phase("reference"):
         ref_scores, ref_hold, ref = _reference_scores(
-            None, X, y, ref_params, steps, X_hold)
+            reference, None, X, y, ref_params, steps, X_hold)
     print("reference: %s leaves per tree, %s rows histogrammed per tree; "
           "seconds %s" % ([len(t.leaf) + 1 for t in ref.trees],
                           [int(sum(t.smaller_rows)) for t in ref.trees],
                           ref.seconds), flush=True)
     def gaps():
-        return check.compare(gbdt.logloss, y, init, got_scores, ref_scores,
+        return check.compare(reference.loss, y, init, got_scores, ref_scores,
                              y_hold, got_hold, ref_hold)
 
     for v in (variant or "").split(","):
         if v.startswith("ref-"):
             with this.phase("reference as " + v):
                 got_scores, got_hold, _ = _reference_scores(
-                    v, X, y, ref_params, steps, X_hold)
+                    reference, v, X, y, ref_params, steps, X_hold)
             print("readings of %s: %s" % (v, gaps()), flush=True)
 
     numbers = gaps()

@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(run):
+    if not run.window_s or not run.busy_s:
+        return None
+    return 100.0 * (1.0 - run.busy_s / run.window_s)

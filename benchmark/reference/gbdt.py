@@ -124,6 +124,9 @@ def logloss(score: np.ndarray, y: np.ndarray) -> float:
     return float(np.mean(np.logaddexp(0.0, s) - y * s))
 
 
+loss = logloss      # the loss of this reference's objective (the interface)
+
+
 @dataclasses.dataclass
 class RefTree:
     """Splits in the order they were made: leaf ``leaf[i]`` sends its rows

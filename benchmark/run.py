@@ -10,6 +10,17 @@ then, as the last line of its standard output, one JSON object. With
 ``--trace 1`` its per-layer metrics, read from a profiler trace of the
 window. Without a TPU that ``trace/peaks.json`` knows it exits 3 and prints
 no result line.
+
+A cell is run by the runner of its traffic mix's ``kind``,
+``harness/<kind>.py``: a training cell (``bosch-train``, kind ``train``)
+drives ``Booster.update`` and reports ``train_iter_s``; a scoring cell
+(kind ``score``; ``bosch-score-bulk``, whose entries wait in
+``queued/bosch-score-bulk.json`` until ``BENCHMARK.json`` lists it) drives
+``PredictServer.submit`` from the mix's clients and reports
+``score_rows_per_s``. Both take the same arguments. ``--variant`` puts a control or a fault in the program's place
+(the runner's ``VARIANTS``: ``ref-bf16`` in both kinds, ``ref-half`` and
+``ref-short`` in one each), which ``correct`` has to refuse. The
+benchmark's own tests run on the CPU: ``python -m pytest benchmark/tests -q``.
 """
 from __future__ import annotations
 
@@ -26,9 +37,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main(argv=None) -> int:
-    from benchmark.harness import device, spec, train
-    # the runner of each kind of traffic mix
-    RUNNERS = {"train": train.run}
+    from benchmark.harness import device, spec
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -36,13 +45,18 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--variant", default=None,
                     help="a control or a fault instead of the program as "
-                         "configured, of %s; several ref-* with commas "
-                         "(never asked for by the driver)"
-                         % ", ".join(train.VARIANTS))
+                         "configured, one of the VARIANTS of the cell's "
+                         "runner; several ref-* with commas (never asked "
+                         "for by the driver)")
     args = ap.parse_args(argv)
     try:
         bench = spec.Spec()
         cell = bench.cell(args.workload)
+        runner = bench.runner(cell["traffic"].get("kind"))
+        if args.variant is not None and not set(
+                args.variant.split(",")) <= set(runner.VARIANTS):
+            raise spec.SpecError("variant %r, the runner has %s" % (
+                args.variant, ", ".join(runner.VARIANTS)))
         import jax
         devices, peaks = device.require_chips(jax.devices(), cell["chips"])
     except (spec.SpecError, device.NoChip) as e:
@@ -51,12 +65,7 @@ def main(argv=None) -> int:
     print("device: %s x%d; workload %s seed %d" % (
         devices[0].device_kind, len(devices), args.workload, args.seed),
         flush=True)
-    kind = cell["traffic"].get("kind")
-    if kind not in RUNNERS:
-        print("benchmark: traffic mix of kind %r, the harness runs %s" % (
-            kind, sorted(RUNNERS)), file=sys.stderr)
-        return 3
-    this, result, compared = RUNNERS[kind](
+    this, result, compared = runner.run(
         cell, args.seed, args.seconds, bool(args.trace), devices[0], peaks,
         T_PROCESS, args.variant)
     if args.trace:
@@ -70,7 +79,7 @@ def main(argv=None) -> int:
             result["breakdown"] = {
                 "device_ops": xplane.top(xplane.self_times(
                     this.trace.ops(), xplane.short_name)),
-                "idle_gaps": xplane.idle_gaps(this.trace, train.SPANS),
+                "idle_gaps": xplane.idle_gaps(this.trace, runner.SPANS),
             }
     else:
         values = {n: this.end_to_end[n]

@@ -28,7 +28,8 @@ LIMITS = {"loss1": 2e-6, "loss2": 2e-6, "step1_norm": 1e-5,
           "change2_norm": 1e-5, "holdout_loss2": 2e-6, "window_compiles": 0}
 
 
-CELLS = [w["name"] for w in spec.Spec().doc["workloads"]]
+CELLS = [w["name"] for w in spec.Spec().doc["workloads"]
+         if spec.Spec().cell(w["name"])["traffic"]["kind"] == "train"]
 
 
 def tiny_cell(name=CELLS[0]):

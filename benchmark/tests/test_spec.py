@@ -1,9 +1,12 @@
-"""A later PR adds a configuration, a traffic mix, a cell and a per-layer
-metric as new files and appended entries, and edits no file that is there."""
+"""A later PR adds a configuration, a traffic mix, a cell, a per-layer
+metric, the runner of a new kind of mix, a plain reference and a generator
+as new files and appended entries, and edits no file that is there."""
 import hashlib
 import json
 import os
 import shutil
+
+import pytest
 
 from benchmark.harness import spec
 
@@ -44,7 +47,27 @@ def test_additions_are_new_files_and_appended_entries(tmp_path):
     bench_dir = checkout / "benchmark"
     (bench_dir / "configs" / "tiny.json").write_text(json.dumps({
         "name": "tiny", "rows": 4096, "features": 8, "reduced": [],
+        "reference": "ranker",
         "params": {"objective": "binary"}, "defaults_in_force": {}}))
+    (bench_dir / "reference" / "ranker.py").write_text(
+        "def init_score(y):\n    return 0.5\n")
+    (bench_dir / "generators" / "flat.py").write_text(
+        "import numpy as np\n"
+        "def make_table(rows, features, seed, data):\n"
+        "    return (np.full((rows, features), data['value'], np.float32),\n"
+        "            np.zeros(rows, np.float32), {'group': [rows]})\n")
+    (bench_dir / "harness" / "echo.py").write_text(
+        "VARIANTS = ()\nSPANS = r'^bench::'\n"
+        "def run(cell, seed, seconds, trace, device, peaks, t_process,\n"
+        "        variant=None):\n"
+        "    X, y, extra = cell['spec'].make_table(cell['traffic']['data'])(\n"
+        "        4, cell['config']['features'], seed, cell['traffic']['data'])\n"
+        "    ref = cell['spec'].reference(cell)\n"
+        "    return X, {'init': ref.init_score(y), 'extra': extra}, {}\n")
+    (bench_dir / "traffic" / "echo-flat.json").write_text(json.dumps({
+        "kind": "echo", "data": {"generator": "flat", "value": 3.0}}))
+    (bench_dir / "limits" / "tiny-echo.json").write_text(json.dumps({
+        "limits": {}}))
     (bench_dir / "traffic" / "train-other.json").write_text(json.dumps({
         "validate": False,
         "kind": "train", "checked_steps": 2, "holdout_rows": 128,
@@ -58,6 +81,9 @@ def test_additions_are_new_files_and_appended_entries(tmp_path):
                            "reduced": [], "why": "a test"})
     doc["workloads"].append({"name": "tiny-other", "config": "tiny",
                              "traffic": "train-other", "chips": 1,
+                             "why": "a test"})
+    doc["workloads"].append({"name": "tiny-echo", "config": "tiny",
+                             "traffic": "echo-flat", "chips": 1,
                              "why": "a test"})
     doc["per_layer"].append({
         "name": "iterations_count", "unit": "iter", "better": "higher",
@@ -83,10 +109,33 @@ def test_additions_are_new_files_and_appended_entries(tmp_path):
     class FakeRun:
         iterations = 5
     assert bench.reader("iterations_count")(FakeRun()) == 5.0
+    # the runner of the new kind, the reference its configuration names and
+    # the generator its mix names, found by those names
+    assert bench.runner_kinds() == sorted(
+        spec.Spec().runner_kinds() + ["echo"])
+    echo = bench.cell("tiny-echo")
+    X, result, _ = bench.runner("echo").run(echo, 7, 0, False, None, {}, 0.0)
+    assert X.shape == (4, 8) and float(X[0, 0]) == 3.0
+    assert result == {"init": 0.5, "extra": {"group": [4]}}
+    # a mix that names no generator and a configuration that names no
+    # reference keep the ones that were there
+    train_cell = bench.cell("bosch-train")
+    assert bench.reference(train_cell).__name__.startswith(
+        "benchmark.reference.gbdt")
+    X, y, extra = bench.make_table(train_cell["traffic"]["data"])(
+        64, 8, 7, train_cell["traffic"]["data"])
+    assert X.shape == (64, 8) and y.shape == (64,) and extra == {}
+    from benchmark.harness import traffic
+    X0, y0 = traffic.make_table(64, 8, 7, train_cell["traffic"]["data"])
+    assert X.tobytes() == X0.tobytes() and y.tobytes() == y0.tobytes()
+    with pytest.raises(spec.SpecError, match="echo.*score.*train"):
+        bench.runner("serve-online")
     # the cells that were there see none of it
     assert "iterations_count" not in bench.per_layer("bosch-train")
     after = _hashes(checkout / "benchmark")
     assert {k: after[k] for k in before} == before
     assert sorted(set(after) - set(before)) == [
-        "configs/tiny.json", "limits/tiny-other.json",
-        "metrics/iterations_count.py", "traffic/train-other.json"]
+        "configs/tiny.json", "generators/flat.py", "harness/echo.py",
+        "limits/tiny-echo.json", "limits/tiny-other.json",
+        "metrics/iterations_count.py", "reference/ranker.py",
+        "traffic/echo-flat.json", "traffic/train-other.json"]

@@ -5,7 +5,8 @@ named ``/device:TPU:<n>``; its line ``XLA Ops`` holds one event per executed
 HLO operation (a ``while`` and the operations of its body both, nested), its
 line ``XLA Modules`` one event per executed program, named
 ``jit_<function>(<fingerprint>)``. Host threads are lines of ``/host:CPU``,
-where ``jax.profiler.TraceAnnotation`` ranges land. All times are
+where ``jax.profiler.TraceAnnotation`` ranges land (``Trace.host`` names a
+second and third line of one name ``<name>#2``, ``<name>#3``). All times are
 nanoseconds on one clock.
 """
 from __future__ import annotations
@@ -89,7 +90,12 @@ def load(path: str) -> Trace:
                 line.name: _line(line.events) for line in plane.lines}
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
-                host[line.name] = _line(line.events)
+                # threads of one name (``python``) each keep their line
+                name, k = line.name, 1
+                while name in host:
+                    k += 1
+                    name = "%s#%d" % (line.name, k)
+                host[name] = _line(line.events)
     return Trace(devices, host)
 
 
