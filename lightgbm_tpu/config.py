@@ -351,6 +351,15 @@ class Config:
     # TPU, and half the psum bytes on data-parallel meshes.
     use_quantized_grad: bool = False
     quant_grad_bits: int = 8         # 8 or 16
+    # LightGBM's own knobs of the mode (docs/Parameters.rst, new in
+    # 4.0.0), with its defaults. num_grad_quant_bins=B, when the caller
+    # gives it, selects the published levels: gradient in [-B/2, B/2],
+    # hessian in [0, B] (ops/quantize.published_levels). When it is not
+    # given, quant_grad_bits decides (both channels to +-(2^(bits-1)-1)),
+    # as before these parameters existed: grad_quant_bins_given().
+    num_grad_quant_bins: int = 4
+    quant_train_renew_leaf: bool = False
+    stochastic_rounding: bool = True
     # run N boosting iterations per device dispatch when nothing needs
     # per-iteration host work (boosting/gbdt.py train_batch); amortizes
     # remote-chip dispatch latency. 0/1 = per-iteration training.
@@ -466,6 +475,12 @@ class Config:
             log.fatal("top_rate + other_rate cannot be larger than 1.0")
         if self.quant_grad_bits not in (8, 16):
             log.fatal("quant_grad_bits must be 8 or 16")
+        if self.num_grad_quant_bins < 2:
+            log.fatal("num_grad_quant_bins must be >= 2")
+        if self.use_quantized_grad and self.quant_train_renew_leaf:
+            log.fatal("quant_train_renew_leaf=true is not implemented: "
+                      "leaf values come from the quantized sums "
+                      "(quant_train_renew_leaf=false)")
         self._warn_unimplemented()
         log.set_verbosity(self.verbosity)
 
@@ -511,6 +526,14 @@ class Config:
     @property
     def num_tree_per_iteration(self) -> int:
         return self.num_class if self.objective in ("multiclass", "multiclassova") else 1
+
+    def grad_quant_bins_given(self) -> int:
+        """``num_grad_quant_bins`` where the caller named it, else 0: a
+        job that sets only ``use_quantized_grad`` keeps the
+        ``quant_grad_bits`` scheme it was written against."""
+        given = any(_ALIASES.get(k, k) == "num_grad_quant_bins"
+                    for k in self.raw_params)
+        return int(self.num_grad_quant_bins) if given else 0
 
     def to_param_string(self) -> str:
         """key: value lines for the model file 'parameters:' block

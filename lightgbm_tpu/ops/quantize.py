@@ -13,6 +13,11 @@ Scheme (per boosting iteration / per tree):
 
 - ``g_scale = max|g| / qmax``, ``h_scale = max|h| / qmax`` over in-bag
   rows (the reference's per-iteration scale, gradient_discretizer.cpp).
+  With ``num_grad_quant_bins = B`` given, the levels are LightGBM's
+  own (``published_levels``): ``g_scale = max|g| / (B/2)``, gradient in
+  ``[-B/2, B/2]``; ``h_scale = max|h| / B``, hessian in ``[0, B]``.
+  Without it both channels take ``quant_grad_bits``' symmetric range
+  (``symmetric_levels``).
 - stochastic rounding ``q = floor(x / scale + u)``, ``u ~ U[0, 1)`` —
   unbiased (``E[q * scale] = x``), seeded per tree so serial and mesh
   learners draw identical integers for identical rows (the draw happens
@@ -37,6 +42,8 @@ any cap); enabling ``jax_enable_x64`` lifts 16-bit accumulation to
 int64 and restores the full range at any scale.
 """
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -96,7 +103,40 @@ def quant_warn_capped(bits: int, qmax: int, max_rows: int) -> None:
                    component="ops.quantize")
 
 
-def _quantize_gh(grad, hess, ind, key, qmax: int, dtype) -> tuple:
+class QuantLevels(NamedTuple):
+    """STATIC integer ranges of one discretization: the gradient lands
+    in ``[-grad, grad]``, the hessian in ``[hess_lo, hess]``; each
+    channel's scale is its in-bag maximum over its upper level.
+    ``stochastic`` False rounds to nearest (``u = 0.5``)."""
+    grad: int
+    hess: int
+    hess_lo: int
+    stochastic: bool = True
+
+
+def symmetric_levels(qmax: int) -> QuantLevels:
+    """The ``quant_grad_bits`` scheme: both channels to ``+-qmax``."""
+    return QuantLevels(int(qmax), int(qmax), -int(qmax))
+
+
+def published_levels(bins: int, stochastic: bool, bits: int,
+                     max_rows: int) -> QuantLevels:
+    """LightGBM's levels for ``num_grad_quant_bins = bins``
+    (gradient_discretizer.cpp: ``max|g| / (bins / 2)``,
+    ``max|h| / bins``): ``bins/2`` gradient levels a side and ``bins``
+    one-sided hessian levels. Levels the row dtype or the int32
+    accumulator cannot hold over ``max_rows`` rows are refused, not
+    capped: a coarser grid is another model than the one asked for."""
+    cap = effective_quant_max(bits, max_rows)
+    if bins > cap:
+        from ..utils import log
+        log.fatal("num_grad_quant_bins=%d does not fit quant_grad_bits=%d "
+                  "rows summed over %d rows (at most %d)"
+                  % (bins, bits, max_rows, cap))
+    return QuantLevels(bins // 2, bins, 0, bool(stochastic))
+
+
+def _quantize_gh(grad, hess, ind, key, qmax, dtype) -> tuple:
     """Discretize per-row (grad, hess) to signed integers.
 
     Parameters
@@ -105,23 +145,33 @@ def _quantize_gh(grad, hess, ind, key, qmax: int, dtype) -> tuple:
     ind : f32[N] in-bag indicator (0/1; GOSS amplification is already
         folded into grad/hess by the sample strategy)
     key : PRNG key for the stochastic rounding draw
-    qmax : STATIC target magnitude (effective_quant_max)
+    qmax : STATIC QuantLevels, or the target magnitude
+        (effective_quant_max) of the symmetric scheme as an int
     dtype : STATIC row dtype (quant_dtype)
 
     Returns (gh int[N, 4] = (q_grad, q_hess, in-bag, 1),
              qscale f32[2] = (g_scale, h_scale)).
     """
-    g = grad * ind
-    h = hess * ind
-    qmaxf = jnp.float32(qmax)
-    gs = jnp.maximum(jnp.max(jnp.abs(g)), kTinyScale) / qmaxf
-    hs = jnp.maximum(jnp.max(jnp.abs(h)), kTinyScale) / qmaxf
-    u = jax.random.uniform(key, (g.shape[0], 2))
-    qg = jnp.clip(jnp.floor(g / gs + u[:, 0]), -qmaxf, qmaxf)
-    qh = jnp.clip(jnp.floor(h / hs + u[:, 1]), -qmaxf, qmaxf)
-    gh = jnp.stack([qg, qh, ind,
-                    jnp.ones_like(ind)], axis=1).astype(dtype)
-    return gh, jnp.stack([gs, hs]).astype(jnp.float32)
+    lv = qmax if isinstance(qmax, QuantLevels) else symmetric_levels(qmax)
+    with jax.named_scope("obs_quantize"):
+        g = grad * ind
+        h = hess * ind
+        gs = jnp.maximum(jnp.max(jnp.abs(g)), kTinyScale) \
+            / jnp.float32(lv.grad)
+        hs = jnp.maximum(jnp.max(jnp.abs(h)), kTinyScale) \
+            / jnp.float32(lv.hess)
+        if lv.stochastic:
+            u = jax.random.uniform(key, (g.shape[0], 2))
+            ug, uh = u[:, 0], u[:, 1]
+        else:
+            ug = uh = jnp.float32(0.5)
+        qg = jnp.clip(jnp.floor(g / gs + ug), -jnp.float32(lv.grad),
+                      jnp.float32(lv.grad))
+        qh = jnp.clip(jnp.floor(h / hs + uh), jnp.float32(lv.hess_lo),
+                      jnp.float32(lv.hess))
+        gh = jnp.stack([qg, qh, ind,
+                        jnp.ones_like(ind)], axis=1).astype(dtype)
+        return gh, jnp.stack([gs, hs]).astype(jnp.float32)
 
 
 quantize_gh = obs_compile.instrument_jit(
@@ -185,6 +235,8 @@ def dequantize_hist(hist: jnp.ndarray, qscale) -> jnp.ndarray:
     product makes every compile see the same f32 inputs."""
     if not jnp.issubdtype(hist.dtype, jnp.integer):
         return hist
-    sv = (scale4(qscale) if qscale is not None
-          else jnp.ones(4, dtype=jnp.float32))
-    return jax.lax.optimization_barrier(hist.astype(jnp.float32) * sv)
+    with jax.named_scope("obs_dequantize"):
+        sv = (scale4(qscale) if qscale is not None
+              else jnp.ones(4, dtype=jnp.float32))
+        return jax.lax.optimization_barrier(
+            hist.astype(jnp.float32) * sv)

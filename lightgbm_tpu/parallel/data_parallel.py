@@ -1053,6 +1053,7 @@ class DataParallelTreeLearner(CapabilityMixin):
             # the scan advanced the device counter once per tree slot;
             # keep the host mirror (the _quantize_stage assert) in step
             self._quant_ctr_host += int(seeds.size)
+            self._count_discretized(int(seeds.size), self.N)
         else:
             out, recs = fn(self.bins, score0, seeds, iters, feature_mask,
                            lr)

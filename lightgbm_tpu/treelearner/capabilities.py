@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.registry import registry as obs
 from ..utils import log
 
 
@@ -120,11 +121,18 @@ class CapabilityMixin:
         self._qscale = self._qs_ones
         if not self._quantized:
             return
-        from ..ops.quantize import (effective_quant_max, quant_dtype,
-                                    quant_warn_capped)
-        self._qmax = effective_quant_max(qbits, max_rows)
+        from ..ops.quantize import (effective_quant_max, published_levels,
+                                    quant_dtype, quant_warn_capped)
+        bins = getattr(config, "grad_quant_bins_given", lambda: 0)()
+        if bins:
+            # LightGBM's own levels (num_grad_quant_bins given)
+            self._qmax = published_levels(
+                bins, getattr(config, "stochastic_rounding", True), qbits,
+                max_rows)
+        else:
+            self._qmax = effective_quant_max(qbits, max_rows)
+            quant_warn_capped(qbits, self._qmax, max_rows)
         self._qdtype = quant_dtype(qbits)
-        quant_warn_capped(qbits, self._qmax, max_rows)
         self._quant_seed = int(getattr(config, "seed", 0)) & 0x7FFFFFFF
         # base key staged once at setup: a per-tree PRNGKey(seed) would
         # be an implicit scalar transfer inside the training loop
@@ -158,8 +166,16 @@ class CapabilityMixin:
         assert self._quant_ctr_host == tree_no, \
             "quantize tree counter desynced from tree numbering " \
             "(%d != %d)" % (self._quant_ctr_host, tree_no)
+        self._count_discretized(1, int(grad.shape[0]))
         return quantize_gh(grad, hess, ind, key, self._qmax,
                            self._qdtype)
+
+    @staticmethod
+    def _count_discretized(trees: int, rows: int) -> None:
+        """``quant/trees`` and ``quant/rows_discretized``: the trees whose
+        gradients went through the discretizer and the rows it rounded."""
+        obs.inc("quant/trees", trees)
+        obs.inc("quant/rows_discretized", trees * rows)
 
     # ------------------------------------------------------------------
     def _make_cegb_fetched(self, rows: int) -> jnp.ndarray:
