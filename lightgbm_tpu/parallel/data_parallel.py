@@ -62,9 +62,9 @@ from ..treelearner.capabilities import (CapabilityMixin, train_cegb,
                                         train_monotone, train_stepwise)
 from ..treelearner.serial import (GrowState, SplitRecord, _cegb_penalty,
                                   _empty_records, _finish_split,
-                                  _go_left_by_bin, _maybe_rand_bins,
-                                  _partition_col, _record_at,
-                                  _split_hist_store, _store_info,
+                                  _maybe_rand_bins, _record_at,
+                                  _rows_go_left, _split_hist_store,
+                                  _store_info,
                                   apply_split_record, build_bundle_tables,
                                   make_root_state, rec_valid,
                                   record_is_valid)
@@ -319,12 +319,8 @@ class DataParallelTreeLearner(CapabilityMixin):
         two child scans, the candidate stores — is the serial learner's
         _finish_split; only the child-histogram computation differs."""
         meta = self.meta
-        f = jnp.maximum(rec.feature, 0)
-        col = _partition_col(bins, f, meta, self._btab, self._bundled)
-        gl = _go_left_by_bin(col, rec.threshold_bin, rec.default_left,
-                             meta.missing_type[f], meta.num_bin[f] - 1,
-                             meta.zero_bin[f], rec.is_categorical,
-                             rec.cat_mask)
+        gl = _rows_go_left(bins, rec, meta, self._btab, self._bundled,
+                           self._has_cat)
         with jax.named_scope("obs_partition"):
             on_leaf = state.leaf_of_row == leaf
             leaf_of_row = jnp.where(valid & on_leaf & ~gl, new_leaf,
@@ -526,13 +522,8 @@ class DataParallelTreeLearner(CapabilityMixin):
                 on_leaf.astype(fetched.dtype)[:, None]
                 * jax.nn.one_hot(f, fetched.shape[1],
                                  dtype=fetched.dtype))
-            col = _partition_col(bins, f, self.meta, self._btab,
-                                 self._bundled)
-            gl = _go_left_by_bin(col, rec.threshold_bin, rec.default_left,
-                                 self.meta.missing_type[f],
-                                 self.meta.num_bin[f] - 1,
-                                 self.meta.zero_bin[f],
-                                 rec.is_categorical, rec.cat_mask)
+            gl = _rows_go_left(bins, rec, self.meta, self._btab,
+                               self._bundled, self._has_cat)
             unf = 1.0 - fetched2
             unf_left = jnp.einsum(
                 "r,rf->f", (on_leaf & gl).astype(jnp.float32), unf)
@@ -789,6 +780,7 @@ class DataParallelTreeLearner(CapabilityMixin):
                 applied += 1
             if obs.enabled:
                 self._count_hist_rows(recs_h, applied)
+                self._count_partition_splits(recs_h, applied)
         return tree, self._finalize_partition(state.leaf_of_row)
 
     def _count_hist_rows(self, recs_h, applied: int) -> None:
@@ -801,6 +793,16 @@ class DataParallelTreeLearner(CapabilityMixin):
         obs.inc("grow/hist_rows_needed", int(small.sum()))
         obs.inc("grow/hist_rows_bucketed",
                 int(self._hist_rows_bucketed(small)))
+
+    @staticmethod
+    def _count_partition_splits(recs_h, applied: int) -> None:
+        """``grow/partition_splits``: applied splits, each one partition
+        pass over every row; ``grow/partition_cat_splits``: those whose
+        record is categorical, the only ones that need the table lookup
+        of ``_go_left_by_bin``."""
+        obs.inc("grow/partition_splits", applied)
+        obs.inc("grow/partition_cat_splits",
+                int(recs_h.is_categorical[:applied].sum()))
 
     def _hist_rows_bucketed(self, small: np.ndarray) -> int:
         """Rows ``_children_histograms`` passes over for splits whose

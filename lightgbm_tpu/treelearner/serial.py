@@ -276,7 +276,15 @@ def _go_left_by_bin(col: jnp.ndarray, tbin, default_left,
                     is_categorical=None, cat_mask=None) -> jnp.ndarray:
     """Training-time split direction over bin values (reference:
     DenseBin::Split templated missing handling, src/io/dense_bin.hpp;
-    categorical bitset routing ≙ DenseBin::SplitCategorical)."""
+    categorical bitset routing ≙ DenseBin::SplitCategorical).
+
+    ``is_categorical``/``cat_mask`` are given only by a program that
+    can meet a categorical split (``_partition_rec`` is the rule).
+    ``cat_mask[col]`` is a gather from the [B] table over every row of
+    the data, whatever the leaf's size, and XLA keeps it under a
+    ``where(False, ...)``: 7.3 ns a row a split on the v5e, 1,865 ms of
+    a 6,366 ms iteration at 1M rows x 254 splits (chip traces, PR
+    27-31). Data with no categorical feature must not pay it."""
     gl = col <= tbin
     gl = jnp.where((missing_type == MissingType.NAN) & (col == nan_bin),
                    default_left, gl)
@@ -285,6 +293,31 @@ def _go_left_by_bin(col: jnp.ndarray, tbin, default_left,
     if is_categorical is not None:
         gl = jnp.where(is_categorical, cat_mask[col], gl)
     return gl
+
+
+def _partition_rec(rec: SplitRecord, has_cat: bool) -> SplitRecord:
+    """``rec`` as the partition reads it: without its categorical fields
+    where the data has no categorical feature (``has_cat`` is the
+    learners' static ``_has_cat``), so that ``_go_left_by_bin`` lowers
+    no table lookup there. The one place that decides it, for every
+    learner; the sharded learner strips the record on the host, before
+    its jitted shard steps see it."""
+    if has_cat:
+        return rec
+    return rec._replace(is_categorical=None, cat_mask=None)
+
+
+def _rows_go_left(bins, rec: SplitRecord, meta, btab, bundled: bool,
+                  has_cat: bool) -> jnp.ndarray:
+    """[R] bool: the rows that ``rec`` sends left, over all of ``bins``
+    (the caller masks by leaf)."""
+    rec = _partition_rec(rec, has_cat)
+    f = jnp.maximum(rec.feature, 0)
+    col = _partition_col(bins, f, meta, btab, bundled)
+    return _go_left_by_bin(col, rec.threshold_bin, rec.default_left,
+                           meta.missing_type[f], meta.num_bin[f] - 1,
+                           meta.zero_bin[f], rec.is_categorical,
+                           rec.cat_mask)
 
 
 # ----------------------------------------------------------------------
@@ -511,12 +544,7 @@ def _split_body(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
     own child count. Fill rows hit the gh-zero dummy row, so the
     gather size selects compiled programs, never values."""
     R = bins.shape[0]
-    f = jnp.maximum(rec.feature, 0)
-    col = _partition_col(bins, f, meta, btab, bundled)
-    gl = _go_left_by_bin(col, rec.threshold_bin, rec.default_left,
-                         meta.missing_type[f], meta.num_bin[f] - 1,
-                         meta.zero_bin[f], rec.is_categorical,
-                         rec.cat_mask)
+    gl = _rows_go_left(bins, rec, meta, btab, bundled, has_cat)
     with jax.named_scope("obs_partition"):
         on_leaf = state.leaf_of_row == leaf
         leaf_of_row = jnp.where(valid & on_leaf & ~gl, new_leaf,
@@ -697,11 +725,7 @@ def _cegb_step_fn_cached(S: int, B: int, Bg: int, bundled: bool,
                 on_leaf.astype(fetched.dtype)[:, None]
                 * jax.nn.one_hot(f, fetched.shape[1],
                                  dtype=fetched.dtype))
-            col = _partition_col(bins, f, meta, btab, bundled)
-            gl = _go_left_by_bin(col, rec.threshold_bin, rec.default_left,
-                                 meta.missing_type[f],
-                                 meta.num_bin[f] - 1, meta.zero_bin[f],
-                                 rec.is_categorical, rec.cat_mask)
+            gl = _rows_go_left(bins, rec, meta, btab, bundled, has_cat)
             unf = 1.0 - fetched2
             unf_left = jnp.einsum(
                 "r,rf->f", (on_leaf & gl).astype(jnp.float32), unf)

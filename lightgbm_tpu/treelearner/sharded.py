@@ -60,9 +60,9 @@ from ..utils import log, next_pow2 as _next_pow2
 from ..utils.scalars import dev_bool, dev_i32
 from .capabilities import CapabilityMixin
 from .serial import (_finish_split, _go_left_by_bin, _maybe_rand_bins,
-                     _pad_rows_fn_cached, _record_at, _stage_gh_fn_cached,
-                     apply_split_record, make_root_state, rec_valid,
-                     record_is_valid)
+                     _pad_rows_fn_cached, _partition_rec, _record_at,
+                     _stage_gh_fn_cached, apply_split_record,
+                     make_root_state, rec_valid, record_is_valid)
 
 
 def _accum_hist(hist: jnp.ndarray, bins: jnp.ndarray,
@@ -156,7 +156,9 @@ def _shard_step(shard_bins, leaf_seg, gh_seg, hist, rec, new_leaf, meta,
     share of it), the same trick the serial learner's ``_bucket`` uses
     to keep deep-tree steps from scanning all rows. Fill rows hit the
     shard's zero pad row (gh 0), so the bucket size changes compiled
-    variants, never values."""
+    variants, never values. ``rec`` comes through ``_partition_rec``:
+    on data with no categorical feature its categorical fields are
+    None and no table lookup is lowered."""
     n_pad = shard_bins.shape[0]
     leaf = rec.leaf
     f = jnp.maximum(rec.feature, 0)
@@ -660,12 +662,13 @@ class ShardedTreeLearner(CapabilityMixin):
             with obs.scope("tree::shard_sweep"):
                 hist_small = self._zero_hist()
                 new_leaf = dev_i32(next_leaf)
+                part_rec = _partition_rec(rec, self._has_cat)
                 for k, bins_dev in pending:
                     S = min(max(_next_pow2(int(small_count) + 16),
                                 _MIN_BUCKET), self._pads[k])
                     leaf_segs[k], hist_small = _shard_step_fn(
                         bins_dev, leaf_segs[k], gh_segs[k], hist_small,
-                        rec, new_leaf, self.meta, S)
+                        part_rec, new_leaf, self.meta, S)
             # prestart the NEXT sweep before this split's read-back —
             # the worker overlaps staging with the finish dispatch +
             # sync below (one speculative staging is wasted per tree
@@ -732,13 +735,14 @@ class ShardedTreeLearner(CapabilityMixin):
                 pending = self.prefetcher.sweep()
             with obs.scope("tree::shard_sweep"):
                 hists = self._zero_khist()
+                part_spec = _partition_rec(spec, self._has_cat)
                 for k, bins_dev in pending:
                     S = min(max(_next_pow2(int(small_max) + 16),
                                 _MIN_BUCKET), self._pads[k])
                     leaf_segs[k], hists = _shard_kstep_fn(
                         bins_dev, leaf_segs[k], gh_segs[k], hists,
-                        spec, nlb, sv_dev, rf_dev, rt_dev, self.meta,
-                        K, S)
+                        part_spec, nlb, sv_dev, rf_dev, rt_dev,
+                        self.meta, K, S)
             # prestart the next round's staging only when even a fully
             # accepted round leaves splits to grow (a rejected tail
             # instead pays one stall at the loop top)

@@ -16,3 +16,18 @@ if "xla_force_host_platform_device_count" not in xla_flags:
     os.environ["XLA_FLAGS"] = (
         xla_flags + " --xla_force_host_platform_device_count=8").strip()
 os.environ.setdefault("JAX_ENABLE_X64", "0")
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def timer_on():
+    """The registry's stage timer, on for one test: counters and compile
+    accounting that are kept only under ``obs.enabled`` move."""
+    from lightgbm_tpu.obs.registry import registry
+    was = registry.timer.enabled
+    registry.timer.enable()
+    try:
+        yield registry.timer
+    finally:
+        registry.timer.enabled = was

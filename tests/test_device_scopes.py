@@ -232,16 +232,6 @@ def test_stage_of_a_name_stack(tf_op, expected):
 
 # --- (c) compile accounting ----------------------------------------------
 
-@pytest.fixture
-def timer_on():
-    was = registry.timer.enabled
-    registry.timer.enable()
-    try:
-        yield registry.timer
-    finally:
-        registry.timer.enabled = was
-
-
 def test_a_compile_is_accounted_once_under_jaxs_name(timer_on):
     def scopes_fresh_fn(x):
         return x * 3 + 1
