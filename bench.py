@@ -360,225 +360,6 @@ def run_stream_smoke() -> dict:
     }
 
 
-def run_grow_bench() -> dict:
-    """Fused-growth bench (``python bench.py grow`` or BENCH_GROW=1):
-    the whole-tree-on-device refactor's acceptance numbers, measured
-    through the trace layer's stage spans (registry scope calls — the
-    records the Perfetto exporter turns into spans):
-
-    - ``grow_dispatches_per_tree``: grow-loop dispatches per tree on
-      the fused path (tree::stage_gh + tree::root_histogram + the
-      single fused tree::split_batches per tree; acceptance ≤ 3 vs
-      ~num_leaves/kb+2 stepped);
-    - ``grow_rows_per_sec``: fused-path training row throughput;
-    - ``grow_speedup_fused_vs_stepped``: warmed wall-time ratio of the
-      stepped (per-batch host loop) path over the fused path;
-    - ``grow_stagings_per_tree_kbatch`` / ``_stepped`` and
-      ``grow_staging_cut_kbatch``: out-of-core shard stagings per tree
-      with K-splits-per-sweep frontier batching vs one-split-per-sweep
-      (the ≥4x acceptance metric at num_leaves=63);
-    - ``grow_dispatches_per_iteration``: PIPELINED boosting — training
-      stage-scope calls per ITERATION with the batched quantized scan
-      (gradients + bagging draw + gh staging + whole-tree growth +
-      score update all inside one ``train_many`` dispatch per batch;
-      acceptance ≤ 4 vs ~6+ looped), plus
-      ``pipeline_speedup_batched_vs_looped`` (warmed wall-time ratio of
-      the per-iteration loop over the batched scan, same config).
-
-    Env knobs: BENCH_GROW_ROWS (200k), BENCH_GROW_ITERS (3),
-    BENCH_GROW_LEAVES (63), BENCH_GROW_K (16), BENCH_GROW_OOC_ROWS
-    (120k), BENCH_GROW_BATCH (8)."""
-    import shutil
-    import tempfile
-
-    import jax
-
-    from lightgbm_tpu.boosting import create_boosting
-    from lightgbm_tpu.config import Config
-    from lightgbm_tpu.io.dataset import BinnedDataset
-    from lightgbm_tpu.io.shards import ShardedBinnedDataset
-    from lightgbm_tpu.obs import health as obs_health
-    from lightgbm_tpu.obs.registry import registry as obs_registry
-
-    enable_compile_cache()
-    platform = jax.devices()[0].platform
-    obs_health.record_backend(platform, source="bench_grow")
-
-    rows = int(os.environ.get("BENCH_GROW_ROWS", 200_000))
-    iters = int(os.environ.get("BENCH_GROW_ITERS", 3))
-    leaves = int(os.environ.get("BENCH_GROW_LEAVES", 63))
-    kfront = int(os.environ.get("BENCH_GROW_K", 16))
-    n_feat = 28
-    X, y = make_higgs_like(rows, n_feat)
-    base = {"objective": "binary", "num_leaves": leaves, "max_bin": 255,
-            "verbosity": -1, "min_data_in_leaf": 100,
-            "tree_learner": "serial"}
-    _stage("grow_start", rows=rows, leaves=leaves, platform=platform)
-
-    GROW_SCOPES = ("tree::stage_gh", "tree::root_histogram",
-                   "tree::split_batches")
-
-    def measure(fused: bool):
-        params = dict(base, tpu_fused_tree=fused,
-                      num_iterations=iters + 1)
-        cfg = Config.from_params(params)
-        ds = BinnedDataset.from_matrix(X, cfg, label=y)
-        booster = create_boosting(cfg, ds)
-        booster.train_one_iter()            # warm compiles
-        jax.block_until_ready(booster.train_score)
-        obs_registry.reset()
-        obs_registry.enable()
-        t0 = time.time()
-        for _ in range(iters):
-            booster.train_one_iter()
-        jax.block_until_ready(booster.train_score)
-        secs = time.time() - t0
-        phases = obs_registry.phases()
-        calls = sum(phases.get(s, {}).get("calls", 0)
-                    for s in GROW_SCOPES)
-        obs_registry.disable()
-        return secs, calls / max(iters, 1)
-
-    t_fused, disp_fused = measure(True)
-    t_stepped, disp_stepped = measure(False)
-    rps = rows * iters / max(t_fused, 1e-9)
-    speedup = t_stepped / max(t_fused, 1e-9)
-    _stage("grow_serial", rows=rows, iters=iters,
-           t_fused=round(t_fused, 2), t_stepped=round(t_stepped, 2),
-           grow_dispatches_per_tree=disp_fused,
-           grow_dispatches_per_tree_stepped=disp_stepped,
-           grow_rows_per_sec=round(rps, 1),
-           grow_speedup_fused_vs_stepped=round(speedup, 3))
-
-    # --- out-of-core: shard stagings per tree, K-batch vs per-split ---
-    ooc_rows = int(os.environ.get("BENCH_GROW_OOC_ROWS", 120_000))
-    ooc_iters = 2
-    Xo, yo = make_higgs_like(ooc_rows, n_feat, seed=7)
-    chunk = max(ooc_rows // 6, 1)
-
-    def source():
-        for lo in range(0, ooc_rows, chunk):
-            yield Xo[lo:lo + chunk], yo[lo:lo + chunk].astype(np.float32)
-
-    def measure_ooc(K):
-        params = dict(base, tpu_frontier_splits=K,
-                      num_iterations=ooc_iters + 1,
-                      bin_construct_sample_cnt=50_000)
-        spill = tempfile.mkdtemp(prefix="lgbm_tpu_grow_")
-        try:
-            ds = ShardedBinnedDataset.from_chunk_source(
-                source, Config.from_params(dict(params)), spill,
-                shard_rows=max(ooc_rows // 4, 4096))
-            booster = create_boosting(
-                Config.from_params(dict(params)), ds)
-            booster.train_one_iter()
-            jax.block_until_ready(booster.train_score)
-            obs_registry.reset()
-            obs_registry.enable()
-            staged0 = obs_registry.count("io/shards_staged")
-            for _ in range(ooc_iters):
-                booster.train_one_iter()
-            jax.block_until_ready(booster.train_score)
-            staged = obs_registry.count("io/shards_staged") - staged0
-            obs_registry.disable()
-            return staged / ooc_iters
-        finally:
-            shutil.rmtree(spill, ignore_errors=True)
-
-    st_k = measure_ooc(kfront)
-    st_1 = measure_ooc(1)
-    cut = st_1 / max(st_k, 1e-9)
-    _stage("grow_oocore", rows=ooc_rows, K=kfront,
-           grow_stagings_per_tree_kbatch=st_k,
-           grow_stagings_per_tree_stepped=st_1,
-           grow_staging_cut_kbatch=round(cut, 2))
-
-    # --- pipelined boosting: dispatches per ITERATION, batched vs
-    # looped (quantized + bagging — the full on-device iteration) ------
-    batch_n = int(os.environ.get("BENCH_GROW_BATCH", 8))
-    pipe_iters = 2 * batch_n
-    # every training stage scope that wraps device dispatch work in the
-    # boosting loop; the batched path folds all of them into ONE
-    # tree::train_batch_dispatch per batch_n iterations
-    PIPE_SCOPES = GROW_SCOPES + (
-        "gbdt::gradients", "gbdt::bagging", "gbdt::score_update",
-        "gbdt::eval_metrics", "tree::train_batch_dispatch")
-
-    def measure_pipeline(batched: bool):
-        params = dict(base, tree_learner="data", mesh_shape="data=1",
-                      use_quantized_grad=True,
-                      bagging_fraction=0.8, bagging_freq=1,
-                      tpu_batch_iterations=(batch_n if batched else 0),
-                      num_iterations=pipe_iters + 1)
-        cfg = Config.from_params(params)
-        ds = BinnedDataset.from_matrix(X, cfg, label=y)
-        booster = create_boosting(cfg, ds)
-        booster.train_one_iter()            # iter 0 + warm compiles
-        if batched:
-            assert booster.can_train_batched(), \
-                "quantized+bagging must be batch-eligible"
-            booster.train_batch(batch_n)    # warm the scan compile
-        else:
-            booster.train_one_iter()
-        jax.block_until_ready(booster.train_score)
-        obs_registry.reset()
-        obs_registry.enable()
-        t0 = time.time()
-        if batched:
-            for _ in range(pipe_iters // batch_n):
-                booster.train_batch(batch_n)
-        else:
-            for _ in range(pipe_iters):
-                booster.train_one_iter()
-        jax.block_until_ready(booster.train_score)
-        secs = time.time() - t0
-        phases = obs_registry.phases()
-        calls = sum(phases.get(s, {}).get("calls", 0)
-                    for s in PIPE_SCOPES)
-        obs_registry.disable()
-        return secs, calls / max(pipe_iters, 1)
-
-    t_batched, disp_iter = measure_pipeline(True)
-    t_looped, disp_iter_looped = measure_pipeline(False)
-    pipe_speedup = t_looped / max(t_batched, 1e-9)
-    _stage("grow_pipeline", rows=rows, batch=batch_n,
-           t_batched=round(t_batched, 2), t_looped=round(t_looped, 2),
-           grow_dispatches_per_iteration=round(disp_iter, 3),
-           grow_dispatches_per_iteration_looped=round(
-               disp_iter_looped, 3),
-           pipeline_speedup_batched_vs_looped=round(pipe_speedup, 3))
-    if disp_iter > 4.0:
-        print("Warning: grow_dispatches_per_iteration %.2f exceeds the "
-              "pipelined-boosting acceptance bound of 4" % disp_iter)
-
-    return {
-        "metric": "grow_speedup_fused_vs_stepped",
-        "value": round(speedup, 3),
-        "unit": "x wall-time speedup, fused whole-tree growth vs the "
-                "stepped host loop on %s (%.0fk rows x %df, %d leaves, "
-                "%d iters; %.0f grow dispatches/tree fused vs %.0f "
-                "stepped; out-of-core K=%d cuts shard stagings "
-                "%.1f->%.1f per tree = %.2fx; pipelined boosting: "
-                "%.2f dispatches/iteration batched-quantized vs %.1f "
-                "looped, %.2fx wall)"
-                % (platform, rows / 1e3, n_feat, leaves, iters,
-                   disp_fused, disp_stepped, kfront, st_1, st_k, cut,
-                   disp_iter, disp_iter_looped, pipe_speedup),
-        "backend": platform,
-        "grow_dispatches_per_tree": disp_fused,
-        "grow_dispatches_per_tree_stepped": disp_stepped,
-        "grow_rows_per_sec": round(rps, 1),
-        "grow_speedup_fused_vs_stepped": round(speedup, 3),
-        "grow_stagings_per_tree_kbatch": st_k,
-        "grow_stagings_per_tree_stepped": st_1,
-        "grow_staging_cut_kbatch": round(cut, 2),
-        "grow_dispatches_per_iteration": round(disp_iter, 3),
-        "grow_dispatches_per_iteration_looped": round(disp_iter_looped,
-                                                      3),
-        "pipeline_speedup_batched_vs_looped": round(pipe_speedup, 3),
-    }
-
-
 def run_oocore_bench() -> dict:
     """Out-of-core smoke (``python bench.py oocore`` or BENCH_OOCORE=1):
     build a dataset whose binned payload EXCEEDS a configured HBM budget
@@ -1779,21 +1560,6 @@ def main() -> None:
         print(json.dumps(result))
         if not (result["validate_ok"] and result["merge_ok"]):
             sys.exit(1)
-        return
-    if (os.environ.get("BENCH_GROW")
-            or (len(sys.argv) > 1 and sys.argv[1] == "grow")):
-        try:
-            result = run_grow_bench()
-        except Exception as e:
-            result = {"metric": "grow_speedup_fused_vs_stepped",
-                      "value": 0.0,
-                      "unit": "x (FAILED: %s: %s)"
-                              % (type(e).__name__, str(e)[:300]),
-                      "grow_dispatches_per_tree": 0,
-                      "grow_rows_per_sec": 0.0}
-            print(json.dumps(result))
-            sys.exit(1)
-        print(json.dumps(result))
         return
     if (os.environ.get("BENCH_OOCORE")
             or (len(sys.argv) > 1 and sys.argv[1] == "oocore")):

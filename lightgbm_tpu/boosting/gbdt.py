@@ -531,8 +531,8 @@ class GBDT:
         returns True when training should stop (an iteration grew no
         tree in any class). Caller must have checked
         can_train_batched()."""
-        from ..treelearner.serial import (apply_split_record,
-                                          record_is_valid)
+        from ..treelearner.grow import (apply_split_record,
+                                        record_is_valid)
         from .sample_strategy import SampleStrategy
         t_batch0 = time.perf_counter()
         learner = self.learner

@@ -373,12 +373,6 @@ class Config:
     # 0/1 = evaluate every iteration (every batch boundary when
     # tpu_batch_iterations is on).
     tpu_eval_iterations: int = 0
-    # fused whole-tree growth (treelearner/serial.py): histogram →
-    # split scan → partition for the entire tree runs as ONE jitted
-    # while_loop dispatch with a device-resident frontier, reading back
-    # only the finished [L-1] split-record buffer (bit-identical to the
-    # stepped host loop). False keeps the legacy per-batch host loop.
-    tpu_fused_tree: bool = True
     # out-of-core frontier batching (treelearner/sharded.py): speculate
     # up to K pending best-split candidates per shard sweep — each
     # staging applies K partition updates and histograms K children —

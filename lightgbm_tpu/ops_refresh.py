@@ -10,11 +10,6 @@ from .ops.split import SplitParams
 def refresh_learner_params(learner, config) -> None:
     learner.params = SplitParams.from_config(config)
     learner.max_depth = int(config.max_depth)
-    if hasattr(learner, "_fused_growth"):
-        # serial learner: the fused/stepped choice is re-readable (the
-        # stepped path is the documented bit-parity fallback)
-        learner._fused_growth = bool(
-            getattr(config, "tpu_fused_tree", True))
     if hasattr(learner, "_K"):
         learner._K = max(1, min(
             int(getattr(config, "tpu_frontier_splits", 8)),

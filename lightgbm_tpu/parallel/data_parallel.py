@@ -14,25 +14,23 @@ reduces over the sharded row axis — XLA inserts the cross-device psum
 its two collectives. The row partition update is a purely local sharded
 elementwise op, like the reference's per-rank ``DataPartition::Split``.
 
-Two departures from the single-chip learner (treelearner/serial.py):
+The grower — the whole-tree loop (one dispatch and one [L-1] record
+read-back per tree, where the reference syncs rank↔rank per split), the
+split step, the per-leaf histogram store — is treelearner/grow.py, the
+code the single-chip learner (treelearner/serial.py) runs too. This
+learner's own part is the histogram (``_mesh_hist``) and the sharding:
 
-- the smaller-child row *compaction* (``jnp.nonzero``) is replaced by a
-  masked full-length histogram pass — compaction is a global reshuffle
-  that would force cross-device gathers, while a mask rides the existing
-  sharding. The histogram-subtraction trick still halves the work: only
-  the smaller child is histogrammed, the sibling comes from
-  parent − smaller.
-- the whole tree grows in ONE device dispatch: a ``lax.while_loop``
-  argmaxes the next leaf, applies the split, and scans both children,
-  writing each winning split into a [L-1] record buffer that the host
-  reads back once per tree. (The reference syncs rank↔rank per split;
-  here a per-split host round-trip would be paid 254 times per tree at
-  255 leaves.) Because there is no data-dependent gather size, the loop
-  needs no host input at all, unlike the serial learner's bucketed
-  batching. Features whose per-split host state steers the scan (CEGB
-  penalties, intermediate monotone bounds, per-node feature masks)
-  fall back to a stepwise host loop, exactly like the serial learner —
-  via the shared drivers in treelearner/capabilities.py.
+- on a one-device mesh the smaller child's rows are compacted before
+  they are histogrammed, as in the serial learner; on a sharded mesh the
+  compaction is replaced by a masked full-length histogram pass —
+  compaction is a global reshuffle that would force cross-device
+  gathers, while a mask rides the existing sharding. The
+  histogram-subtraction trick still halves the work: only the smaller
+  child is histogrammed, the sibling comes from parent − smaller.
+- features whose per-split host state steers the scan (CEGB penalties,
+  intermediate monotone bounds, per-node feature masks) fall back to a
+  stepwise host loop, exactly like the serial learner — via the shared
+  drivers in treelearner/capabilities.py.
 
 EFB stays *bundled* across the mesh (reference: bundles are built before
 ReduceScatter, src/io/dataset.cpp:107 + data_parallel_tree_learner.cpp:185):
@@ -58,16 +56,16 @@ from ..ops.histogram import (build_histogram, mask_gh,
 from ..ops.quantize import dequantize_sums, sum_gh
 from ..ops.split import (FeatureMeta, SplitParams, calculate_leaf_output,
                          find_best_split)
-from ..treelearner.capabilities import (CapabilityMixin, train_cegb,
-                                        train_monotone, train_stepwise)
-from ..treelearner.serial import (GrowState, SplitRecord, _cegb_penalty,
-                                  _empty_records, _finish_split,
-                                  _maybe_rand_bins, _record_at,
-                                  _rows_go_left, _split_hist_store,
-                                  _store_info,
-                                  apply_split_record, build_bundle_tables,
-                                  make_root_state, rec_valid,
-                                  record_is_valid)
+from ..treelearner.capabilities import (CapabilityMixin, _cegb_penalty,
+                                        train_cegb, train_monotone,
+                                        train_stepwise)
+from ..treelearner.grow import (GrowState, SplitRecord,
+                                _compact_child_hist, _compact_sizes,
+                                _grow_tree, _maybe_rand_bins, _record_at,
+                                _rows_go_left, _split_step, _store_info,
+                                _subtract_child_hists, apply_split_record,
+                                build_bundle_tables, make_root_state,
+                                rec_valid, record_is_valid)
 from ..utils import log
 
 
@@ -313,160 +311,60 @@ class DataParallelTreeLearner(CapabilityMixin):
                          leaf, new_leaf, valid, mask_left, mask_right,
                          rand_seed=0, extra_trees=None, pen_left=None,
                          pen_right=None, qscale=None):
-        """Apply one chosen split and scan both children. ``valid``
-        guards every state write (loop steps after the no-more-splits
-        point must leave state untouched). The tail — depth gating, the
-        two child scans, the candidate stores — is the serial learner's
-        _finish_split; only the child-histogram computation differs."""
-        meta = self.meta
-        gl = _rows_go_left(bins, rec, meta, self._btab, self._bundled,
-                           self._has_cat)
-        with jax.named_scope("obs_partition"):
-            on_leaf = state.leaf_of_row == leaf
-            leaf_of_row = jnp.where(valid & on_leaf & ~gl, new_leaf,
-                                    state.leaf_of_row)
-            leaf_of_row = jax.lax.with_sharding_constraint(
-                leaf_of_row, self.row_sharding)
-
-        smaller_is_left = rec.left_total_count <= rec.right_total_count
-        (hists, hist_left, hist_right, mask_left,
-         mask_right) = self._children_histograms(
-            bins, state, rec, leaf, new_leaf, leaf_of_row,
-            smaller_is_left, valid, mask_left, mask_right, qscale)
-        state = state._replace(leaf_of_row=leaf_of_row, hists=hists)
-        return _finish_split(
-            state, rec, leaf, new_leaf, valid, hist_left, hist_right,
-            mask_left, mask_right, meta, self.params,
-            max_depth=self.max_depth,
+        """grow.py's split step over this learner's data, sharding and
+        child histograms (``_children_histograms``)."""
+        return _split_step(
+            bins, state, rec, leaf, new_leaf, valid, mask_left,
+            mask_right, self.meta, self.params, self._btab,
+            self._children_histograms, bundled=self._bundled,
+            has_cat=self._has_cat, max_depth=self.max_depth,
             extra_trees=(self._extra_trees if extra_trees is None
                          else extra_trees),
-            has_cat=self._has_cat, rand_seed=rand_seed,
+            row_sharding=self.row_sharding, rand_seed=rand_seed,
             pen_left=pen_left, pen_right=pen_right, qscale=qscale)
 
     def _children_histograms(self, bins, state, rec, leaf, new_leaf,
                              leaf_of_row, smaller_is_left, valid,
                              mask_left, mask_right, qscale=None):
         """The updated per-leaf store, the cross-device-summed child
-        histograms and the per-child scan masks. Base learner: masked
-        histogram of the smaller child over the full sharded row space
-        (the analogue of the reference ranks histogramming their local
-        leaf rows then ReduceScatter-summing,
-        data_parallel_tree_learner.cpp:185), sibling by subtraction —
-        BIT-EXACT in quantized-integer mode — and both children stored
-        by the serial learner's ``_split_hist_store``, which reads the
-        store before it writes it (why: its docstring). Voting-parallel
+        histograms and the per-child scan masks. Base learner: the
+        smaller child's histogram, its sibling by subtraction
+        (``_subtract_child_hists``). On one device the child's rows are
+        compacted first, so histogram cost tracks the child's size
+        (the reference's DataPartition + per-leaf iterators,
+        data_partition.hpp:21); a sharded mesh keeps the masked
+        histogram over the full row space (the analogue of the
+        reference ranks histogramming their local leaf rows then
+        ReduceScatter-summing, data_parallel_tree_learner.cpp:185):
+        compaction across shards would need an all-to-all, and each
+        shard already scans only its local rows. Voting-parallel
         overrides this with the reduced-comm vote and skips the
         store."""
-        small_id = jnp.where(smaller_is_left, leaf, new_leaf)
-        small_sel = leaf_of_row == small_id
-        small_totals = jnp.stack([
-            jnp.where(smaller_is_left, rec.left_sum_grad,
-                      rec.right_sum_grad),
-            jnp.where(smaller_is_left, rec.left_sum_hess,
-                      rec.right_sum_hess),
-            jnp.where(smaller_is_left, rec.left_count, rec.right_count),
-            jnp.where(smaller_is_left, rec.left_total_count,
-                      rec.right_total_count)])
-        if self.mesh.devices.size == 1:
-            # single-chip fast path: compact the child's rows first so
-            # histogram cost tracks the child size, not the full row
-            # space (the reference's DataPartition + per-leaf iterators,
-            # data_partition.hpp:21; the CUDA learner's equivalent win
-            # is cuda_data_partition's leaf-indexed row sets)
-            hist_small = self._compact_child_hist(
-                bins, state.gh, small_sel, small_totals)
-        else:
+        def small_hist(mask, totals):
+            if self.mesh.devices.size == 1:
+                return _compact_child_hist(
+                    bins, state.gh, mask, totals, _compact_sizes(self.R),
+                    self._mesh_hist)
             # dtype-preserving mask (an f32 multiply would de-quantize
             # integer gh rows)
-            hist_small = self._mesh_hist(
-                bins, mask_gh(state.gh, small_sel), small_totals)
-        hists, hist_left, hist_right = _split_hist_store(
-            state.hists, leaf, new_leaf, hist_small, smaller_is_left,
-            valid)
-        return hists, hist_left, hist_right, mask_left, mask_right
+            return self._mesh_hist(bins, mask_gh(state.gh, mask), totals)
 
-    def _compact_sizes(self) -> list:
-        """The compaction ladder's bucket sizes, largest first: half
-        the rows, then a quarter of the last until 16,384 or under."""
-        sizes = []
-        s = -(-self.R // 2)
-        while s > 16384:
-            sizes.append(s)
-            s = -(-s // 4)
-        sizes.append(s)
-        return sizes
-
-    @jax.named_scope("obs_compact")
-    def _compact_child_hist(self, bins, gh, mask, totals):
-        """Gather the smaller child's rows into a static power-ladder
-        bucket (``lax.switch`` over compiled sizes) and histogram only
-        those. A leaf-wise tree's total smaller-child row count is
-        ~N·log2(L)/2, so this cuts per-tree histogram work by ~50x at
-        255 leaves vs masked full-row scans — the single-chip analogue
-        of the reference's per-leaf row iterators
-        (data_partition.hpp:119 GetIndexOnLeaf). The scatter/gather
-        compaction itself is O(R) bandwidth, far below the histogram's
-        O(S·F) compute. Sharded meshes keep the masked full-row scan
-        (compaction across shards would need an all-to-all; each shard
-        already scans only its local rows)."""
-        R = bins.shape[0]
-        sizes = self._compact_sizes()
-        count = totals[3].astype(jnp.int32)     # rows on the leaf
-        pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
-        rows = jnp.arange(R, dtype=jnp.int32)
-
-        def make_branch(S):
-            @jax.named_scope("obs_bucket_%d" % S)
-            def branch(_):
-                idx = jnp.zeros((S,), dtype=jnp.int32)
-                idx = idx.at[jnp.where(mask, pos, S)].set(rows,
-                                                          mode="drop")
-                keep = (jnp.arange(S, dtype=jnp.int32)
-                        < count)[:, None]
-                gh_keep = jnp.where(keep, gh[idx],
-                                    jnp.zeros((), dtype=gh.dtype))
-                return self._mesh_hist(bins[idx], gh_keep, totals)
-            return branch
-
-        k = jnp.clip(
-            jnp.sum(jnp.asarray(sizes, dtype=jnp.int32) >= count) - 1,
-            0, len(sizes) - 1)
-        return jax.lax.switch(k, [make_branch(S) for S in sizes], 0)
+        return _subtract_child_hists(
+            state, rec, leaf, new_leaf, leaf_of_row, smaller_is_left,
+            valid, small_hist) + (mask_left, mask_right)
 
     # ------------------------------------------------------------------
     def _tree_impl(self, bins, state: GrowState, feature_mask, rand_seed,
                    qscale):
-        """Grow the whole tree in one dispatch: while splits remain, the
-        device argmaxes the next leaf (the argmax the reference reaches
-        via SyncUpGlobalBestSplit), applies it, and appends the record.
-        Exits as soon as no positive-gain candidate is left, so a short
-        tree costs no wasted iterations."""
-        kb = self.L - 1
+        """Grow the whole tree in one dispatch (``_grow_tree``)."""
+        def step(state, rec, leaf, new_leaf, valid):
+            return self._mesh_split_body(bins, state, rec, leaf, new_leaf,
+                                         valid, feature_mask,
+                                         feature_mask,
+                                         rand_seed=rand_seed,
+                                         qscale=qscale)
 
-        def cond(carry):
-            i, _, _, cont = carry
-            return cont & (i < kb)
-
-        def body(carry):
-            i, state, recs, _ = carry
-            with jax.named_scope("obs_pick_leaf"):
-                best = jnp.argmax(state.gain).astype(jnp.int32)
-                rec = _record_at(state, best)
-                valid = rec_valid(rec)
-                recs = jax.tree_util.tree_map(
-                    lambda buf, v: buf.at[i].set(v), recs, rec)
-            new_leaf = (i + 1).astype(jnp.int32)
-            state = self._mesh_split_body(bins, state, rec, best,
-                                          new_leaf, valid, feature_mask,
-                                          feature_mask,
-                                          rand_seed=rand_seed,
-                                          qscale=qscale)
-            return i + 1, state, recs, valid
-
-        carry = (jnp.int32(0), state, _empty_records(kb, self.B),
-                 jnp.asarray(True))
-        _, state, recs, _ = jax.lax.while_loop(cond, body, carry)
-        return state, recs
+        return _grow_tree(state, step, self.L, self.B)
 
     def _step_impl(self, bins, state: GrowState, leaf, new_leaf,
                    mask_left, mask_right, rand_seed, qscale):
@@ -630,7 +528,7 @@ class DataParallelTreeLearner(CapabilityMixin):
                                   self._cegb_used, self._cegb_fetched,
                                   self._qscale)
 
-    def _cegb_step(self, state, leaf, k, allowed, feature_mask, smaller):
+    def _cegb_step(self, state, leaf, k, allowed, feature_mask):
         state, rec, self._cegb_used, self._cegb_fetched = \
             self._cegb_step_fn(self.bins, state, jnp.int32(leaf),
                                jnp.int32(k), feature_mask,
@@ -650,8 +548,7 @@ class DataParallelTreeLearner(CapabilityMixin):
         return self._mono_root_fn(self.bins, gh, feature_mask,
                                   jnp.int32(rand_seed), self._qscale)
 
-    def _mono_step(self, state, leaf, k, allowed, feature_mask, bounds,
-                   smaller):
+    def _mono_step(self, state, leaf, k, allowed, feature_mask, bounds):
         if self._mono_step_fn is None:
             self._mono_step_fn = obs_compile.instrument_jit(
                 "mesh.mono_step", self._mono_step_impl,
@@ -675,7 +572,7 @@ class DataParallelTreeLearner(CapabilityMixin):
             feature_mask, self._qscale)
 
     def _node_step(self, state, leaf, k, allowed, mask_left, mask_right,
-                   rand_seed, smaller):
+                   rand_seed):
         if self._step_fn is None:
             self._step_fn = obs_compile.instrument_jit(
                 "mesh.step", self._step_impl,
@@ -811,7 +708,7 @@ class DataParallelTreeLearner(CapabilityMixin):
         row space on a sharded mesh."""
         if self.mesh.devices.size != 1:
             return self.R * len(small)
-        sizes = np.asarray(self._compact_sizes()[::-1])
+        sizes = np.asarray(_compact_sizes(self.R)[::-1])
         return sizes[np.minimum(np.searchsorted(sizes, small),
                                 len(sizes) - 1)].sum()
 

@@ -27,12 +27,14 @@ def create_tree_learner(config, dataset, mesh=None):
         from .sharded import ShardedTreeLearner
         return ShardedTreeLearner(config, dataset)
     if name in ("serial",):
-        # On an accelerator the serial learner pays a host round-trip
-        # per split batch (254 splits/tree). The 1-device-mesh data
-        # learner grows the whole tree in ONE dispatch and is pinned
-        # bit-exact to serial (tests/test_parallel_learners.py), so the
-        # DEFAULT promotes — an explicitly requested serial learner is
-        # honored, as are forced splits (serial-scan only).
+        # On an accelerator the DEFAULT is the 1-device-mesh data
+        # learner: the learner the benchmark's cells measure. Both run
+        # grow.py's whole-tree loop and split step and grow the same
+        # trees (tests/test_fused_growth.py TestSerialVsMeshParity);
+        # the serial learner pads rows and features to shared shapes,
+        # which the CPU tests want and the chip has not measured. An
+        # explicitly requested serial learner is honored, as are forced
+        # splits (serial only). One learner class is ROADMAP D1.
         explicit = any(k in getattr(config, "raw_params", {})
                        for k in ("tree_learner", "tree", "tree_type",
                                  "tree_learner_type"))
@@ -42,8 +44,7 @@ def create_tree_learner(config, dataset, mesh=None):
             from ..parallel import DataParallelTreeLearner, make_mesh
             log.info("tree_learner=serial on an accelerator: using the "
                      "1-device-mesh whole-tree learner (identical "
-                     "trees, one host sync per tree instead of one "
-                     "per split)")
+                     "trees)")
             return DataParallelTreeLearner(config, dataset, make_mesh(1))
         return SerialTreeLearner(config, dataset)
     import jax
@@ -52,12 +53,9 @@ def create_tree_learner(config, dataset, mesh=None):
                             VotingParallelTreeLearner, make_mesh)
     if mesh is None:
         if len(jax.devices()) < 2:
-            # still honor the request on a 1-device mesh: the mesh
-            # learners grow the whole tree in ONE dispatch (one
-            # read-back per tree), which also makes them the faster
-            # engine when host round-trips dominate (e.g. big-N CPU)
+            # still honor the request on a 1-device mesh
             log.info("tree_learner=%s on a single device: using a "
-                     "1-device mesh (whole-tree dispatch)" % name)
+                     "1-device mesh" % name)
         # mesh_shape (e.g. "data=8") bounds the device count; the
         # 1-D GBDT learners use the first axis extent
         n_dev = None

@@ -26,6 +26,7 @@ import numpy as np
 
 from ..obs.registry import registry as obs
 from ..utils import log
+from .grow import apply_split_record, record_is_valid
 
 
 class CapabilityMixin:
@@ -265,6 +266,18 @@ class CapabilityMixin:
                                                              mono_inner)
 
 
+def _cegb_penalty(params, count, used, coupled, unfetched, lazy):
+    """Per-feature CEGB gain penalty for scanning one leaf (reference:
+    CostEfficientGradientBoosting::DeltaGain,
+    cost_effective_gradient_boosting.hpp:80-99): split penalty scaled by
+    leaf size + coupled penalty for model-new features + lazy per-row
+    fetch cost for rows that have not used the feature yet."""
+    pen = params.cegb_penalty_split * count + coupled * (~used)
+    if lazy is not None:
+        pen = pen + lazy * unfetched
+    return params.cegb_tradeoff * pen
+
+
 # ----------------------------------------------------------------------
 # Host-side training drivers. Each steers per-split device steps through
 # the learner's adapter methods; the loops are identical for the serial
@@ -276,8 +289,6 @@ def train_cegb(learner, tree, gh, feature_mask):
     """CEGB growth: one host round-trip per split so penalties track
     the evolving used/fetched state (reference: the DeltaGain calls
     inside FindBestSplitsFromHistograms, serial_tree_learner.cpp:375+)."""
-    from .serial import apply_split_record, record_is_valid
-
     if getattr(learner, "_forced", None) is not None \
             or learner._constraint_groups is not None:
         log.warning("CEGB runs without forced splits / per-node "
@@ -293,10 +304,8 @@ def train_cegb(learner, tree, gh, feature_mask):
         leaf = int(pending.leaf)
         apply_split_record(tree, learner.dataset, pending)
         allowed = learner._splittable(int(tree.leaf_depth[leaf]))
-        smaller = min(float(pending.left_total_count),
-                      float(pending.right_total_count))
         state, rec = learner._cegb_step(state, leaf, k, allowed,
-                                        feature_mask, smaller)
+                                        feature_mask)
         # jaxlint: disable=JLT001 -- per-split sync (CEGB host loop)
         pending = jax.device_get(rec)
     return state
@@ -314,7 +323,6 @@ def train_monotone(learner, tree, gh, feature_mask, rand_seed):
     monotone_constraints.hpp:856) — the scalar-bound candidates from
     the shared step are overwritten by an ``_adv_scan`` per child."""
     from .monotone import AdvancedMonotoneTracker
-    from .serial import apply_split_record, record_is_valid
 
     tracker = learner._mono_tracker
     advanced = isinstance(tracker, AdvancedMonotoneTracker)
@@ -371,11 +379,9 @@ def train_monotone(learner, tree, gh, feature_mask, rand_seed):
                         float(pending.right_count),
                         float(pending.right_total_count))
         allowed = learner._splittable(int(tree.leaf_depth[leaf]))
-        smaller = min(float(pending.left_total_count),
-                      float(pending.right_total_count))
         applied_tbin = int(pending.threshold_bin)
         state, rec, gains_d = learner._mono_step(
-            state, leaf, k, allowed, feature_mask, bounds, smaller)
+            state, leaf, k, allowed, feature_mask, bounds)
         if advanced:
             # overwrite both children's candidates with the
             # per-threshold-constrained scan
@@ -414,8 +420,6 @@ def train_monotone(learner, tree, gh, feature_mask, rand_seed):
 def train_stepwise(learner, tree, state, rec, feature_mask, rand_seed=0):
     """One host round-trip per split — needed when per-node feature
     masks depend on the host-side feature path."""
-    from .serial import apply_split_record, record_is_valid
-
     # jaxlint: disable=JLT001 -- per-node feature masks are computed
     # from the host-side feature path, so this driver syncs per split
     # by design (its docstring is the contract)
@@ -428,14 +432,11 @@ def train_stepwise(learner, tree, state, rec, feature_mask, rand_seed=0):
         f = int(pending.feature)
         apply_split_record(tree, learner.dataset, pending)
         allowed = learner._splittable(int(tree.leaf_depth[leaf]))
-        smaller = min(float(pending.left_total_count),
-                      float(pending.right_total_count))
         paths[leaf] = paths[k] = paths.get(leaf, frozenset()) | {f}
         mask_left = learner._node_mask(feature_mask, paths[leaf])
         mask_right = learner._node_mask(feature_mask, paths[k])
         state, rec = learner._node_step(state, leaf, k, allowed,
-                                        mask_left, mask_right, rand_seed,
-                                        smaller)
+                                        mask_left, mask_right, rand_seed)
         # jaxlint: disable=JLT001 -- per-split sync (stepwise host loop)
         pending = jax.device_get(rec)
     return state

@@ -1,0 +1,588 @@
+"""The leaf-wise grower's device code, shared by every learner.
+
+One tree is grown by one loop (``_grow_tree``): while a leaf has a
+positive-gain candidate, the device argmaxes the next leaf, writes the
+winning ``SplitRecord`` into the read-back buffer and runs one split
+step (``_split_step``): partition the leaf's rows, histogram the
+smaller child, take its sibling by subtraction from the per-leaf store,
+scan both children for their own best splits. The serial learner
+(treelearner/serial.py), the mesh learners (parallel/) and the
+out-of-core learner (treelearner/sharded.py) differ in how a histogram
+is built and where its rows live; what a split *is* lives here, once.
+
+XLA needs static shapes, so the two data-dependent quantities are
+handled as:
+
+- **row->leaf partition**: a full-length ``leaf_of_row`` vector updated
+  by a vectorized compare on the split feature's bin column (no index
+  lists; the analogue of the reference's DataPartition::Split,
+  src/treelearner/data_partition.hpp:21 / cuda_data_partition.cu:288).
+- **per-leaf row gather** (``_compact_child_hist``): the smaller
+  child's rows are compacted, in ascending order, into the smallest
+  bucket of a static ladder (``_compact_sizes``) that holds them, one
+  ``lax.switch`` branch per bucket; the bucket's tail carries gh 0 and
+  vanishes from every sum.
+
+max_depth gating follows BeforeFindBestSplit (serial_tree_learner.cpp:287):
+a leaf at depth d is splittable iff max_depth <= 0 or d < max_depth —
+enforced on device by zeroing candidate gains at record-creation time,
+using a device-resident per-leaf depth vector.
+"""
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..io.binning import MissingType
+from ..io.dataset import BinnedDataset
+from ..models.tree import Tree
+from ..ops.histogram import subtract_histogram
+from ..ops.split import SplitInfo, find_best_split, make_rand_bins
+
+_NEG_INF = -jnp.inf
+
+
+class GrowState(NamedTuple):
+    """Device-resident per-tree state (the analogue of the CUDA learner's
+    CUDALeafSplits + histogram + partition buffers)."""
+    leaf_of_row: jnp.ndarray      # [R] i32 (R = N+1; last row is a dummy, -1)
+    gh: jnp.ndarray               # [R, 4] f32 (grad, hess, in-bag, total=1)
+    hists: jnp.ndarray            # [L, F, B, 4] f32
+    leaf_depth: jnp.ndarray       # [L] i32 — device-side max_depth gating
+    # Per-leaf best-split candidates (SplitInfo fields, array-of-struct):
+    gain: jnp.ndarray             # [L] f32, -inf when invalid
+    feature: jnp.ndarray          # [L] i32
+    threshold_bin: jnp.ndarray    # [L] i32
+    default_left: jnp.ndarray    # [L] bool
+    is_categorical: jnp.ndarray   # [L] bool
+    cat_mask: jnp.ndarray         # [L, B] bool — bins going left (cat)
+    # monotone bounds each candidate's children would inherit
+    cand_left_min: jnp.ndarray    # [L] f32
+    cand_left_max: jnp.ndarray
+    cand_right_min: jnp.ndarray
+    cand_right_max: jnp.ndarray
+    left_sum_grad: jnp.ndarray    # [L] f32
+    left_sum_hess: jnp.ndarray
+    left_count: jnp.ndarray
+    left_total_count: jnp.ndarray
+    left_output: jnp.ndarray
+    right_sum_grad: jnp.ndarray
+    right_sum_hess: jnp.ndarray
+    right_count: jnp.ndarray
+    right_total_count: jnp.ndarray
+    right_output: jnp.ndarray
+
+
+class SplitRecord(NamedTuple):
+    """One winning split, read back to the host (per step or per tree)."""
+    leaf: jnp.ndarray
+    gain: jnp.ndarray
+    feature: jnp.ndarray
+    threshold_bin: jnp.ndarray
+    default_left: jnp.ndarray
+    is_categorical: jnp.ndarray
+    cat_mask: jnp.ndarray
+    left_sum_grad: jnp.ndarray
+    left_sum_hess: jnp.ndarray
+    left_count: jnp.ndarray
+    left_total_count: jnp.ndarray
+    left_output: jnp.ndarray
+    right_sum_grad: jnp.ndarray
+    right_sum_hess: jnp.ndarray
+    right_count: jnp.ndarray
+    right_total_count: jnp.ndarray
+    right_output: jnp.ndarray
+
+
+def _record_at(state: GrowState, leaf) -> SplitRecord:
+    return SplitRecord(
+        leaf=leaf, gain=state.gain[leaf], feature=state.feature[leaf],
+        threshold_bin=state.threshold_bin[leaf],
+        default_left=state.default_left[leaf],
+        is_categorical=state.is_categorical[leaf],
+        cat_mask=state.cat_mask[leaf],
+        left_sum_grad=state.left_sum_grad[leaf],
+        left_sum_hess=state.left_sum_hess[leaf],
+        left_count=state.left_count[leaf],
+        left_total_count=state.left_total_count[leaf],
+        left_output=state.left_output[leaf],
+        right_sum_grad=state.right_sum_grad[leaf],
+        right_sum_hess=state.right_sum_hess[leaf],
+        right_count=state.right_count[leaf],
+        right_total_count=state.right_total_count[leaf],
+        right_output=state.right_output[leaf])
+
+
+def _empty_records(k: int, B: int) -> SplitRecord:
+    """[k]-shaped record buffers; feature = -1 marks never-written slots."""
+    zi = jnp.zeros(k, dtype=jnp.int32)
+    zf = jnp.zeros(k, dtype=jnp.float32)
+    zb = jnp.zeros(k, dtype=bool)
+    return SplitRecord(
+        leaf=zi, gain=jnp.full(k, _NEG_INF, dtype=jnp.float32),
+        feature=jnp.full(k, -1, dtype=jnp.int32), threshold_bin=zi,
+        default_left=zb, is_categorical=zb,
+        cat_mask=jnp.zeros((k, B), dtype=bool),
+        left_sum_grad=zf, left_sum_hess=zf, left_count=zf,
+        left_total_count=zf, left_output=zf,
+        right_sum_grad=zf, right_sum_hess=zf, right_count=zf,
+        right_total_count=zf, right_output=zf)
+
+
+@jax.named_scope("obs_split_scan")
+def _store_info(state: GrowState, leaf, info: SplitInfo, allowed,
+                valid=True) -> GrowState:
+    """Write a leaf's candidate split; ``allowed`` zeroes the gain
+    (max_depth gating), ``valid`` guards the whole write (loop steps
+    after the no-more-splits point must leave state untouched)."""
+    def put(arr, new):
+        return arr.at[leaf].set(jnp.where(valid, new, arr[leaf]))
+    return state._replace(
+        gain=put(state.gain, jnp.where(allowed, info.gain, _NEG_INF)),
+        feature=put(state.feature, info.feature),
+        threshold_bin=put(state.threshold_bin, info.threshold_bin),
+        default_left=put(state.default_left, info.default_left),
+        is_categorical=put(state.is_categorical, info.is_categorical),
+        cat_mask=state.cat_mask.at[leaf].set(
+            jnp.where(valid, info.cat_mask, state.cat_mask[leaf])),
+        cand_left_min=put(state.cand_left_min, info.left_min_output),
+        cand_left_max=put(state.cand_left_max, info.left_max_output),
+        cand_right_min=put(state.cand_right_min, info.right_min_output),
+        cand_right_max=put(state.cand_right_max, info.right_max_output),
+        left_sum_grad=put(state.left_sum_grad, info.left_sum_grad),
+        left_sum_hess=put(state.left_sum_hess, info.left_sum_hess),
+        left_count=put(state.left_count, info.left_count),
+        left_total_count=put(state.left_total_count, info.left_total_count),
+        left_output=put(state.left_output, info.left_output),
+        right_sum_grad=put(state.right_sum_grad, info.right_sum_grad),
+        right_sum_hess=put(state.right_sum_hess, info.right_sum_hess),
+        right_count=put(state.right_count, info.right_count),
+        right_total_count=put(state.right_total_count,
+                              info.right_total_count),
+        right_output=put(state.right_output, info.right_output))
+
+
+def make_root_state(gh, hist, leaf_of_row, info, L: int, F: int, B: int,
+                    children_allowed, hist_slots: int = 0) -> GrowState:
+    """Initial GrowState after the root histogram+scan (shared by the
+    serial and mesh-parallel learners). ``hist_slots`` shrinks the
+    per-leaf histogram store for learners that never re-read it (the
+    voting learner re-votes per leaf instead of subtracting)."""
+    hist_slots = hist_slots or L
+    zf = lambda: jnp.zeros(L, dtype=jnp.float32)
+    state = GrowState(
+        leaf_of_row=leaf_of_row, gh=gh,
+        hists=jnp.zeros((hist_slots, F, B, 4),
+                        dtype=hist.dtype).at[0].set(hist),
+        leaf_depth=jnp.zeros(L, dtype=jnp.int32),
+        gain=jnp.full(L, _NEG_INF, dtype=jnp.float32),
+        feature=jnp.full(L, -1, dtype=jnp.int32),
+        threshold_bin=jnp.zeros(L, dtype=jnp.int32),
+        default_left=jnp.zeros(L, dtype=bool),
+        is_categorical=jnp.zeros(L, dtype=bool),
+        cat_mask=jnp.zeros((L, B), dtype=bool),
+        cand_left_min=jnp.full(L, -jnp.inf, dtype=jnp.float32),
+        cand_left_max=jnp.full(L, jnp.inf, dtype=jnp.float32),
+        cand_right_min=jnp.full(L, -jnp.inf, dtype=jnp.float32),
+        cand_right_max=jnp.full(L, jnp.inf, dtype=jnp.float32),
+        left_sum_grad=zf(), left_sum_hess=zf(), left_count=zf(),
+        left_total_count=zf(), left_output=zf(), right_sum_grad=zf(),
+        right_sum_hess=zf(), right_count=zf(), right_total_count=zf(),
+        right_output=zf())
+    return _store_info(state, 0, info, children_allowed)
+
+
+def record_is_valid(rec) -> bool:
+    """Host-side check of a read-back split record."""
+    return (int(rec.feature) >= 0 and np.isfinite(float(rec.gain))
+            and float(rec.gain) > 0.0)
+
+
+def rec_valid(rec: SplitRecord):
+    """Device-side twin of record_is_valid — the two predicates MUST stay
+    in lockstep (the device suppresses state writes for invalid records,
+    the host stops applying them; divergence would desync the tree from
+    the partition)."""
+    return ((rec.feature >= 0) & jnp.isfinite(rec.gain)
+            & (rec.gain > 0.0))
+
+
+def apply_split_record(tree: Tree, dataset: BinnedDataset, rec) -> None:
+    """Replay one device split record into the host Tree (reference:
+    the Tree::Split call inside SerialTreeLearner::Split,
+    serial_tree_learner.cpp:593)."""
+    leaf = int(rec.leaf)
+    f = int(rec.feature)
+    tbin = int(rec.threshold_bin)
+    mapper = dataset.bin_mappers[f]
+    common = dict(
+        leaf=leaf, feature=dataset.real_feature_index(f),
+        feature_inner=f,
+        left_value=float(rec.left_output),
+        right_value=float(rec.right_output),
+        left_count=int(round(float(rec.left_count))),
+        right_count=int(round(float(rec.right_count))),
+        left_weight=float(rec.left_sum_hess),
+        right_weight=float(rec.right_sum_hess),
+        gain=float(rec.gain))
+    if bool(rec.is_categorical):
+        bin_mask = np.asarray(rec.cat_mask)
+        cats = [mapper.bin_2_categorical[b]
+                for b in np.nonzero(bin_mask)[0]
+                if b < len(mapper.bin_2_categorical)]
+        tree.split_categorical(cat_values=cats, bin_mask=bin_mask, **common)
+    else:
+        tree.split(
+            threshold_bin=tbin,
+            threshold_real=dataset.real_threshold(f, tbin),
+            missing_type=mapper.missing_type,
+            default_left=bool(rec.default_left), **common)
+
+
+@jax.named_scope("obs_partition")
+def _go_left_by_bin(col: jnp.ndarray, tbin, default_left,
+                    missing_type, nan_bin, zero_bin,
+                    is_categorical=None, cat_mask=None) -> jnp.ndarray:
+    """Training-time split direction over bin values (reference:
+    DenseBin::Split templated missing handling, src/io/dense_bin.hpp;
+    categorical bitset routing ≙ DenseBin::SplitCategorical).
+
+    ``is_categorical``/``cat_mask`` are given only by a program that
+    can meet a categorical split (``_partition_rec`` is the rule).
+    ``cat_mask[col]`` is a gather from the [B] table over every row of
+    the data, whatever the leaf's size, and XLA keeps it under a
+    ``where(False, ...)``: 7.3 ns a row a split on the v5e, 1,865 ms of
+    a 6,366 ms iteration at 1M rows x 254 splits (chip traces, PR
+    27-31). Data with no categorical feature must not pay it."""
+    gl = col <= tbin
+    gl = jnp.where((missing_type == MissingType.NAN) & (col == nan_bin),
+                   default_left, gl)
+    gl = jnp.where((missing_type == MissingType.ZERO) & (col == zero_bin),
+                   default_left, gl)
+    if is_categorical is not None:
+        gl = jnp.where(is_categorical, cat_mask[col], gl)
+    return gl
+
+
+def _partition_rec(rec: SplitRecord, has_cat: bool) -> SplitRecord:
+    """``rec`` as the partition reads it: without its categorical fields
+    where the data has no categorical feature (``has_cat`` is the
+    learners' static ``_has_cat``), so that ``_go_left_by_bin`` lowers
+    no table lookup there. The one place that decides it, for every
+    learner; the sharded learner strips the record on the host, before
+    its jitted shard steps see it."""
+    if has_cat:
+        return rec
+    return rec._replace(is_categorical=None, cat_mask=None)
+
+
+def _rows_go_left(bins, rec: SplitRecord, meta, btab, bundled: bool,
+                  has_cat: bool) -> jnp.ndarray:
+    """[R] bool: the rows that ``rec`` sends left, over all of ``bins``
+    (the caller masks by leaf)."""
+    rec = _partition_rec(rec, has_cat)
+    f = jnp.maximum(rec.feature, 0)
+    col = _partition_col(bins, f, meta, btab, bundled)
+    return _go_left_by_bin(col, rec.threshold_bin, rec.default_left,
+                           meta.missing_type[f], meta.num_bin[f] - 1,
+                           meta.zero_bin[f], rec.is_categorical,
+                           rec.cat_mask)
+
+
+def _maybe_rand_bins(extra_trees: bool, rand_seed, node_id, meta, params):
+    """Per-node extra_trees random thresholds, or None."""
+    if not extra_trees:
+        return None
+    key = jax.random.fold_in(jax.random.PRNGKey(rand_seed), node_id)
+    return make_rand_bins(key, meta, params)
+
+
+class BundleTables(NamedTuple):
+    """Device-resident EFB tables (io/efb.py BundleLayout mirror).
+    ``member[g, b]``/``unmap[g, b]`` route a bundle bin back to its
+    owning feature and original bin; ``gidx_*`` gather the bundle
+    histogram into per-feature histograms; zero rows are reconstructed
+    for ``zero_fix`` features."""
+    group_of: jnp.ndarray       # [Fp] i32
+    member: jnp.ndarray         # [Gp, Bg] i32
+    unmap: jnp.ndarray          # [Gp, Bg] i32
+    gidx_g: jnp.ndarray         # [Fp, B] i32 (-1 = empty)
+    gidx_b: jnp.ndarray         # [Fp, B] i32
+    zero_fix: jnp.ndarray       # [Fp] bool
+
+
+def build_bundle_tables(dataset: BinnedDataset, Fp: int, Gp: int,
+                        B: int, Bg: int) -> BundleTables:
+    """Device EFB tables from the dataset's BundleLayout, padded to
+    ``Fp`` features / ``Gp`` bundle columns (shared by the serial and
+    mesh-parallel learners)."""
+    lay = dataset.bundle
+    F = dataset.num_features
+    G = lay.num_groups
+    member = np.full((Gp, Bg), -1, dtype=np.int32)
+    member[:G, :lay.member.shape[1]] = lay.member
+    unmap = np.zeros((Gp, Bg), dtype=np.int32)
+    unmap[:G, :lay.unmap.shape[1]] = lay.unmap
+    group_of = np.zeros(Fp, dtype=np.int32)
+    group_of[:F] = lay.group_of
+    gidx_g = np.full((Fp, B), -1, dtype=np.int32)
+    gidx_b = np.zeros((Fp, B), dtype=np.int32)
+    gidx_g[:F, :lay.gidx_g.shape[1]] = lay.gidx_g
+    gidx_b[:F, :lay.gidx_b.shape[1]] = lay.gidx_b
+    zero_fix = np.zeros(Fp, dtype=bool)
+    zero_fix[:F] = lay.needs_zero_fix
+    return BundleTables(
+        group_of=jnp.asarray(group_of), member=jnp.asarray(member),
+        unmap=jnp.asarray(unmap), gidx_g=jnp.asarray(gidx_g),
+        gidx_b=jnp.asarray(gidx_b), zero_fix=jnp.asarray(zero_fix))
+
+
+@jax.named_scope("obs_partition")
+def _partition_col(bins, f, meta, btab, bundled: bool):
+    """The split feature's ORIGINAL bin value per row (unbundling via the
+    member/unmap LUTs when bundled; identity otherwise)."""
+    if not bundled:
+        return jnp.take(bins, f, axis=1).astype(jnp.int32)
+    g = btab.group_of[f]
+    raw = jnp.take(bins, g, axis=1).astype(jnp.int32)
+    owner = btab.member[g][raw]
+    return jnp.where(owner == f, btab.unmap[g][raw], meta.zero_bin[f])
+
+
+def _split_hist_store(hists, leaf, new_leaf, hist_small, smaller_is_left,
+                      valid):
+    """Subtract the sibling from the parent's stored histogram and store
+    both children: ``(hists, hist_left, hist_right)``. The one place
+    where a split step touches the per-leaf store ``[L, F, B, 4]``
+    (serial and mesh learners). An invalid step writes the old slices
+    back, so the store stays bit for bit what it was.
+
+    Both old slices are read once, before the first write, and held
+    behind an ``optimization_barrier`` so that XLA cannot re-derive
+    them from the store inside the update fusions: a read of the old
+    store ordered after a write keeps the carried buffer live across
+    that write, and on the v5e the whole store was then copied twice
+    per split (two ``copy`` of ``f32[L,F,B,4]`` in the ``while`` body,
+    a fifth to a quarter of an iteration; ISSUE 28,
+    tests/test_hist_store_inplace.py). Read nothing of ``hists`` after
+    the first ``.at[].set`` here."""
+    old_leaf, old_new = jax.lax.optimization_barrier(
+        (hists[leaf], hists[new_leaf]))
+    hist_large = subtract_histogram(old_leaf, hist_small)
+    with jax.named_scope("obs_hist_subtract"):
+        hist_left = jnp.where(smaller_is_left, hist_small, hist_large)
+        hist_right = jnp.where(smaller_is_left, hist_large, hist_small)
+    with jax.named_scope("obs_hist_store"):
+        hists = hists \
+            .at[leaf].set(jnp.where(valid, hist_left, old_leaf)) \
+            .at[new_leaf].set(jnp.where(valid, hist_right, old_new))
+    return hists, hist_left, hist_right
+
+
+def _finish_split(state: GrowState, rec: SplitRecord, leaf, new_leaf,
+                  valid, hist_left, hist_right, mask_left, mask_right,
+                  meta, params, *, max_depth: int, extra_trees: bool,
+                  has_cat: bool, rand_seed=0, pen_left=None,
+                  pen_right=None, children_allowed=None,
+                  qscale=None) -> GrowState:
+    """Depth gating + both children's best-split scans + candidate
+    stores — the tail of ``_split_step``, and of the out-of-core
+    learner's finish programs (treelearner/sharded.py), which build
+    their child histograms shard by shard. ``children_allowed`` None
+    means: derive from the
+    device-side leaf_depth against the static max_depth."""
+    with jax.named_scope("obs_split_scan"):
+        child_depth = state.leaf_depth[leaf] + 1
+        leaf_depth = state.leaf_depth \
+            .at[leaf].set(jnp.where(valid, child_depth,
+                                    state.leaf_depth[leaf])) \
+            .at[new_leaf].set(jnp.where(valid, child_depth,
+                                        state.leaf_depth[new_leaf]))
+        if children_allowed is None:
+            children_allowed = ((max_depth <= 0)
+                                | (child_depth < max_depth))
+
+    left_info = find_best_split(
+        hist_left, rec.left_sum_grad, rec.left_sum_hess,
+        rec.left_count, rec.left_total_count, meta, params,
+        mask_left, state.cand_left_min[leaf],
+        state.cand_left_max[leaf],
+        parent_output=rec.left_output,
+        rand_bins=_maybe_rand_bins(extra_trees, rand_seed, 2 * new_leaf,
+                                   meta, params),
+        gain_penalty=pen_left, leaf_depth=child_depth,
+        has_categorical=has_cat, hist_scale=qscale)
+    right_info = find_best_split(
+        hist_right, rec.right_sum_grad, rec.right_sum_hess,
+        rec.right_count, rec.right_total_count, meta, params,
+        mask_right, state.cand_right_min[leaf],
+        state.cand_right_max[leaf],
+        parent_output=rec.right_output,
+        rand_bins=_maybe_rand_bins(extra_trees, rand_seed,
+                                   2 * new_leaf + 1, meta, params),
+        gain_penalty=pen_right, leaf_depth=child_depth,
+        has_categorical=has_cat, hist_scale=qscale)
+
+    state = state._replace(leaf_depth=leaf_depth)
+    state = _store_info(state, leaf, left_info, children_allowed, valid)
+    state = _store_info(state, new_leaf, right_info, children_allowed,
+                        valid)
+    return state
+
+
+def _compact_sizes(R: int) -> list:
+    """The compaction ladder's bucket sizes for ``R`` rows, largest
+    first: half the rows, then a quarter of the last until 16,384 or
+    under."""
+    sizes = []
+    s = -(-R // 2)
+    while s > 16384:
+        sizes.append(s)
+        s = -(-s // 4)
+    sizes.append(s)
+    return sizes
+
+
+@jax.named_scope("obs_compact")
+def _compact_child_hist(bins, gh, mask, totals, sizes, hist_fn):
+    """Gather the rows under ``mask`` (the smaller child's; ``totals[3]``
+    is their count) into the smallest bucket of ``sizes`` that holds
+    them (``lax.switch`` over compiled sizes) and histogram only those
+    with ``hist_fn(bins, gh, totals)``, the learner's own histogram. A
+    leaf-wise tree's total smaller-child row count is ~N·log2(L)/2, so
+    this cuts per-tree histogram work by ~50x at 255 leaves vs masked
+    full-row scans — the single-chip analogue of the reference's
+    per-leaf row iterators (data_partition.hpp:119 GetIndexOnLeaf). The
+    scatter/gather compaction itself is O(R) bandwidth, far below the
+    histogram's O(S·F) compute. The rows keep their ascending order and
+    the bucket's tail is zeroed in ``gh``, so which bucket runs changes
+    compiled programs, never values."""
+    R = bins.shape[0]
+    count = totals[3].astype(jnp.int32)     # rows on the leaf
+    pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
+    rows = jnp.arange(R, dtype=jnp.int32)
+
+    def make_branch(S):
+        @jax.named_scope("obs_bucket_%d" % S)
+        def branch(_):
+            idx = jnp.zeros((S,), dtype=jnp.int32)
+            idx = idx.at[jnp.where(mask, pos, S)].set(rows,
+                                                      mode="drop")
+            keep = (jnp.arange(S, dtype=jnp.int32)
+                    < count)[:, None]
+            gh_keep = jnp.where(keep, gh[idx],
+                                jnp.zeros((), dtype=gh.dtype))
+            return hist_fn(bins[idx], gh_keep, totals)
+        return branch
+
+    k = jnp.clip(
+        jnp.sum(jnp.asarray(sizes, dtype=jnp.int32) >= count) - 1,
+        0, len(sizes) - 1)
+    return jax.lax.switch(k, [make_branch(S) for S in sizes], 0)
+
+
+def _subtract_child_hists(state: GrowState, rec: SplitRecord, leaf,
+                          new_leaf, leaf_of_row, smaller_is_left, valid,
+                          small_hist):
+    """Histogram the smaller child only, ``small_hist(mask, totals)``
+    over its rows and record sums, take the sibling by subtraction from
+    the parent's stored histogram — BIT-EXACT in quantized-integer mode
+    — and store both: ``(hists, hist_left, hist_right)``."""
+    small_id = jnp.where(smaller_is_left, leaf, new_leaf)
+    small_sel = leaf_of_row == small_id
+    small_totals = jnp.stack([
+        jnp.where(smaller_is_left, rec.left_sum_grad,
+                  rec.right_sum_grad),
+        jnp.where(smaller_is_left, rec.left_sum_hess,
+                  rec.right_sum_hess),
+        jnp.where(smaller_is_left, rec.left_count, rec.right_count),
+        jnp.where(smaller_is_left, rec.left_total_count,
+                  rec.right_total_count)])
+    hist_small = small_hist(small_sel, small_totals)
+    return _split_hist_store(state.hists, leaf, new_leaf, hist_small,
+                             smaller_is_left, valid)
+
+
+def _split_step(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
+                valid, mask_left, mask_right, meta, params, btab,
+                child_hists, *, bundled: bool, has_cat: bool,
+                max_depth: int, extra_trees: bool, row_sharding=None,
+                children_allowed=None, rand_seed=0, pen_left=None,
+                pen_right=None, qscale=None) -> GrowState:
+    """Apply one split (already chosen: ``rec`` at ``leaf``) and scan
+    both children: the one split step of every whole-tree loop and
+    host-stepped driver. ``valid`` guards every state write (loop steps
+    after the no-more-splits point must leave state untouched).
+
+    ``child_hists(bins, state, rec, leaf, new_leaf, leaf_of_row,
+    smaller_is_left, valid, mask_left, mask_right, qscale)`` is the
+    learner's part: it returns the updated per-leaf store, both
+    children's histograms and their scan masks (``_subtract_child_hists``
+    for the learners that keep a store). ``row_sharding`` pins the new
+    partition to the mesh learners' row layout. ``children_allowed``
+    None means: derive from the device-side leaf_depth."""
+    gl = _rows_go_left(bins, rec, meta, btab, bundled, has_cat)
+    with jax.named_scope("obs_partition"):
+        on_leaf = state.leaf_of_row == leaf
+        leaf_of_row = jnp.where(valid & on_leaf & ~gl, new_leaf,
+                                state.leaf_of_row)
+        if row_sharding is not None:
+            leaf_of_row = jax.lax.with_sharding_constraint(
+                leaf_of_row, row_sharding)
+
+    smaller_is_left = rec.left_total_count <= rec.right_total_count
+    (hists, hist_left, hist_right, mask_left,
+     mask_right) = child_hists(
+        bins, state, rec, leaf, new_leaf, leaf_of_row, smaller_is_left,
+        valid, mask_left, mask_right, qscale)
+    state = state._replace(leaf_of_row=leaf_of_row, hists=hists)
+    return _finish_split(state, rec, leaf, new_leaf, valid, hist_left,
+                         hist_right, mask_left, mask_right, meta, params,
+                         max_depth=max_depth, extra_trees=extra_trees,
+                         has_cat=has_cat, rand_seed=rand_seed,
+                         pen_left=pen_left, pen_right=pen_right,
+                         children_allowed=children_allowed,
+                         qscale=qscale)
+
+
+def _grow_tree(state: GrowState, split_step, L: int, B: int,
+               start_leaf=None, max_splits=None):
+    """Grow the whole tree in one dispatch: while splits remain, the
+    device argmaxes the next leaf (the argmax the reference does on the
+    host, serial_tree_learner.cpp:194, and reaches across ranks via
+    SyncUpGlobalBestSplit), runs ``split_step(state, rec, leaf,
+    new_leaf, valid)`` and appends the record to the ``[L-1]`` buffer
+    the host reads back once per tree. Exits as soon as no positive-gain
+    candidate is left, so a short tree costs no wasted iterations.
+
+    ``start_leaf``/``max_splits`` (traced) continue a tree whose first
+    ``start_leaf - 1`` splits were forced on the host; without them the
+    loop numbers its leaves from 1 and is bounded by ``L`` alone."""
+    kb = L - 1
+
+    def cond(carry):
+        i, _, _, cont = carry
+        return cont & (i < kb)
+
+    def body(carry):
+        i, state, recs, _ = carry
+        with jax.named_scope("obs_pick_leaf"):
+            best = jnp.argmax(state.gain).astype(jnp.int32)
+            rec = _record_at(state, best)
+            valid = rec_valid(rec)
+            if max_splits is not None:
+                valid = valid & (i < max_splits)
+            recs = jax.tree_util.tree_map(
+                lambda buf, v: buf.at[i].set(v), recs, rec)
+        first = 1 if start_leaf is None else start_leaf
+        new_leaf = (i + first).astype(jnp.int32)
+        state = split_step(state, rec, best, new_leaf, valid)
+        return i + 1, state, recs, valid
+
+    carry = (jnp.int32(0), state, _empty_records(kb, B),
+             jnp.asarray(True))
+    _, state, recs, _ = jax.lax.while_loop(cond, body, carry)
+    return state, recs

@@ -5,44 +5,27 @@ TPU-native counterpart of the reference's SerialTreeLearner
 spirit, its CUDA whole-loop learner
 (src/treelearner/cuda/cuda_single_gpu_tree_learner.cpp:128): all heavy state
 — binned rows, gradients, per-leaf histograms, the row→leaf partition — is
-device-resident; the host only orchestrates batches of split steps and
-records the chosen splits into the host ``Tree``.
+device-resident, and the whole tree grows in ONE dispatch
+(``serial.fused_tree``): the host reads back the ``[L-1]`` split records
+and replays them into the host ``Tree``.
+
+The grower itself — the whole-tree loop, the split step, the partition,
+the smaller-child compaction, the per-leaf histogram store — is
+treelearner/grow.py, shared with the mesh learners. This module holds
+what is the serial learner's own: its histogram (``_leaf_histogram``,
+one device, EFB bundles unpacked in place), its jitted programs, padded
+to canonical shapes so that datasets of similar size share compiled
+variants, and forced splits.
 
 The binned matrix is a **traced argument** of every jitted function, never a
 closed-over constant: closing over it would embed the whole dataset into the
 HLO as a literal, making the compiled program scale with the data (at Higgs
 scale ~300 MB of program).
-
-XLA needs static shapes, so the two data-dependent quantities are handled as:
-
-- **row→leaf partition**: a full-length ``leaf_of_row`` vector updated by a
-  vectorized compare on the split feature's bin column (no index lists; the
-  analogue of the reference's DataPartition::Split,
-  src/treelearner/data_partition.hpp:21 / cuda_data_partition.cu:288).
-- **per-leaf row gather**: rows of the leaf to histogram are compacted with
-  ``jnp.nonzero(..., size=S)`` where the static size S is a power of two
-  ≥ half the largest current leaf. Padding rows point at a dummy row whose
-  (grad, hess, count) are zero so they vanish from sums.
-
-Unlike the reference's CUDA learner (one host sync per split), split steps
-run in **batches**: a ``lax.fori_loop`` executes k split steps per device
-dispatch — the device itself argmaxes the next leaf to split, applies the
-split, histograms the smaller child, scans both children — and a buffer of
-k split records is read back per batch. S stays valid for a whole batch
-because the maximum leaf size never grows as splits proceed; k is derived
-from S (many steps per dispatch once gathers are small) so both the number
-of host round-trips per tree (~log₂ num_leaves + num_leaves/32) and the
-number of compiled variants (~log₂ N, keyed on S alone) stay small.
-
-max_depth gating follows BeforeFindBestSplit (serial_tree_learner.cpp:287):
-a leaf at depth d is splittable iff max_depth <= 0 or d < max_depth —
-enforced on device by zeroing candidate gains at record-creation time,
-using a device-resident per-leaf depth vector.
 """
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,271 +36,19 @@ from ..io.dataset import BinnedDataset
 from ..models.tree import Tree
 from ..obs import compile as obs_compile
 from ..obs.registry import registry as obs
-from ..ops.histogram import (build_histogram, subtract_histogram,
-                             unpack_bundle_histogram)
+from ..ops.histogram import build_histogram, unpack_bundle_histogram
 from ..ops.quantize import dequantize_sums, sum_gh
-from ..ops.split import (FeatureMeta, SplitInfo, SplitParams,
-                         calculate_leaf_output, find_best_split,
-                         make_rand_bins)
+from ..ops.split import (FeatureMeta, SplitParams, calculate_leaf_output,
+                         find_best_split)
 from ..utils import log, next_pow2 as _next_pow2
 from ..utils.scalars import dev_bool, dev_i32
-from .capabilities import (CapabilityMixin, train_cegb, train_monotone,
-                           train_stepwise)
-
-_NEG_INF = -jnp.inf
-_MIN_BUCKET = 256
-# Splits per device dispatch cap. Each batch costs one host round-trip
-# (its cost on the chip: not measured), so larger batches trade a
-# little wasted compute (stale gather size S) for fewer syncs: ~12
-# dispatches/tree at 255 leaves.
-_MAX_BATCH = 64
-
-
-class GrowState(NamedTuple):
-    """Device-resident per-tree state (the analogue of the CUDA learner's
-    CUDALeafSplits + histogram + partition buffers)."""
-    leaf_of_row: jnp.ndarray      # [R] i32 (R = N+1; last row is a dummy, -1)
-    gh: jnp.ndarray               # [R, 4] f32 (grad, hess, in-bag, total=1)
-    hists: jnp.ndarray            # [L, F, B, 4] f32
-    leaf_depth: jnp.ndarray       # [L] i32 — device-side max_depth gating
-    # Per-leaf best-split candidates (SplitInfo fields, array-of-struct):
-    gain: jnp.ndarray             # [L] f32, -inf when invalid
-    feature: jnp.ndarray          # [L] i32
-    threshold_bin: jnp.ndarray    # [L] i32
-    default_left: jnp.ndarray    # [L] bool
-    is_categorical: jnp.ndarray   # [L] bool
-    cat_mask: jnp.ndarray         # [L, B] bool — bins going left (cat)
-    # monotone bounds each candidate's children would inherit
-    cand_left_min: jnp.ndarray    # [L] f32
-    cand_left_max: jnp.ndarray
-    cand_right_min: jnp.ndarray
-    cand_right_max: jnp.ndarray
-    left_sum_grad: jnp.ndarray    # [L] f32
-    left_sum_hess: jnp.ndarray
-    left_count: jnp.ndarray
-    left_total_count: jnp.ndarray
-    left_output: jnp.ndarray
-    right_sum_grad: jnp.ndarray
-    right_sum_hess: jnp.ndarray
-    right_count: jnp.ndarray
-    right_total_count: jnp.ndarray
-    right_output: jnp.ndarray
-
-
-class SplitRecord(NamedTuple):
-    """One winning split, read back to the host (per step or per batch)."""
-    leaf: jnp.ndarray
-    gain: jnp.ndarray
-    feature: jnp.ndarray
-    threshold_bin: jnp.ndarray
-    default_left: jnp.ndarray
-    is_categorical: jnp.ndarray
-    cat_mask: jnp.ndarray
-    left_sum_grad: jnp.ndarray
-    left_sum_hess: jnp.ndarray
-    left_count: jnp.ndarray
-    left_total_count: jnp.ndarray
-    left_output: jnp.ndarray
-    right_sum_grad: jnp.ndarray
-    right_sum_hess: jnp.ndarray
-    right_count: jnp.ndarray
-    right_total_count: jnp.ndarray
-    right_output: jnp.ndarray
-
-
-def _record_at(state: GrowState, leaf) -> SplitRecord:
-    return SplitRecord(
-        leaf=leaf, gain=state.gain[leaf], feature=state.feature[leaf],
-        threshold_bin=state.threshold_bin[leaf],
-        default_left=state.default_left[leaf],
-        is_categorical=state.is_categorical[leaf],
-        cat_mask=state.cat_mask[leaf],
-        left_sum_grad=state.left_sum_grad[leaf],
-        left_sum_hess=state.left_sum_hess[leaf],
-        left_count=state.left_count[leaf],
-        left_total_count=state.left_total_count[leaf],
-        left_output=state.left_output[leaf],
-        right_sum_grad=state.right_sum_grad[leaf],
-        right_sum_hess=state.right_sum_hess[leaf],
-        right_count=state.right_count[leaf],
-        right_total_count=state.right_total_count[leaf],
-        right_output=state.right_output[leaf])
-
-
-def _empty_records(k: int, B: int) -> SplitRecord:
-    """[k]-shaped record buffers; feature = -1 marks never-written slots."""
-    zi = jnp.zeros(k, dtype=jnp.int32)
-    zf = jnp.zeros(k, dtype=jnp.float32)
-    zb = jnp.zeros(k, dtype=bool)
-    return SplitRecord(
-        leaf=zi, gain=jnp.full(k, _NEG_INF, dtype=jnp.float32),
-        feature=jnp.full(k, -1, dtype=jnp.int32), threshold_bin=zi,
-        default_left=zb, is_categorical=zb,
-        cat_mask=jnp.zeros((k, B), dtype=bool),
-        left_sum_grad=zf, left_sum_hess=zf, left_count=zf,
-        left_total_count=zf, left_output=zf,
-        right_sum_grad=zf, right_sum_hess=zf, right_count=zf,
-        right_total_count=zf, right_output=zf)
-
-
-@jax.named_scope("obs_split_scan")
-def _store_info(state: GrowState, leaf, info: SplitInfo, allowed,
-                valid=True) -> GrowState:
-    """Write a leaf's candidate split; ``allowed`` zeroes the gain
-    (max_depth gating), ``valid`` guards the whole write (batched steps
-    after the no-more-splits point must leave state untouched)."""
-    def put(arr, new):
-        return arr.at[leaf].set(jnp.where(valid, new, arr[leaf]))
-    return state._replace(
-        gain=put(state.gain, jnp.where(allowed, info.gain, _NEG_INF)),
-        feature=put(state.feature, info.feature),
-        threshold_bin=put(state.threshold_bin, info.threshold_bin),
-        default_left=put(state.default_left, info.default_left),
-        is_categorical=put(state.is_categorical, info.is_categorical),
-        cat_mask=state.cat_mask.at[leaf].set(
-            jnp.where(valid, info.cat_mask, state.cat_mask[leaf])),
-        cand_left_min=put(state.cand_left_min, info.left_min_output),
-        cand_left_max=put(state.cand_left_max, info.left_max_output),
-        cand_right_min=put(state.cand_right_min, info.right_min_output),
-        cand_right_max=put(state.cand_right_max, info.right_max_output),
-        left_sum_grad=put(state.left_sum_grad, info.left_sum_grad),
-        left_sum_hess=put(state.left_sum_hess, info.left_sum_hess),
-        left_count=put(state.left_count, info.left_count),
-        left_total_count=put(state.left_total_count, info.left_total_count),
-        left_output=put(state.left_output, info.left_output),
-        right_sum_grad=put(state.right_sum_grad, info.right_sum_grad),
-        right_sum_hess=put(state.right_sum_hess, info.right_sum_hess),
-        right_count=put(state.right_count, info.right_count),
-        right_total_count=put(state.right_total_count,
-                              info.right_total_count),
-        right_output=put(state.right_output, info.right_output))
-
-
-def make_root_state(gh, hist, leaf_of_row, info, L: int, F: int, B: int,
-                    children_allowed, hist_slots: int = 0) -> GrowState:
-    """Initial GrowState after the root histogram+scan (shared by the
-    serial and mesh-parallel learners). ``hist_slots`` shrinks the
-    per-leaf histogram store for learners that never re-read it (the
-    voting learner re-votes per leaf instead of subtracting)."""
-    hist_slots = hist_slots or L
-    zf = lambda: jnp.zeros(L, dtype=jnp.float32)
-    state = GrowState(
-        leaf_of_row=leaf_of_row, gh=gh,
-        hists=jnp.zeros((hist_slots, F, B, 4),
-                        dtype=hist.dtype).at[0].set(hist),
-        leaf_depth=jnp.zeros(L, dtype=jnp.int32),
-        gain=jnp.full(L, _NEG_INF, dtype=jnp.float32),
-        feature=jnp.full(L, -1, dtype=jnp.int32),
-        threshold_bin=jnp.zeros(L, dtype=jnp.int32),
-        default_left=jnp.zeros(L, dtype=bool),
-        is_categorical=jnp.zeros(L, dtype=bool),
-        cat_mask=jnp.zeros((L, B), dtype=bool),
-        cand_left_min=jnp.full(L, -jnp.inf, dtype=jnp.float32),
-        cand_left_max=jnp.full(L, jnp.inf, dtype=jnp.float32),
-        cand_right_min=jnp.full(L, -jnp.inf, dtype=jnp.float32),
-        cand_right_max=jnp.full(L, jnp.inf, dtype=jnp.float32),
-        left_sum_grad=zf(), left_sum_hess=zf(), left_count=zf(),
-        left_total_count=zf(), left_output=zf(), right_sum_grad=zf(),
-        right_sum_hess=zf(), right_count=zf(), right_total_count=zf(),
-        right_output=zf())
-    return _store_info(state, 0, info, children_allowed)
-
-
-def record_is_valid(rec) -> bool:
-    """Host-side check of a read-back split record."""
-    return (int(rec.feature) >= 0 and np.isfinite(float(rec.gain))
-            and float(rec.gain) > 0.0)
-
-
-def rec_valid(rec: SplitRecord):
-    """Device-side twin of record_is_valid — the two predicates MUST stay
-    in lockstep (the device suppresses state writes for invalid records,
-    the host stops applying them; divergence would desync the tree from
-    the partition)."""
-    return ((rec.feature >= 0) & jnp.isfinite(rec.gain)
-            & (rec.gain > 0.0))
-
-
-def apply_split_record(tree: Tree, dataset: BinnedDataset, rec) -> None:
-    """Replay one device split record into the host Tree (reference:
-    the Tree::Split call inside SerialTreeLearner::Split,
-    serial_tree_learner.cpp:593)."""
-    leaf = int(rec.leaf)
-    f = int(rec.feature)
-    tbin = int(rec.threshold_bin)
-    mapper = dataset.bin_mappers[f]
-    common = dict(
-        leaf=leaf, feature=dataset.real_feature_index(f),
-        feature_inner=f,
-        left_value=float(rec.left_output),
-        right_value=float(rec.right_output),
-        left_count=int(round(float(rec.left_count))),
-        right_count=int(round(float(rec.right_count))),
-        left_weight=float(rec.left_sum_hess),
-        right_weight=float(rec.right_sum_hess),
-        gain=float(rec.gain))
-    if bool(rec.is_categorical):
-        bin_mask = np.asarray(rec.cat_mask)
-        cats = [mapper.bin_2_categorical[b]
-                for b in np.nonzero(bin_mask)[0]
-                if b < len(mapper.bin_2_categorical)]
-        tree.split_categorical(cat_values=cats, bin_mask=bin_mask, **common)
-    else:
-        tree.split(
-            threshold_bin=tbin,
-            threshold_real=dataset.real_threshold(f, tbin),
-            missing_type=mapper.missing_type,
-            default_left=bool(rec.default_left), **common)
-
-
-@jax.named_scope("obs_partition")
-def _go_left_by_bin(col: jnp.ndarray, tbin, default_left,
-                    missing_type, nan_bin, zero_bin,
-                    is_categorical=None, cat_mask=None) -> jnp.ndarray:
-    """Training-time split direction over bin values (reference:
-    DenseBin::Split templated missing handling, src/io/dense_bin.hpp;
-    categorical bitset routing ≙ DenseBin::SplitCategorical).
-
-    ``is_categorical``/``cat_mask`` are given only by a program that
-    can meet a categorical split (``_partition_rec`` is the rule).
-    ``cat_mask[col]`` is a gather from the [B] table over every row of
-    the data, whatever the leaf's size, and XLA keeps it under a
-    ``where(False, ...)``: 7.3 ns a row a split on the v5e, 1,865 ms of
-    a 6,366 ms iteration at 1M rows x 254 splits (chip traces, PR
-    27-31). Data with no categorical feature must not pay it."""
-    gl = col <= tbin
-    gl = jnp.where((missing_type == MissingType.NAN) & (col == nan_bin),
-                   default_left, gl)
-    gl = jnp.where((missing_type == MissingType.ZERO) & (col == zero_bin),
-                   default_left, gl)
-    if is_categorical is not None:
-        gl = jnp.where(is_categorical, cat_mask[col], gl)
-    return gl
-
-
-def _partition_rec(rec: SplitRecord, has_cat: bool) -> SplitRecord:
-    """``rec`` as the partition reads it: without its categorical fields
-    where the data has no categorical feature (``has_cat`` is the
-    learners' static ``_has_cat``), so that ``_go_left_by_bin`` lowers
-    no table lookup there. The one place that decides it, for every
-    learner; the sharded learner strips the record on the host, before
-    its jitted shard steps see it."""
-    if has_cat:
-        return rec
-    return rec._replace(is_categorical=None, cat_mask=None)
-
-
-def _rows_go_left(bins, rec: SplitRecord, meta, btab, bundled: bool,
-                  has_cat: bool) -> jnp.ndarray:
-    """[R] bool: the rows that ``rec`` sends left, over all of ``bins``
-    (the caller masks by leaf)."""
-    rec = _partition_rec(rec, has_cat)
-    f = jnp.maximum(rec.feature, 0)
-    col = _partition_col(bins, f, meta, btab, bundled)
-    return _go_left_by_bin(col, rec.threshold_bin, rec.default_left,
-                           meta.missing_type[f], meta.num_bin[f] - 1,
-                           meta.zero_bin[f], rec.is_categorical,
-                           rec.cat_mask)
+from .capabilities import (CapabilityMixin, _cegb_penalty, train_cegb,
+                           train_monotone, train_stepwise)
+from .grow import (GrowState, SplitRecord, _compact_child_hist,
+                   _compact_sizes, _grow_tree, _maybe_rand_bins, _record_at,
+                   _rows_go_left, _split_step, _store_info,
+                   _subtract_child_hists, apply_split_record,
+                   build_bundle_tables, make_root_state, record_is_valid)
 
 
 # ----------------------------------------------------------------------
@@ -369,28 +100,6 @@ def _pad_rows_fn_cached(R: int):
     return obs_compile.instrument_jit("serial.pad_gh", pad)
 
 
-def _maybe_rand_bins(extra_trees: bool, rand_seed, node_id, meta, params):
-    """Per-node extra_trees random thresholds, or None."""
-    if not extra_trees:
-        return None
-    key = jax.random.fold_in(jax.random.PRNGKey(rand_seed), node_id)
-    return make_rand_bins(key, meta, params)
-
-
-class BundleTables(NamedTuple):
-    """Device-resident EFB tables (io/efb.py BundleLayout mirror).
-    ``member[g, b]``/``unmap[g, b]`` route a bundle bin back to its
-    owning feature and original bin; ``gidx_*`` gather the bundle
-    histogram into per-feature histograms; zero rows are reconstructed
-    for ``zero_fix`` features."""
-    group_of: jnp.ndarray       # [Fp] i32
-    member: jnp.ndarray         # [Gp, Bg] i32
-    unmap: jnp.ndarray          # [Gp, Bg] i32
-    gidx_g: jnp.ndarray         # [Fp, B] i32 (-1 = empty)
-    gidx_b: jnp.ndarray         # [Fp, B] i32
-    zero_fix: jnp.ndarray       # [Fp] bool
-
-
 def _leaf_histogram(bins, gh, meta, btab, *, B: int, Bg: int,
                     bundled: bool, totals=None,
                     hist_impl: tuple = ("auto", False)):
@@ -408,196 +117,35 @@ def _leaf_histogram(bins, gh, meta, btab, *, B: int, Bg: int,
                                    btab.zero_fix, meta.zero_bin, totals)
 
 
-def build_bundle_tables(dataset: BinnedDataset, Fp: int, Gp: int,
-                        B: int, Bg: int) -> BundleTables:
-    """Device EFB tables from the dataset's BundleLayout, padded to
-    ``Fp`` features / ``Gp`` bundle columns (shared by the serial and
-    mesh-parallel learners)."""
-    lay = dataset.bundle
-    F = dataset.num_features
-    G = lay.num_groups
-    member = np.full((Gp, Bg), -1, dtype=np.int32)
-    member[:G, :lay.member.shape[1]] = lay.member
-    unmap = np.zeros((Gp, Bg), dtype=np.int32)
-    unmap[:G, :lay.unmap.shape[1]] = lay.unmap
-    group_of = np.zeros(Fp, dtype=np.int32)
-    group_of[:F] = lay.group_of
-    gidx_g = np.full((Fp, B), -1, dtype=np.int32)
-    gidx_b = np.zeros((Fp, B), dtype=np.int32)
-    gidx_g[:F, :lay.gidx_g.shape[1]] = lay.gidx_g
-    gidx_b[:F, :lay.gidx_b.shape[1]] = lay.gidx_b
-    zero_fix = np.zeros(Fp, dtype=bool)
-    zero_fix[:F] = lay.needs_zero_fix
-    return BundleTables(
-        group_of=jnp.asarray(group_of), member=jnp.asarray(member),
-        unmap=jnp.asarray(unmap), gidx_g=jnp.asarray(gidx_g),
-        gidx_b=jnp.asarray(gidx_b), zero_fix=jnp.asarray(zero_fix))
-
-
-@jax.named_scope("obs_partition")
-def _partition_col(bins, f, meta, btab, bundled: bool):
-    """The split feature's ORIGINAL bin value per row (unbundling via the
-    member/unmap LUTs when bundled; identity otherwise)."""
-    if not bundled:
-        return jnp.take(bins, f, axis=1).astype(jnp.int32)
-    g = btab.group_of[f]
-    raw = jnp.take(bins, g, axis=1).astype(jnp.int32)
-    owner = btab.member[g][raw]
-    return jnp.where(owner == f, btab.unmap[g][raw], meta.zero_bin[f])
-
-
-def _split_hist_store(hists, leaf, new_leaf, hist_small, smaller_is_left,
-                      valid):
-    """Subtract the sibling from the parent's stored histogram and store
-    both children: ``(hists, hist_left, hist_right)``. The one place
-    where a split step touches the per-leaf store ``[L, F, B, 4]``
-    (serial and mesh learners). An invalid step writes the old slices
-    back, so the store stays bit for bit what it was.
-
-    Both old slices are read once, before the first write, and held
-    behind an ``optimization_barrier`` so that XLA cannot re-derive
-    them from the store inside the update fusions: a read of the old
-    store ordered after a write keeps the carried buffer live across
-    that write, and on the v5e the whole store was then copied twice
-    per split (two ``copy`` of ``f32[L,F,B,4]`` in the ``while`` body,
-    a fifth to a quarter of an iteration; ISSUE 28,
-    tests/test_hist_store_inplace.py). Read nothing of ``hists`` after
-    the first ``.at[].set`` here."""
-    old_leaf, old_new = jax.lax.optimization_barrier(
-        (hists[leaf], hists[new_leaf]))
-    hist_large = subtract_histogram(old_leaf, hist_small)
-    with jax.named_scope("obs_hist_subtract"):
-        hist_left = jnp.where(smaller_is_left, hist_small, hist_large)
-        hist_right = jnp.where(smaller_is_left, hist_large, hist_small)
-    with jax.named_scope("obs_hist_store"):
-        hists = hists \
-            .at[leaf].set(jnp.where(valid, hist_left, old_leaf)) \
-            .at[new_leaf].set(jnp.where(valid, hist_right, old_new))
-    return hists, hist_left, hist_right
-
-
-def _finish_split(state: GrowState, rec: SplitRecord, leaf, new_leaf,
-                  valid, hist_left, hist_right, mask_left, mask_right,
-                  meta, params, *, max_depth: int, extra_trees: bool,
-                  has_cat: bool, rand_seed=0, pen_left=None,
-                  pen_right=None, children_allowed=None,
-                  qscale=None) -> GrowState:
-    """Depth gating + both children's best-split scans + candidate
-    stores — the split-step tail shared verbatim by the serial and
-    mesh-parallel learners (only the child-histogram computation
-    differs). ``children_allowed`` None means: derive from the
-    device-side leaf_depth against the static max_depth."""
-    with jax.named_scope("obs_split_scan"):
-        child_depth = state.leaf_depth[leaf] + 1
-        leaf_depth = state.leaf_depth \
-            .at[leaf].set(jnp.where(valid, child_depth,
-                                    state.leaf_depth[leaf])) \
-            .at[new_leaf].set(jnp.where(valid, child_depth,
-                                        state.leaf_depth[new_leaf]))
-        if children_allowed is None:
-            children_allowed = ((max_depth <= 0)
-                                | (child_depth < max_depth))
-
-    left_info = find_best_split(
-        hist_left, rec.left_sum_grad, rec.left_sum_hess,
-        rec.left_count, rec.left_total_count, meta, params,
-        mask_left, state.cand_left_min[leaf],
-        state.cand_left_max[leaf],
-        parent_output=rec.left_output,
-        rand_bins=_maybe_rand_bins(extra_trees, rand_seed, 2 * new_leaf,
-                                   meta, params),
-        gain_penalty=pen_left, leaf_depth=child_depth,
-        has_categorical=has_cat, hist_scale=qscale)
-    right_info = find_best_split(
-        hist_right, rec.right_sum_grad, rec.right_sum_hess,
-        rec.right_count, rec.right_total_count, meta, params,
-        mask_right, state.cand_right_min[leaf],
-        state.cand_right_max[leaf],
-        parent_output=rec.right_output,
-        rand_bins=_maybe_rand_bins(extra_trees, rand_seed,
-                                   2 * new_leaf + 1, meta, params),
-        gain_penalty=pen_right, leaf_depth=child_depth,
-        has_categorical=has_cat, hist_scale=qscale)
-
-    state = state._replace(leaf_depth=leaf_depth)
-    state = _store_info(state, leaf, left_info, children_allowed, valid)
-    state = _store_info(state, new_leaf, right_info, children_allowed,
-                        valid)
-    return state
-
-
 def _split_body(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
                 valid, mask_left, mask_right, meta, params, btab, *,
-                S, B: int, Bg: int, bundled: bool, max_depth: int,
-                extra_trees: bool, has_cat: bool = True,
-                hist_impl: tuple = ("auto", False), children_allowed=None,
-                rand_seed=0, pen_left=None, pen_right=None,
-                qscale=None) -> GrowState:
-    """Apply one split (already chosen: ``rec`` at ``leaf``) and scan both
-    children. Shared by the per-split, batched and fused paths.
-    ``children_allowed`` None means: derive from device leaf_depth.
-
-    ``S`` is the smaller-child gather size: a static int on the
-    host-stepped paths (the host buckets it per batch), or a static
-    tuple of bucket sizes on the fused whole-tree path — the device
-    then picks the branch of a ``lax.switch`` ladder from the record's
-    own child count. Fill rows hit the gh-zero dummy row, so the
-    gather size selects compiled programs, never values."""
-    R = bins.shape[0]
-    gl = _rows_go_left(bins, rec, meta, btab, bundled, has_cat)
-    with jax.named_scope("obs_partition"):
-        on_leaf = state.leaf_of_row == leaf
-        leaf_of_row = jnp.where(valid & on_leaf & ~gl, new_leaf,
-                                state.leaf_of_row)
-
-    smaller_is_left = rec.left_total_count <= rec.right_total_count
-    small_id = jnp.where(smaller_is_left, leaf, new_leaf)
-    small_totals = jnp.stack([
-        jnp.where(smaller_is_left, rec.left_sum_grad, rec.right_sum_grad),
-        jnp.where(smaller_is_left, rec.left_sum_hess, rec.right_sum_hess),
-        jnp.where(smaller_is_left, rec.left_count, rec.right_count),
-        jnp.where(smaller_is_left, rec.left_total_count,
-                  rec.right_total_count)])
-
+                B: int, Bg: int, bundled: bool,
+                hist_impl: tuple = ("auto", False), **step) -> GrowState:
+    """grow.py's split step over this learner's histogram: the smaller
+    child's rows compacted (``_compact_child_hist``) and histogrammed by
+    ``_leaf_histogram``. ``step`` is ``_split_step``'s keywords."""
     # quantized mode: the record's totals are dequantized f32, but the
     # bundled zero-bin fix needs exact int sums — _leaf_histogram
     # recomputes them from the gathered integer rows
-    def hist_at(size: int):
-        with jax.named_scope("obs_compact"), \
-                jax.named_scope("obs_bucket_%d" % size):
-            (idx,) = jnp.nonzero(leaf_of_row == small_id, size=size,
-                                 fill_value=R - 1)
-            return _leaf_histogram(bins[idx], state.gh[idx], meta, btab,
-                                   B=B, Bg=Bg, bundled=bundled,
-                                   totals=small_totals,
-                                   hist_impl=hist_impl)
+    def hist_fn(bins, gh, totals):
+        return _leaf_histogram(bins, gh, meta, btab, B=B, Bg=Bg,
+                               bundled=bundled, totals=totals,
+                               hist_impl=hist_impl)
 
-    ladder = S if isinstance(S, tuple) else (S,)
-    if len(ladder) == 1:
-        hist_small = hist_at(ladder[0])
-    else:
-        # device-side bucket choice (the host `_bucket` policy, on
-        # device): smallest ladder size ≥ child count + the f32-count
-        # rounding margin; the ladder tops out at next_pow2(N), which
-        # covers any child
-        small_cnt = small_totals[3]
-        k = jnp.clip(
-            jnp.sum(jnp.asarray(ladder, dtype=jnp.float32)
-                    < small_cnt + 16.0),
-            0, len(ladder) - 1).astype(jnp.int32)
-        hist_small = jax.lax.switch(
-            k, [lambda _, s=s: hist_at(s) for s in ladder], 0)
-    hists, hist_left, hist_right = _split_hist_store(
-        state.hists, leaf, new_leaf, hist_small, smaller_is_left, valid)
+    def child_hists(bins, state, rec, leaf, new_leaf, leaf_of_row,
+                    smaller_is_left, valid, mask_left, mask_right, qscale):
+        def small_hist(mask, totals):
+            return _compact_child_hist(
+                bins, state.gh, mask, totals,
+                _compact_sizes(bins.shape[0]), hist_fn)
 
-    state = state._replace(leaf_of_row=leaf_of_row, hists=hists)
-    return _finish_split(state, rec, leaf, new_leaf, valid, hist_left,
-                         hist_right, mask_left, mask_right, meta, params,
-                         max_depth=max_depth, extra_trees=extra_trees,
-                         has_cat=has_cat, rand_seed=rand_seed,
-                         pen_left=pen_left, pen_right=pen_right,
-                         children_allowed=children_allowed,
-                         qscale=qscale)
+        return _subtract_child_hists(
+            state, rec, leaf, new_leaf, leaf_of_row, smaller_is_left,
+            valid, small_hist) + (mask_left, mask_right)
+
+    return _split_step(bins, state, rec, leaf, new_leaf, valid, mask_left,
+                       mask_right, meta, params, btab, child_hists,
+                       bundled=bundled, **step)
 
 
 @functools.lru_cache(maxsize=None)
@@ -630,7 +178,7 @@ def _root_fn_cached(L: int, B: int, Bg: int, bundled: bool,
 
 
 @functools.lru_cache(maxsize=None)
-def _step_fn_cached(S: int, B: int, Bg: int, bundled: bool,
+def _step_fn_cached(B: int, Bg: int, bundled: bool,
                     extra_trees: bool, has_cat: bool = True,
                     hist_impl: tuple = ("auto", False)):
     """Per-split step (host chooses the leaf): used when per-node feature
@@ -642,7 +190,7 @@ def _step_fn_cached(S: int, B: int, Bg: int, bundled: bool,
         rec = _record_at(state, leaf)
         state = _split_body(bins, state, rec, leaf, new_leaf,
                             jnp.asarray(True), mask_left, mask_right,
-                            meta, params, btab, S=S, B=B, Bg=Bg,
+                            meta, params, btab, B=B, Bg=Bg,
                             bundled=bundled, max_depth=0,
                             extra_trees=extra_trees, has_cat=has_cat,
                             hist_impl=hist_impl,
@@ -653,18 +201,6 @@ def _step_fn_cached(S: int, B: int, Bg: int, bundled: bool,
 
     return obs_compile.instrument_jit("serial.step", step,
                                       donate_argnums=(1,))
-
-
-def _cegb_penalty(params, count, used, coupled, unfetched, lazy):
-    """Per-feature CEGB gain penalty for scanning one leaf (reference:
-    CostEfficientGradientBoosting::DeltaGain,
-    cost_effective_gradient_boosting.hpp:80-99): split penalty scaled by
-    leaf size + coupled penalty for model-new features + lazy per-row
-    fetch cost for rows that have not used the feature yet."""
-    pen = params.cegb_penalty_split * count + coupled * (~used)
-    if lazy is not None:
-        pen = pen + lazy * unfetched
-    return params.cegb_tradeoff * pen
 
 
 @functools.lru_cache(maxsize=None)
@@ -699,7 +235,7 @@ def _cegb_root_fn_cached(L: int, B: int, Bg: int, bundled: bool,
 
 
 @functools.lru_cache(maxsize=None)
-def _cegb_step_fn_cached(S: int, B: int, Bg: int, bundled: bool,
+def _cegb_step_fn_cached(B: int, Bg: int, bundled: bool,
                          has_lazy: bool, has_cat: bool = True,
                          hist_impl: tuple = ("auto", False)):
     """Per-split CEGB step: applies the pending split, updates the
@@ -741,7 +277,7 @@ def _cegb_step_fn_cached(S: int, B: int, Bg: int, bundled: bool,
                               coupled, unf_right, lazy)
         state = _split_body(bins, state, rec, leaf, new_leaf,
                             jnp.asarray(True), feature_mask, feature_mask,
-                            meta, params, btab, S=S, B=B, Bg=Bg,
+                            meta, params, btab, B=B, Bg=Bg,
                             bundled=bundled, max_depth=0,
                             extra_trees=False, has_cat=has_cat,
                             hist_impl=hist_impl,
@@ -756,7 +292,7 @@ def _cegb_step_fn_cached(S: int, B: int, Bg: int, bundled: bool,
 
 
 @functools.lru_cache(maxsize=None)
-def _mono_step_fn_cached(S: int, B: int, Bg: int, bundled: bool,
+def _mono_step_fn_cached(B: int, Bg: int, bundled: bool,
                          has_cat: bool = True,
                          hist_impl: tuple = ("auto", False)):
     """Per-split step for monotone_constraints_method=intermediate: the
@@ -774,7 +310,7 @@ def _mono_step_fn_cached(S: int, B: int, Bg: int, bundled: bool,
         rec = _record_at(state, leaf)
         state = _split_body(bins, state, rec, leaf, new_leaf,
                             jnp.asarray(True), feature_mask, feature_mask,
-                            meta, params, btab, S=S, B=B, Bg=Bg,
+                            meta, params, btab, B=B, Bg=Bg,
                             bundled=bundled, max_depth=0,
                             extra_trees=False, has_cat=has_cat,
                             hist_impl=hist_impl,
@@ -840,7 +376,7 @@ def _adv_rescan_fn_cached(B: int, has_cat: bool = True):
 
 
 @functools.lru_cache(maxsize=None)
-def _forced_fn_cached(S: int, B: int, Bg: int, bundled: bool,
+def _forced_fn_cached(B: int, Bg: int, bundled: bool,
                       extra_trees: bool, has_cat: bool = True,
                       hist_impl: tuple = ("auto", False)):
     """Forced split of a given (feature, threshold-bin) on a leaf
@@ -880,7 +416,7 @@ def _forced_fn_cached(S: int, B: int, Bg: int, bundled: bool,
         ok = (left[3] > 0.5) & (right[3] > 0.5)
         state = _split_body(bins, state, rec, leaf, new_leaf, ok,
                             feature_mask, feature_mask, meta, params,
-                            btab, S=S, B=B, Bg=Bg, bundled=bundled,
+                            btab, B=B, Bg=Bg, bundled=bundled,
                             max_depth=0, extra_trees=extra_trees,
                             has_cat=has_cat, hist_impl=hist_impl,
                             children_allowed=children_allowed,
@@ -892,102 +428,28 @@ def _forced_fn_cached(S: int, B: int, Bg: int, bundled: bool,
 
 
 @functools.lru_cache(maxsize=None)
-def _batch_fn_cached(S: int, kb: int, B: int, Bg: int, bundled: bool,
-                     max_depth: int, extra_trees: bool,
-                     has_cat: bool = True,
-                     hist_impl: tuple = ("auto", False)):
-    """Batched split steps: one dispatch runs kb splits, the device
-    picking the best leaf each step (the argmax the reference does on host
-    at serial_tree_learner.cpp:194). Records of the applied splits are
-    written to [kb] buffers and read back once."""
-    def batch(bins, state: GrowState, start_leaf, max_splits,
-              feature_mask, rand_seed, qscale, meta, params, btab):
-        def body(i, carry):
-            state, recs = carry
-            with jax.named_scope("obs_pick_leaf"):
-                best = jnp.argmax(state.gain).astype(jnp.int32)
-                rec = _record_at(state, best)
-                valid = rec_valid(rec) & (i < max_splits)
-                recs = jax.tree_util.tree_map(
-                    lambda buf, v: buf.at[i].set(v), recs, rec)
-            new_leaf = (start_leaf + i).astype(jnp.int32)
-            state = _split_body(bins, state, rec, best, new_leaf, valid,
-                                feature_mask, feature_mask, meta, params,
-                                btab, S=S, B=B, Bg=Bg, bundled=bundled,
-                                max_depth=max_depth,
-                                extra_trees=extra_trees, has_cat=has_cat,
-                                hist_impl=hist_impl,
-                                rand_seed=rand_seed, qscale=qscale)
-            return state, recs
-
-        state, recs = jax.lax.fori_loop(
-            0, kb, body, (state, _empty_records(kb, B)))
-        return state, recs
-
-    return obs_compile.instrument_jit("serial.batch", batch,
-                                      donate_argnums=(1,))
-
-
-def _bucket_ladder(bucket_fn, max_bucket: int) -> tuple:
-    """Every gather size ``bucket_fn`` can return, ascending — the
-    static branch ladder of the fused whole-tree grower. Each branch
-    compiles one child-histogram gather size; the padded fill rows
-    carry gh 0, so which branch runs changes compiled programs, never
-    values."""
-    sizes = {bucket_fn(0.0)}
-    c = 1
-    while c <= max_bucket:
-        sizes.add(bucket_fn(float(c)))
-        c <<= 1
-    return tuple(sorted(sizes))
-
-
-@functools.lru_cache(maxsize=None)
 def _fused_fn_cached(L: int, B: int, Bg: int, bundled: bool,
                      max_depth: int, extra_trees: bool,
                      has_cat: bool = True,
-                     hist_impl: tuple = ("auto", False),
-                     ladder: tuple = ()):
-    """Fused whole-tree growth: ONE dispatch runs the entire grow loop
-    — the device argmaxes the next frontier leaf, applies the split
-    (partition update + smaller-child histogram through the gather
-    ladder + sibling subtraction), scans both children, and appends the
-    record — until no positive-gain candidate remains. The host reads
-    back only the [L-1] record buffer (the Booster-paper /
-    XGBoost-GPU "whole pipeline on the accelerator" move; the serial
-    analogue of the mesh learner's `_tree_impl`). Bit-identical to the
-    stepped `serial.batch` loop: same body, same per-step argmax, same
-    gather semantics."""
-    kb = L - 1
-
+                     hist_impl: tuple = ("auto", False)):
+    """Whole-tree growth: ONE dispatch runs grow.py's loop over this
+    learner's split step until no positive-gain candidate remains, and
+    the host reads back only the [L-1] record buffer (the Booster-paper
+    / XGBoost-GPU "whole pipeline on the accelerator" move; the serial
+    twin of the mesh learner's ``_tree_impl``). ``start_leaf`` /
+    ``max_splits`` continue after a forced-split preamble."""
     def fused(bins, state: GrowState, start_leaf, max_splits,
               feature_mask, rand_seed, qscale, meta, params, btab):
-        def cond(carry):
-            i, _, _, cont = carry
-            return cont & (i < kb)
+        def step(state, rec, leaf, new_leaf, valid):
+            return _split_body(bins, state, rec, leaf, new_leaf, valid,
+                               feature_mask, feature_mask, meta, params,
+                               btab, B=B, Bg=Bg, bundled=bundled,
+                               max_depth=max_depth,
+                               extra_trees=extra_trees, has_cat=has_cat,
+                               hist_impl=hist_impl, rand_seed=rand_seed,
+                               qscale=qscale)
 
-        def body(carry):
-            i, state, recs, _ = carry
-            with jax.named_scope("obs_pick_leaf"):
-                best = jnp.argmax(state.gain).astype(jnp.int32)
-                rec = _record_at(state, best)
-                valid = rec_valid(rec) & (i < max_splits)
-                recs = jax.tree_util.tree_map(
-                    lambda buf, v: buf.at[i].set(v), recs, rec)
-            new_leaf = (start_leaf + i).astype(jnp.int32)
-            state = _split_body(bins, state, rec, best, new_leaf, valid,
-                                feature_mask, feature_mask, meta, params,
-                                btab, S=ladder, B=B, Bg=Bg,
-                                bundled=bundled, max_depth=max_depth,
-                                extra_trees=extra_trees, has_cat=has_cat,
-                                hist_impl=hist_impl,
-                                rand_seed=rand_seed, qscale=qscale)
-            return i + 1, state, recs, valid
-
-        carry = (jnp.int32(0), state, _empty_records(kb, B),
-                 jnp.asarray(True))
-        _, state, recs, _ = jax.lax.while_loop(cond, body, carry)
-        return state, recs
+        return _grow_tree(state, step, L, B, start_leaf, max_splits)
 
     return obs_compile.instrument_jit("serial.fused_tree", fused,
                                       donate_argnums=(1,))
@@ -1014,8 +476,7 @@ class SerialTreeLearner(CapabilityMixin):
         # feature/bundle columns to an 8 multiple: pad rows carry gh 0 /
         # leaf -1 so they vanish from every sum, pad features are trivial
         # (num_bin 1), and the canonical shapes share compiled step
-        # variants across datasets. The dummy rows double as the
-        # nonzero-gather fill target.
+        # variants across datasets.
         self.R = -(-(N + 1) // 4096) * 4096
         self.Fp = -(-F // 8) * 8
         from ..ops.histogram import resolve_hist_impl
@@ -1048,12 +509,6 @@ class SerialTreeLearner(CapabilityMixin):
         self.params = SplitParams.from_config(config)
         self._ff_rng = np.random.RandomState(config.feature_fraction_seed)
         self._resolve_constraints()
-        self._max_bucket = _next_pow2(N)
-        # fused whole-tree growth (default): the entire grow loop runs
-        # as one dispatch; the stepped per-batch host loop stays behind
-        # the flag (and under the host-stepped capability drivers)
-        self._fused_growth = bool(getattr(config, "tpu_fused_tree", True))
-        self._ladder = _bucket_ladder(self._bucket, self._max_bucket)
         # extra_trees (config.h:368): random single-threshold candidates,
         # seeded per tree (host counter) and per node (device fold-in)
         self._extra_trees = bool(config.extra_trees)
@@ -1084,48 +539,15 @@ class SerialTreeLearner(CapabilityMixin):
         self._btab = build_bundle_tables(dataset, self.Fp, self.Gp,
                                          self.B, self.Bg)
 
-    def _step_fn(self, S: int):
-        return _step_fn_cached(S, self.B, self.Bg, self._bundled,
+    def _step_fn(self):
+        return _step_fn_cached(self.B, self.Bg, self._bundled,
                                self._extra_trees, self._has_cat,
                                self._hist_impl)
-
-    def _batch_fn(self, S: int):
-        kb = self._batch_k(S)
-        return (_batch_fn_cached(S, kb, self.B, self.Bg, self._bundled,
-                                 self.max_depth, self._extra_trees,
-                                 self._has_cat, self._hist_impl), kb)
 
     def _fused_fn(self):
         return _fused_fn_cached(self.L, self.B, self.Bg, self._bundled,
                                 self.max_depth, self._extra_trees,
-                                self._has_cat, self._hist_impl,
-                                self._ladder)
-
-    def _batch_k(self, S: int) -> int:
-        """Steps per dispatch: aim for ~4R gathered rows per batch so early
-        (large-S) batches stay short while deep-tree batches amortize the
-        host round-trip over many cheap steps. Derived from the padded row
-        count R (not N) so the (S, kb) pair — and thus the compiled batch
-        variant — is shared across datasets of similar size."""
-        return int(np.clip((4 * self.R) // max(S, 1), 1, _MAX_BATCH))
-
-    def _bucket(self, count: float) -> int:
-        # Small data (one pad block): a single canonical gather size —
-        # every small dataset then shares one compiled batch variant, and
-        # the extra gathered rows are noise at this scale.
-        if self.R <= 4096:
-            return self.R // 2
-        # +16 margin: counts travel as f32 sums and may round for very
-        # large leaves. The floor caps compiled variants at ~log2(N) - 8.
-        S = min(max(_next_pow2(int(count) + 16), _MIN_BUCKET),
-                self._max_bucket)
-        if self._max_bucket >= (1 << 20):
-            # large datasets: even power-of-two exponents only — halves
-            # the number of compiled batch variants for ≤2x gather slack
-            e = S.bit_length() - 1
-            if (e & 1) and S < self._max_bucket:
-                S <<= 1
-        return min(S, self._max_bucket)
+                                self._has_cat, self._hist_impl)
 
     # ------------------------------------------------------------------
     def _load_forced_splits(self, config):
@@ -1144,7 +566,7 @@ class SerialTreeLearner(CapabilityMixin):
             return None
 
     def _apply_forced_splits(self, tree: Tree, state: GrowState,
-                             feature_mask, rand_seed, leaf_total):
+                             feature_mask, rand_seed):
         """Apply the forced-split tree breadth-first before best-gain
         growth (reference: SerialTreeLearner::ForceSplits,
         serial_tree_learner.cpp:451). Returns (state, next_leaf)."""
@@ -1160,9 +582,7 @@ class SerialTreeLearner(CapabilityMixin):
             mapper = self.dataset.bin_mappers[inner]
             tbin = int(mapper.value_to_bin(
                 np.asarray([float(spec.get("threshold", 0.0))]))[0])
-            M = max(leaf_total.values())
-            S = self._bucket(M / 2)
-            fn = _forced_fn_cached(S, self.B, self.Bg, self._bundled,
+            fn = _forced_fn_cached(self.B, self.Bg, self._bundled,
                                    self._extra_trees, self._has_cat,
                                    self._hist_impl)
             allowed = self._splittable(int(tree.leaf_depth[leaf]) + 1)
@@ -1182,8 +602,6 @@ class SerialTreeLearner(CapabilityMixin):
             # (host Tree replay), same preamble as above
             r = jax.device_get(rec)
             apply_split_record(tree, self.dataset, r)
-            leaf_total[leaf] = float(r.left_total_count)
-            leaf_total[next_leaf] = float(r.right_total_count)
             if "left" in spec:
                 queue.append((leaf, spec["left"]))
             if "right" in spec:
@@ -1240,11 +658,10 @@ class SerialTreeLearner(CapabilityMixin):
                                        rand_seed, self._qscale, self.meta,
                                        self.params, self._btab)
             obs.watch_ready("tree::root_histogram", rec)
-        leaf_total = {0: float(self.N)}
         next_leaf = 1
         if self._forced is not None:
             state, next_leaf = self._apply_forced_splits(
-                tree, state, feature_mask, rand_seed, leaf_total)
+                tree, state, feature_mask, rand_seed)
         per_node = self._needs_per_node_masks()
         if per_node and self._forced is not None:
             log.warning("forced splits combined with per-node feature "
@@ -1252,21 +669,17 @@ class SerialTreeLearner(CapabilityMixin):
         if per_node and self._forced is None:
             state = train_stepwise(self, tree, state, rec, feature_mask,
                                    rand_seed)
-        elif self._fused_growth:
+        else:
             state = self._train_fused(tree, state, feature_mask,
                                       rand_seed, next_leaf)
-        else:
-            state = self._train_batched(tree, state, feature_mask,
-                                        rand_seed, leaf_total, next_leaf)
         return tree, _rows_out_fn_cached(self.N)(state.leaf_of_row)
 
     # ------------------------------------------------------------------
     def _train_fused(self, tree: Tree, state: GrowState, feature_mask,
                      rand_seed, next_leaf: int = 1) -> GrowState:
         """Whole-tree device growth: one `serial.fused_tree` dispatch,
-        one record read-back (vs one per ~kb-split batch on the stepped
-        path). `next_leaf` > 1 continues after a forced-split
-        preamble."""
+        one record read-back. `next_leaf` > 1 continues after a
+        forced-split preamble."""
         max_splits = self.L - next_leaf
         if max_splits <= 0:
             return state
@@ -1276,9 +689,9 @@ class SerialTreeLearner(CapabilityMixin):
                              dev_i32(max_splits), feature_mask,
                              rand_seed, self._qscale, self.meta,
                              self.params, self._btab)
-            # jaxlint: disable=JLT001 -- THE per-tree host sync of the
-            # fused path: the whole tree's split records read back in
-            # one deliberate hop (the grow loop itself never syncs)
+            # jaxlint: disable=JLT001 -- THE per-tree host sync: the
+            # whole tree's split records read back in one deliberate
+            # hop (the grow loop itself never syncs)
             recs_h = jax.device_get(recs)
         with obs.scope("tree::apply_records"):
             for i in range(max_splits):
@@ -1288,48 +701,9 @@ class SerialTreeLearner(CapabilityMixin):
                 apply_split_record(tree, self.dataset, r)
         return state
 
-    # ------------------------------------------------------------------
-    def _train_batched(self, tree: Tree, state: GrowState,
-                       feature_mask, rand_seed, leaf_total=None,
-                       next_leaf: int = 1) -> GrowState:
-        if leaf_total is None:
-            leaf_total = {0: float(self.N)}
-        while next_leaf < self.L:
-            M = max(leaf_total.values())
-            S = self._bucket(M / 2)
-            fn, kb = self._batch_fn(S)
-            max_splits = min(kb, self.L - next_leaf)
-            # split_batches = per-leaf child histogram + best-split scan
-            # steps fused into one dispatch; the device_get is the
-            # per-batch sync, so the scope covers the real device time
-            with obs.scope("tree::split_batches"):
-                state, recs = fn(self.bins, state, dev_i32(next_leaf),
-                                 dev_i32(max_splits), feature_mask,
-                                 rand_seed, self._qscale, self.meta,
-                                 self.params, self._btab)
-                # jaxlint: disable=JLT001 -- the LEGACY stepped path's
-                # per-batch host sync (tpu_fused_tree=false; also the
-                # fused path's bit-parity reference): the split records
-                # must reach the host Tree once per ~log2(L) batch
-                recs_h = jax.device_get(recs)
-            stop = False
-            with obs.scope("tree::apply_records"):
-                for i in range(max_splits):
-                    r = jax.tree_util.tree_map(lambda a: a[i], recs_h)
-                    if not record_is_valid(r):
-                        stop = True
-                        break
-                    apply_split_record(tree, self.dataset, r)
-                    leaf_total[int(r.leaf)] = float(r.left_total_count)
-                    leaf_total[next_leaf] = float(r.right_total_count)
-                    next_leaf += 1
-            if stop:
-                break
-        return state
-
     # --- adapter methods for the shared capability drivers
     # (treelearner/capabilities.py): each wraps this learner's cached
-    # jitted step functions with its bucketed gather size ---------------
+    # jitted step functions ---------------------------------------------
 
     def _cegb_root(self, gh, feature_mask):
         root = _cegb_root_fn_cached(self.L, self.B, self.Bg,
@@ -1341,9 +715,8 @@ class SerialTreeLearner(CapabilityMixin):
                     self._cegb_lazy, self._qscale, self.meta,
                     self.params, self._btab)
 
-    def _cegb_step(self, state, leaf, k, allowed, feature_mask, smaller):
-        S = self._bucket(smaller)
-        fn = _cegb_step_fn_cached(S, self.B, self.Bg, self._bundled,
+    def _cegb_step(self, state, leaf, k, allowed, feature_mask):
+        fn = _cegb_step_fn_cached(self.B, self.Bg, self._bundled,
                                   self._cegb_has_lazy,
                                   self._has_cat, self._hist_impl)
         state, rec, self._cegb_used, self._cegb_fetched = fn(
@@ -1363,10 +736,8 @@ class SerialTreeLearner(CapabilityMixin):
                        self._splittable(0), rand_seed, self._qscale,
                        self.meta, self.params, self._btab)
 
-    def _mono_step(self, state, leaf, k, allowed, feature_mask, bounds,
-                   smaller):
-        S = self._bucket(smaller)
-        fn = _mono_step_fn_cached(S, self.B, self.Bg, self._bundled,
+    def _mono_step(self, state, leaf, k, allowed, feature_mask, bounds):
+        fn = _mono_step_fn_cached(self.B, self.Bg, self._bundled,
                                   self._has_cat, self._hist_impl)
         return fn(self.bins, state, jnp.int32(leaf), jnp.int32(k),
                   jnp.asarray(allowed), feature_mask,
@@ -1397,9 +768,8 @@ class SerialTreeLearner(CapabilityMixin):
                   self._qscale, self.meta, self.params, self._btab)
 
     def _node_step(self, state, leaf, k, allowed, mask_left, mask_right,
-                   rand_seed, smaller):
-        S = self._bucket(smaller)
-        return self._step_fn(S)(
+                   rand_seed):
+        return self._step_fn()(
             self.bins, state, jnp.int32(leaf), jnp.int32(k),
             jnp.asarray(allowed), mask_left, mask_right, rand_seed,
             self._qscale, self.meta, self.params, self._btab)

@@ -28,8 +28,8 @@ because
 Per-row O(1)-width state (the [R, 4] gh rows, per-shard row→leaf
 segments) stays device-resident: O(N) words next to the O(N·F)-byte
 bins payload the shards stream. The device argmax that picks the next
-leaf is read back once per split (the documented JLT001 sync, like the
-serial learner's per-batch read-back) — so a tree costs
+leaf is read back once per split (the documented JLT001 sync, where the
+serial learner reads back once per tree) — so a tree costs
 ``num_leaves`` shard sweeps. Batching K splits per sweep is the
 standing follow-up (ROADMAP).
 
@@ -59,10 +59,10 @@ from ..ops.split import (FeatureMeta, SplitParams, calculate_leaf_output,
 from ..utils import log, next_pow2 as _next_pow2
 from ..utils.scalars import dev_bool, dev_i32
 from .capabilities import CapabilityMixin
-from .serial import (_finish_split, _go_left_by_bin, _maybe_rand_bins,
-                     _pad_rows_fn_cached, _partition_rec, _record_at,
-                     _stage_gh_fn_cached, apply_split_record,
-                     make_root_state, rec_valid, record_is_valid)
+from .grow import (_finish_split, _go_left_by_bin, _maybe_rand_bins,
+                   _partition_rec, _record_at, apply_split_record,
+                   make_root_state, rec_valid, record_is_valid)
+from .serial import _pad_rows_fn_cached, _stage_gh_fn_cached
 
 
 def _accum_hist(hist: jnp.ndarray, bins: jnp.ndarray,
@@ -143,8 +143,8 @@ def _root_fn_cached(L: int, B: int, extra_trees: bool, has_cat: bool):
 def _shard_step(shard_bins, leaf_seg, gh_seg, hist, rec, new_leaf, meta,
                 S: int):
     """One shard's slice of a split step: route the shard's rows of the
-    split leaf left/right (the serial ``_split_body`` partition update,
-    applied to this contiguous row segment), then gather the rows now
+    split leaf left/right (the partition update of grow.py's
+    ``_split_step``, applied to this contiguous row segment), then gather the rows now
     sitting on the SMALLER child and scatter them into the running
     child histogram. Shard segments are disjoint contiguous row ranges,
     so sweeping them in order performs the identical per-row updates —
@@ -153,8 +153,8 @@ def _shard_step(shard_bins, leaf_seg, gh_seg, hist, rec, new_leaf, meta,
 
     ``S`` is the STATIC gather width: a power-of-two bucket of the
     smaller child's global row count (an upper bound on any shard's
-    share of it), the same trick the serial learner's ``_bucket`` uses
-    to keep deep-tree steps from scanning all rows. Fill rows hit the
+    share of it), the bucketing grow.py's ``_compact_child_hist`` does
+    on the device, to keep deep-tree steps from scanning all rows. Fill rows hit the
     shard's zero pad row (gh 0), so the bucket size changes compiled
     variants, never values. ``rec`` comes through ``_partition_rec``:
     on data with no categorical feature its categorical fields are
@@ -180,8 +180,7 @@ def _shard_step(shard_bins, leaf_seg, gh_seg, hist, rec, new_leaf, meta,
 _shard_step_fn = obs_compile.instrument_jit(
     "sharded.shard_step", _shard_step, static_argnums=(7,))
 
-# gather-bucket floor: caps compiled shard-step variants (serial's
-# _MIN_BUCKET discipline)
+# gather-bucket floor: caps compiled shard-step variants
 _MIN_BUCKET = 256
 
 
@@ -681,8 +680,7 @@ class ShardedTreeLearner(CapabilityMixin):
                     rand_seed, self._qscale, self.meta, self.params)
                 # jaxlint: disable=JLT001 -- THE per-split host sync:
                 # the applied split's record plus the next argmax
-                # choice read back together (the sharded analogue of
-                # the serial learner's per-batch read-back)
+                # choice read back together
                 next_rec_h = jax.device_get(next_rec)
             apply_split_record(tree, self.dataset, rec_h)
             next_leaf += 1

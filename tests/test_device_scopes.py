@@ -66,13 +66,12 @@ def lowered():
                  sds((), jnp.int32), sds((2,), jnp.float32), serial.meta,
                  serial.params, serial._btab)
     state, _ = jax.eval_shape(serial._root_fn, *root_args)
-    batch, _ = serial._batch_fn(4096)
-    serial_batch = batch.lower(
+    serial_tree = serial._fused_fn().lower(
         root_args[0], state, sds((), jnp.int32), sds((), jnp.int32),
         root_args[3], sds((), jnp.int32), sds((2,), jnp.float32),
         serial.meta, serial.params, serial._btab)
     return {"root": root, "tree": tree, "tree_onehot": tree_onehot,
-            "serial_batch": serial_batch.as_text(debug_info=True)}
+            "serial_tree": serial_tree.as_text(debug_info=True)}
 
 
 @pytest.mark.parametrize("program,scope", [
@@ -87,28 +86,37 @@ def lowered():
     ("tree", "obs_split_scan"),
     ("tree", "obs_psum_histogram"),
     ("tree_onehot", "obs_hist_einsum"),
-    ("serial_batch", "obs_pick_leaf"),
-    ("serial_batch", "obs_partition"),
-    ("serial_batch", "obs_compact"),
-    ("serial_batch", "obs_hist_subtract"),
-    ("serial_batch", "obs_hist_store"),
-    ("serial_batch", "obs_split_scan"),
+    ("serial_tree", "obs_pick_leaf"),
+    ("serial_tree", "obs_partition"),
+    ("serial_tree", "obs_compact"),
+    ("serial_tree", "obs_hist_subtract"),
+    ("serial_tree", "obs_hist_store"),
+    ("serial_tree", "obs_split_scan"),
 ])
 def test_scope_is_in_the_lowered_program(lowered, program, scope):
     """``mesh.root``/``mesh.tree`` (``_root_impl``, ``_tree_impl``) on one
-    device, and the serial learner's batched step, which share the scoped
-    functions."""
+    device, and the serial learner's whole-tree program
+    (``serial.fused_tree``), which run the same scoped functions of
+    ``treelearner/grow.py``."""
     assert re.search(r'[/"]%s[/"]' % scope, lowered[program]), (
         "%s has no operation under %s" % (program, scope))
 
 
-def test_ladder_branches_are_tagged_inside_the_compaction(lowered):
-    """40,000 rows: buckets of 20,000 and 5,000, each its own branch of
-    the switch, with the histogram's scope inside."""
-    for branch, size in ((0, 20000), (1, 5000)):
+@pytest.mark.parametrize("program,buckets", [
+    ("tree", (20000, 5000)),
+    # 40,960 padded rows: the same loop and the same compaction
+    ("serial_tree", (20480, 5120)),
+])
+def test_ladder_branches_are_tagged_inside_the_compaction(lowered, program,
+                                                          buckets):
+    """40,000 rows: buckets of a half and an eighth of the learner's
+    rows, each its own branch of the switch inside the whole-tree
+    loop's body, with the histogram's scope inside. Both learners reach
+    the one ``_grow_tree`` and the one ``_compact_child_hist``."""
+    for branch, size in enumerate(buckets):
         assert re.search(
             r"while/body/obs_compact/cond/branch_%d_fun/obs_bucket_%d/"
-            r"obs_hist_scatter/" % (branch, size), lowered["tree"])
+            r"obs_hist_scatter/" % (branch, size), lowered[program])
 
 
 def test_pallas_path_is_scoped_and_the_kernel_named(monkeypatch):

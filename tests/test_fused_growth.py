@@ -1,17 +1,17 @@
-"""Fused whole-tree on-device growth (ISSUE 8).
+"""Whole-tree on-device growth (ISSUE 8, ISSUE 33).
 
 Three contracts:
 
-1. BIT parity — the fused serial grower (one `serial.fused_tree`
-   dispatch per tree, device argmax frontier + gather-ladder child
-   histograms) produces bit-identical trees AND train scores to the
-   stepped per-batch host loop across the capability matrix
-   (exact / quantized8 / quantized16 x bagging x multiclass x basic
-   monotone), and the sharded K-splits-per-sweep frontier stays
+1. One grower — the serial learner (one `serial.fused_tree` dispatch
+   per tree) and the data-parallel learner on a one-device mesh (the
+   program the benchmark's cells run) both run treelearner/grow.py's
+   whole-tree loop and split step, and grow bit-identical trees AND
+   train scores across the capability matrix (exact / quantized8 /
+   quantized16 x bagging x multiclass x basic monotone x the one-hot
+   histogram); the sharded K-splits-per-sweep frontier stays
    bit-identical to in-memory training while cutting shard stagings.
-2. Dispatch count — ≤ 3 grow dispatches per tree on the fused path
-   (stage_gh + root + ONE fused split_batches), asserted from the
-   trace layer's stage spans.
+2. Dispatch count — ≤ 3 grow dispatches per tree (stage_gh + root +
+   ONE split_batches), asserted from the trace layer's stage spans.
 3. The batched-iterations lift — quantized-gradient runs batch through
    `train_many` (scan-carried fold_in tree counter + alive flag) and
    match the looped path under the documented batched-path tolerance;
@@ -82,14 +82,83 @@ def _scores_bits(b):
 
 
 # ---------------------------------------------------------------------------
-# fused vs stepped serial growth: BIT parity matrix
+# serial vs one-device mesh: BIT parity matrix
 # ---------------------------------------------------------------------------
 
-class TestFusedVsSteppedParity:
-    """The acceptance pin: one whole-tree dispatch produces EXACTLY the
-    stepped host loop's trees and scores. The two model strings differ
-    only in the tpu_fused_tree parameter dump, so trees compare via
-    per-tree to_string."""
+SERIAL = {"tree_learner": "serial"}           # given, so it is honoured
+MESH1 = {"tree_learner": "data", "mesh_shape": "data=1"}
+
+
+def _train_pair(params, X, y, iters=3):
+    bs = _train_matrix(dict(params, **SERIAL), X, y, iters)
+    bm = _train_matrix(dict(params, **MESH1), X, y, iters)
+    assert type(bs.learner).__name__ == "SerialTreeLearner"
+    assert type(bm.learner).__name__ == "DataParallelTreeLearner"
+    assert bm.learner.mesh.devices.size == 1
+    return bs, bm
+
+
+def _tree_fields(tree):
+    return dict(line.split("=", 1)
+                for line in tree.to_string().splitlines() if "=" in line)
+
+
+_GAINS = ("split_gain",)
+_SUMS = ("leaf_value", "leaf_weight", "internal_value", "internal_weight")
+
+
+def _assert_same_trees_and_scores(bs, bm, integer_sums=False):
+    """What the pair gives bit for bit, and what it cannot.
+
+    Structure, thresholds, counts and so the partitions: always equal.
+    Both learners hand the same rows in the same ascending order to a
+    histogram that adds in row order (scatter) or in integers
+    (quantized), and the serial learner's pad rows and pad features add
+    zeros.
+
+    Leaf values, weights and train scores: bit-equal where the sums are
+    integers (``integer_sums``: quantized gradients). In float32 the
+    root's totals are one reduction over all R rows, R is 4,096-row
+    blocks in the serial learner and the row count in the mesh learner,
+    and XLA's reduction tree changes with the length: the totals, and
+    every right child's ``total - left`` after them, may differ in the
+    last bit. Held to float32 rounding there.
+
+    ``split_gain``: float32 rounding always. The mesh learner's programs
+    close over the split parameters as constants, the serial learner's
+    take them as arguments, and XLA folds a constant into the gain's
+    arithmetic (a difference of sums of hundreds) in another order.
+    Which leaf and which threshold win is equal, as the structure
+    shows."""
+    assert len(bs.models) == len(bm.models) > 0
+    close = _GAINS if integer_sums else _GAINS + _SUMS
+    for ts, tm in zip(bs.models, bm.models):
+        fs, fm = _tree_fields(ts), _tree_fields(tm)
+        assert ts.num_leaves > 1 and set(close) <= set(fs)
+        for key in close:
+            np.testing.assert_allclose(
+                np.array(fs.pop(key).split(), dtype=float),
+                np.array(fm.pop(key).split(), dtype=float),
+                rtol=2e-5, atol=1e-4 if key in _GAINS else 1e-7,
+                err_msg=key)
+        assert fs == fm
+    if integer_sums:
+        assert np.array_equal(_scores_bits(bs), _scores_bits(bm))
+    else:
+        np.testing.assert_allclose(np.asarray(bs.train_score),
+                                   np.asarray(bm.train_score),
+                                   rtol=2e-5, atol=1e-6)
+
+
+class TestSerialVsMeshParity:
+    """The serial learner, which nearly every CPU test grows its trees
+    through, against ``tree_learner=data`` on a one-device mesh, which
+    every cell of the benchmark runs: one split step and one whole-tree
+    loop (treelearner/grow.py), so the same trees and scores, bit for
+    bit where ``_assert_same_trees_and_scores`` says the pair can give
+    it. Their row and feature padding differ (4096-row blocks and
+    8-feature blocks against none), and so do their compaction
+    buckets."""
 
     @pytest.mark.parametrize("extra", [
         pytest.param({}, id="exact"),
@@ -98,58 +167,72 @@ class TestFusedVsSteppedParity:
                       "quant_grad_bits": 16}, id="quantized16"),
         pytest.param({"bagging_fraction": 0.7, "bagging_freq": 1},
                      id="bagging"),
-        # heaviest cell of the matrix (~43s: extra_trees retraces the
-        # split kernel); the randomized-threshold path keeps dedicated
+        # heaviest cell of the matrix (extra_trees retraces the split
+        # kernel); the randomized-threshold path keeps dedicated
         # coverage in the slow tier
         pytest.param({"extra_trees": True}, id="extra_trees",
                      marks=pytest.mark.slow),
         pytest.param({"monotone_constraints": [1, -1, 0, 0, 0, 0]},
                      id="basic_monotone"),
     ])
-    def test_bit_identical_trees_and_scores(self, extra):
+    def test_same_trees_and_scores(self, extra):
         X, y = _data()
-        params = dict(BASE, **extra)
-        bf = _train_matrix(dict(params, tpu_fused_tree=True), X, y)
-        bs = _train_matrix(dict(params, tpu_fused_tree=False), X, y)
-        assert [t.to_string() for t in bf.models] == \
-            [t.to_string() for t in bs.models]
-        assert np.array_equal(_scores_bits(bf), _scores_bits(bs))
+        bs, bm = _train_pair(dict(BASE, **extra), X, y)
+        _assert_same_trees_and_scores(
+            bs, bm, integer_sums="use_quantized_grad" in extra)
 
     def test_multiclass(self):
+        # noisy labels: on separable ones a class's gradients flatten
+        # after a few splits, its gains sink to rounding noise, and
+        # whether one is above zero is a matter of the last bit
         rng = np.random.RandomState(5)
         X = rng.randn(700, 5)
-        y = np.digitize(X[:, 0], [-0.5, 0.5]).astype(np.float64)
+        y = np.argmax(X[:, :3] + 0.5 * rng.randn(700, 3),
+                      axis=1).astype(np.float64)
         params = dict(BASE, objective="multiclass", num_class=3,
                       bin_construct_sample_cnt=700)
-        bf = _train_matrix(dict(params, tpu_fused_tree=True), X, y)
-        bs = _train_matrix(dict(params, tpu_fused_tree=False), X, y)
-        assert [t.to_string() for t in bf.models] == \
-            [t.to_string() for t in bs.models]
-        assert np.array_equal(_scores_bits(bf), _scores_bits(bs))
+        bs, bm = _train_pair(params, X, y)
+        assert len(bs.models) == 9
+        _assert_same_trees_and_scores(bs, bm)
 
-    def test_forced_splits_continue_fused(self, tmp_path):
-        """A forced-split preamble hands the frontier to the fused
-        grower mid-tree (start_leaf > 1) — same trees as stepped."""
+    def test_onehot_histogram(self):
+        """``hist_backend=onehot``: the histogram is a contraction over
+        the bucket's rows, and the two learners' buckets differ in size
+        (2,048 rows against 400 here): one more float32 sum whose order
+        may differ, under the same tolerance."""
+        X, y = _data()
+        bs, bm = _train_pair(dict(BASE, hist_backend="onehot"), X, y)
+        _assert_same_trees_and_scores(bs, bm)
+
+    def test_forced_splits_then_growth(self, tmp_path):
+        """A forced-split preamble hands the frontier to the whole-tree
+        loop mid-tree (start_leaf > 1): the forced root and its forced
+        left child hold, growth continues to num_leaves, and two runs
+        give the same trees. Serial only: the mesh learners ignore
+        forced splits, with a warning."""
         path = tmp_path / "forced.json"
         path.write_text(json.dumps(
             {"feature": 0, "threshold": 0.0,
              "left": {"feature": 1, "threshold": 0.0}}))
         X, y = _data()
-        params = dict(BASE, forcedsplits_filename=str(path),
-                      tree_learner="serial")
-        bf = _train_matrix(dict(params, tpu_fused_tree=True), X, y)
-        bs = _train_matrix(dict(params, tpu_fused_tree=False), X, y)
-        assert [t.to_string() for t in bf.models] == \
-            [t.to_string() for t in bs.models]
-        t0 = bf.models[0]
-        assert int(t0.split_feature[0]) == 0  # the forced root held
-
-    def test_fused_is_default(self):
-        X, y = _data(400)
-        ds = BinnedDataset.from_matrix(
-            X, Config.from_params(dict(BASE)), label=y)
-        booster = _train(ds, dict(BASE), iters=1)
-        assert booster.learner._fused_growth
+        params = dict(BASE, forcedsplits_filename=str(path), **SERIAL)
+        first = _train_matrix(params, X, y)
+        again = _train_matrix(params, X, y)
+        assert [t.to_string() for t in first.models] == \
+            [t.to_string() for t in again.models]
+        free = _train_matrix(dict(BASE, **SERIAL), X, y)
+        t0 = first.models[0]
+        assert t0.num_leaves == BASE["num_leaves"]
+        # node 0 is the forced root, node 1 its forced left child
+        assert int(t0.split_feature[0]) == 0
+        assert int(t0.left_child[0]) == 1
+        assert int(t0.split_feature[1]) == 1
+        for node, feature in ((0, 0), (1, 1)):
+            mapper = first.learner.dataset.bin_mappers[feature]
+            assert int(t0.threshold_in_bin[node]) == int(
+                mapper.value_to_bin(np.asarray([0.0]))[0])
+        # and it is the forcing that put them there
+        assert free.models[0].to_string() != t0.to_string()
 
 
 # ---------------------------------------------------------------------------
@@ -163,8 +246,7 @@ GROW_SCOPES = ("tree::stage_gh", "tree::root_histogram",
 class TestDispatchCount:
     def test_fused_le3_dispatches_per_tree_from_trace(self, tmp_path):
         """Exported trace spans: each tree::grow span contains exactly
-        one stage_gh + one root_histogram + ONE split_batches span —
-        the stepped path's per-batch loop is gone."""
+        one stage_gh + one root_histogram + ONE split_batches span."""
         path = str(tmp_path / "trace.json")
         registry.reset()
         registry.enable(sampling=True)
@@ -183,18 +265,6 @@ class TestDispatchCount:
         per_tree = sum(1 for e in spans
                        if e["name"] in GROW_SCOPES) / iters
         assert per_tree <= 3.0
-
-    def test_stepped_path_still_batches(self):
-        """The legacy path keeps multiple split_batches dispatches per
-        tree (the regression guard's control arm)."""
-        registry.reset()
-        registry.enable()
-        X, y = _data(600)
-        _train_matrix(dict(BASE, num_leaves=31, tpu_fused_tree=False),
-                      X, y, iters=2)
-        phases = registry.phases()
-        registry.disable()
-        assert phases["tree::split_batches"]["calls"] > 2
 
 
 # ---------------------------------------------------------------------------
@@ -386,7 +456,7 @@ class TestFusedTransferGuard:
         at all."""
         import jax
         X, y = _data(500)
-        params = dict(BASE, num_leaves=7, tpu_fused_tree=True, **extra)
+        params = dict(BASE, num_leaves=7, **extra)
         ds = BinnedDataset.from_matrix(
             X, Config.from_params(dict(params)), label=y)
         booster = create_boosting(

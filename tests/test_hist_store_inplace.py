@@ -1,7 +1,7 @@
 """The per-leaf histogram store is updated in place.
 
 A split step reads the two old slices of ``GrowState.hists`` once,
-before its first write (``treelearner/serial.py _split_hist_store``). A
+before its first write (``treelearner/grow.py _split_hist_store``). A
 read of the old store ordered after a write makes XLA keep the carried
 buffer alive across that write: on the v5e the whole ``f32[L,F,B,4]``
 store was then copied twice per split (ISSUE 28). Two guards:
@@ -27,7 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.dataset import BinnedDataset
 from lightgbm_tpu.parallel import DataParallelTreeLearner, make_mesh
-from lightgbm_tpu.treelearner.serial import _split_hist_store
+from lightgbm_tpu.treelearner.grow import _split_hist_store
 
 
 def describe_v5e():

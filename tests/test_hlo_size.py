@@ -32,7 +32,6 @@ def learner():
     lrn = SerialTreeLearner(cfg, ds)
     # pretend the dataset is 1M rows: rebuild shape-dependent attributes
     lrn.N = N_BIG
-    lrn._max_bucket = 1 << 20
     return lrn
 
 
@@ -65,23 +64,21 @@ def test_root_hlo_small(learner):
     assert n < MAX_HLO_BYTES, f"root HLO is {n} bytes"
 
 
-def test_batch_step_hlo_small(learner):
+def test_fused_tree_hlo_small(learner):
     args = _root_args(learner)
     state_sds, _ = jax.eval_shape(learner._root_fn, *args)
-    S = 1 << 18
-    fn, _ = learner._batch_fn(S)
-    lowered = fn.lower(args[0], state_sds, _sds((), jnp.int32),
-                       _sds((), jnp.int32), args[3], _sds((), jnp.int32),
-                       _sds((2,), jnp.float32),
-                       learner.meta, learner.params, learner._btab)
+    lowered = learner._fused_fn().lower(
+        args[0], state_sds, _sds((), jnp.int32), _sds((), jnp.int32),
+        args[3], _sds((), jnp.int32), _sds((2,), jnp.float32),
+        learner.meta, learner.params, learner._btab)
     n = _hlo_bytes(lowered)
-    assert n < MAX_HLO_BYTES, f"batch step HLO is {n} bytes"
+    assert n < MAX_HLO_BYTES, f"whole-tree HLO is {n} bytes"
 
 
 def test_stepwise_hlo_small(learner):
     args = _root_args(learner)
     state_sds, _ = jax.eval_shape(learner._root_fn, *args)
-    fn = learner._step_fn(1 << 18)
+    fn = learner._step_fn()
     lowered = fn.lower(args[0], state_sds, _sds((), jnp.int32),
                        _sds((), jnp.int32), _sds((), jnp.bool_),
                        args[3], args[3], _sds((), jnp.int32),

@@ -39,8 +39,8 @@ from lightgbm_tpu.io.shards import ShardedBinnedDataset
 from lightgbm_tpu.obs.registry import registry
 from lightgbm_tpu.parallel import DataParallelTreeLearner, make_mesh
 from lightgbm_tpu.treelearner import sharded
-from lightgbm_tpu.treelearner.serial import (SerialTreeLearner,
-                                             _go_left_by_bin, _split_body)
+from lightgbm_tpu.treelearner.grow import _go_left_by_bin
+from lightgbm_tpu.treelearner.serial import SerialTreeLearner, _split_body
 from test_hist_store_inplace import compile_tree_program, describe_v5e
 
 PARAMS = {"objective": "binary", "num_leaves": 7, "max_bin": 31,
@@ -104,7 +104,7 @@ def _serial_step(categorical: bool, tmp_path):
         jnp.asarray(True), jnp.int32(0), ln._qs_ones, ln.meta, ln.params,
         ln._btab)
     step = functools.partial(
-        _split_body, S=ln.R // 2, B=ln.B, Bg=ln.Bg, bundled=ln._bundled,
+        _split_body, B=ln.B, Bg=ln.Bg, bundled=ln._bundled,
         max_depth=ln.max_depth, extra_trees=False, has_cat=ln._has_cat,
         hist_impl=ln._hist_impl)
     jaxpr = jax.make_jaxpr(step)(
