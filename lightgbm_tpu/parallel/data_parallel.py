@@ -63,10 +63,20 @@ from ..treelearner.grow import (GrowState, SplitRecord,
                                 _compact_child_hist, _compact_sizes,
                                 _grow_tree, _maybe_rand_bins, _record_at,
                                 _rows_go_left, _split_step, _store_info,
-                                _subtract_child_hists, apply_split_record,
+                                _subtract_child_hists, _window_sizes,
+                                apply_split_record,
                                 build_bundle_tables, make_root_state,
                                 rec_valid, record_is_valid)
 from ..utils import log
+
+
+def _padded_to_ladder(counts: np.ndarray, sizes: list) -> int:
+    """``counts`` summed, each padded to the smallest of ``sizes`` (a
+    ladder of grow.py, largest first) that holds it: the host's twin of
+    ``grow._ladder_branch``."""
+    sizes = np.asarray(sizes[::-1])
+    return sizes[np.minimum(np.searchsorted(sizes, counts),
+                            len(sizes) - 1)].sum()
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "data") -> Mesh:
@@ -300,12 +310,19 @@ class DataParallelTreeLearner(CapabilityMixin):
         leaf_of_row = self._initial_partition(gh)
         state = make_root_state(gh, hist, leaf_of_row, info, self.L,
                                 self.F, self.B, self._splittable(0),
-                                hist_slots=self._hist_slots)
+                                hist_slots=self._hist_slots,
+                                ordered=self._compacts())
         return state, _record_at(state, 0)
 
     def _root_impl(self, bins, gh, feature_mask, rand_seed, qscale):
         return self._root_impl_opts(bins, gh, feature_mask, rand_seed,
                                     self._extra_trees, qscale)
+
+    def _compacts(self) -> bool:
+        """Whether ``_children_histograms`` compacts the smaller child's
+        rows, and the grow state so keeps the rows ordered by leaf: on
+        one device. Across a mesh it would be a global reshuffle."""
+        return self.mesh.devices.size == 1
 
     def _mesh_split_body(self, bins, state: GrowState, rec: SplitRecord,
                          leaf, new_leaf, valid, mask_left, mask_right,
@@ -330,8 +347,9 @@ class DataParallelTreeLearner(CapabilityMixin):
         histograms and the per-child scan masks. Base learner: the
         smaller child's histogram, its sibling by subtraction
         (``_subtract_child_hists``). On one device the child's rows are
-        compacted first, so histogram cost tracks the child's size
-        (the reference's DataPartition + per-leaf iterators,
+        compacted first (its segment of ``state.order``, which the split
+        step has just reordered), so histogram cost tracks the child's
+        size (the reference's DataPartition + per-leaf iterators,
         data_partition.hpp:21); a sharded mesh keeps the masked
         histogram over the full row space (the analogue of the
         reference ranks histogramming their local leaf rows then
@@ -340,10 +358,10 @@ class DataParallelTreeLearner(CapabilityMixin):
         shard already scans only its local rows. Voting-parallel
         overrides this with the reduced-comm vote and skips the
         store."""
-        def small_hist(mask, totals):
-            if self.mesh.devices.size == 1:
+        def small_hist(small, mask, totals):
+            if self._compacts():
                 return _compact_child_hist(
-                    bins, state.gh, mask, totals, _compact_sizes(self.R),
+                    bins, state, small, totals, _compact_sizes(self.R),
                     self._mesh_hist)
             # dtype-preserving mask (an f32 multiply would de-quantize
             # integer gh rows)
@@ -402,7 +420,8 @@ class DataParallelTreeLearner(CapabilityMixin):
             hist_scale=qscale)
         state = make_root_state(gh, hist, leaf_of_row, info, self.L,
                                 self.F, self.B, self._splittable(0),
-                                hist_slots=self._hist_slots)
+                                hist_slots=self._hist_slots,
+                                ordered=self._compacts())
         return state, _record_at(state, 0)
 
     def _cegb_step_impl(self, bins, state, leaf, new_leaf, feature_mask,
@@ -678,6 +697,7 @@ class DataParallelTreeLearner(CapabilityMixin):
             if obs.enabled:
                 self._count_hist_rows(recs_h, applied)
                 self._count_partition_splits(recs_h, applied)
+                self._count_partition_rows(recs_h, applied)
         return tree, self._finalize_partition(state.leaf_of_row)
 
     def _count_hist_rows(self, recs_h, applied: int) -> None:
@@ -706,11 +726,23 @@ class DataParallelTreeLearner(CapabilityMixin):
         smaller children hold ``small`` rows: the bucket
         ``_compact_child_hist`` picks on one device, the whole masked
         row space on a sharded mesh."""
-        if self.mesh.devices.size != 1:
+        if not self._compacts():
             return self.R * len(small)
-        sizes = np.asarray(_compact_sizes(self.R)[::-1])
-        return sizes[np.minimum(np.searchsorted(sizes, small),
-                                len(sizes) - 1)].sum()
+        return _padded_to_ladder(small, _compact_sizes(self.R))
+
+    def _count_partition_rows(self, recs_h, applied: int) -> None:
+        """Where the learner keeps the rows ordered by leaf:
+        ``grow/partition_parent_rows``, the rows of each applied split's
+        parent, which ``_partition_order`` has to reorder;
+        ``grow/partition_window_rows``: the windows it reordered for
+        them."""
+        if not self._compacts():
+            return
+        parents = (recs_h.left_total_count[:applied]
+                   + recs_h.right_total_count[:applied])
+        obs.inc("grow/partition_parent_rows", int(parents.sum()))
+        obs.inc("grow/partition_window_rows",
+                int(_padded_to_ladder(parents, _window_sizes(self.R))))
 
     # --- device-resident multi-iteration batching ---------------------
     # Every dispatch and every host sync costs the device idle time

@@ -117,6 +117,10 @@ class VotingParallelTreeLearner(DataParallelTreeLearner):
             in_specs=(P(axis, None), P(axis, None), P(), P()),
             out_specs=(P(), P()))(bins, gh_masked, feature_mask, qscale)
 
+    def _compacts(self) -> bool:
+        # both children are voted on over the masked row space
+        return False
+
     def _children_histograms(self, bins, state, rec, leaf, new_leaf,
                              leaf_of_row, smaller_is_left, valid,
                              mask_left, mask_right, qscale=None):

@@ -14,14 +14,22 @@ XLA needs static shapes, so the two data-dependent quantities are
 handled as:
 
 - **row->leaf partition**: a full-length ``leaf_of_row`` vector updated
-  by a vectorized compare on the split feature's bin column (no index
-  lists; the analogue of the reference's DataPartition::Split,
+  by a vectorized compare on the split feature's bin column (the
+  analogue of the reference's DataPartition::Split,
   src/treelearner/data_partition.hpp:21 / cuda_data_partition.cu:288).
+- **rows ordered by leaf** (``_partition_order``; learners that compact,
+  which is every learner on one device): ``order`` holds the row
+  numbers with each leaf's rows contiguous and ascending, ``seg_begin``
+  / ``seg_count`` each leaf's segment (the reference's ``indices_``,
+  ``leaf_begin_``, ``leaf_count_``). A split reorders its parent's
+  segment only: the smallest window of a static ladder
+  (``_window_sizes``) that holds the parent is sliced out, stably
+  partitioned into left rows then right rows, and written back.
 - **per-leaf row gather** (``_compact_child_hist``): the smaller
-  child's rows are compacted, in ascending order, into the smallest
-  bucket of a static ladder (``_compact_sizes``) that holds them, one
-  ``lax.switch`` branch per bucket; the bucket's tail carries gh 0 and
-  vanishes from every sum.
+  child's rows, a slice of ``order`` and so in ascending order, fill the
+  smallest bucket of a static ladder (``_compact_sizes``) that holds
+  them, one ``lax.switch`` branch per bucket; the bucket's tail carries
+  gh 0 and vanishes from every sum.
 
 max_depth gating follows BeforeFindBestSplit (serial_tree_learner.cpp:287):
 a leaf at depth d is splittable iff max_depth <= 0 or d < max_depth —
@@ -30,7 +38,7 @@ using a device-resident per-leaf depth vector.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +82,12 @@ class GrowState(NamedTuple):
     right_count: jnp.ndarray
     right_total_count: jnp.ndarray
     right_output: jnp.ndarray
+    # Rows ordered by leaf, where the learner compacts (None where it
+    # histograms the masked row space: no leaf of the pytree, so such a
+    # learner's programs carry nothing for them):
+    order: Optional[jnp.ndarray] = None      # [R + W] i32, make_root_state
+    seg_begin: Optional[jnp.ndarray] = None  # [L] i32 — leaf's first entry
+    seg_count: Optional[jnp.ndarray] = None  # [L] i32 — its physical rows
 
 
 class SplitRecord(NamedTuple):
@@ -166,12 +180,32 @@ def _store_info(state: GrowState, leaf, info: SplitInfo, allowed,
 
 
 def make_root_state(gh, hist, leaf_of_row, info, L: int, F: int, B: int,
-                    children_allowed, hist_slots: int = 0) -> GrowState:
+                    children_allowed, hist_slots: int = 0,
+                    ordered: bool = False) -> GrowState:
     """Initial GrowState after the root histogram+scan (shared by the
     serial and mesh-parallel learners). ``hist_slots`` shrinks the
     per-leaf histogram store for learners that never re-read it (the
-    voting learner re-votes per leaf instead of subtracting)."""
+    voting learner re-votes per leaf instead of subtracting).
+
+    ``ordered`` (the learner compacts) adds the rows ordered by leaf:
+    ``order[:R]`` is leaf 0's rows, ascending, then the pad rows (leaf
+    -1); ``order[R:]`` is W entries of row 0 that no segment owns, W
+    the largest window of ``_window_sizes``, there so that a window or
+    a bucket sliced from any segment's begin never passes the end
+    (``dynamic_slice`` would shift it left, and the rows would reach
+    the histogram at other positions)."""
     hist_slots = hist_slots or L
+    by_leaf = {}
+    if ordered:
+        R = leaf_of_row.shape[0]
+        on_root = leaf_of_row == 0
+        dest, n_root = _lefts_first(on_root, jnp.ones(R, dtype=bool))
+        zl = jnp.zeros(L, dtype=jnp.int32)
+        by_leaf = dict(
+            order=jnp.zeros(R + _window_sizes(R)[0], dtype=jnp.int32)
+            .at[dest].set(jnp.arange(R, dtype=jnp.int32),
+                          unique_indices=True, mode="promise_in_bounds"),
+            seg_begin=zl, seg_count=zl.at[0].set(n_root))
     zf = lambda: jnp.zeros(L, dtype=jnp.float32)
     state = GrowState(
         leaf_of_row=leaf_of_row, gh=gh,
@@ -191,7 +225,7 @@ def make_root_state(gh, hist, leaf_of_row, info, L: int, F: int, B: int,
         left_sum_grad=zf(), left_sum_hess=zf(), left_count=zf(),
         left_total_count=zf(), left_output=zf(), right_sum_grad=zf(),
         right_sum_hess=zf(), right_count=zf(), right_total_count=zf(),
-        right_output=zf())
+        right_output=zf(), **by_leaf)
     return _store_info(state, 0, info, children_allowed)
 
 
@@ -446,51 +480,162 @@ def _compact_sizes(R: int) -> list:
     return sizes
 
 
+_ORDER_CHUNK = 16384
+
+
+def _window_sizes(R: int) -> list:
+    """The window ladder's sizes for ``R`` rows, largest first: a chunk
+    of ``_ORDER_CHUNK`` entries doubled until it holds every row, so
+    each is a whole number of chunks. Doubled, where the buckets
+    quadruple: a window's cost is its size, and the branches hold no
+    histogram, so a finer ladder costs next to nothing to compile."""
+    sizes = [_ORDER_CHUNK]
+    while sizes[0] < R:
+        sizes.insert(0, 2 * sizes[0])
+    return sizes
+
+
+def _ladder_branch(sizes, count):
+    """Index of the smallest of ``sizes`` (largest first) that holds
+    ``count`` rows."""
+    return jnp.clip(
+        jnp.sum(jnp.asarray(sizes, dtype=jnp.int32) >= count) - 1,
+        0, len(sizes) - 1)
+
+
+def _prefix_counts(flags):
+    """Inclusive prefix counts of ``flags`` ([n] bool, or f32 holding
+    whole numbers), as f32: exact, every partial sum being a whole
+    number under 2**24. Rows of 128 are summed by a product with a
+    triangle of ones, and the rows' totals the same way one level up.
+    ``jnp.cumsum`` gives the same numbers, but the TPU's compiler takes
+    18 s over one of a million entries and so much again for every
+    other size (host-side v5e compile, PR 34), which seven window
+    branches cannot afford; this takes 1 s."""
+    x = flags.astype(jnp.float32)
+    n = x.shape[0]
+    if n <= 128:
+        return jnp.cumsum(x)
+    ones = jnp.triu(jnp.ones((128, 128), dtype=jnp.float32))
+    rows = jnp.matmul(jnp.pad(x, (0, -n % 128)).reshape(-1, 128), ones,
+                      precision=jax.lax.Precision.HIGHEST)
+    before = _prefix_counts(rows[:, -1]) - rows[:, -1]
+    return (rows + before[:, None]).reshape(-1)[:n]
+
+
+def _lefts_first(left, inside):
+    """``(dest, n_left)`` of a stable partition of the entries under
+    ``inside`` (a prefix of the positions): an entry under ``left``
+    goes behind the lefts before it, another behind all lefts and the
+    others before it (its position less the lefts so far); what lies
+    behind ``inside`` keeps its place."""
+    j = jnp.arange(left.shape[0], dtype=jnp.int32)
+    lefts = _prefix_counts(left).astype(jnp.int32)
+    n_left = lefts[-1]
+    return jnp.where(left, lefts - 1,
+                     jnp.where(inside, n_left + j - lefts, j)), n_left
+
+
 @jax.named_scope("obs_compact")
-def _compact_child_hist(bins, gh, mask, totals, sizes, hist_fn):
-    """Gather the rows under ``mask`` (the smaller child's; ``totals[3]``
-    is their count) into the smallest bucket of ``sizes`` that holds
-    them (``lax.switch`` over compiled sizes) and histogram only those
-    with ``hist_fn(bins, gh, totals)``, the learner's own histogram. A
-    leaf-wise tree's total smaller-child row count is ~N·log2(L)/2, so
-    this cuts per-tree histogram work by ~50x at 255 leaves vs masked
-    full-row scans — the single-chip analogue of the reference's
-    per-leaf row iterators (data_partition.hpp:119 GetIndexOnLeaf). The
-    scatter/gather compaction itself is O(R) bandwidth, far below the
-    histogram's O(S·F) compute. The rows keep their ascending order and
-    the bucket's tail is zeroed in ``gh``, so which bucket runs changes
-    compiled programs, never values."""
-    R = bins.shape[0]
-    count = totals[3].astype(jnp.int32)     # rows on the leaf
-    pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
-    rows = jnp.arange(R, dtype=jnp.int32)
+def _partition_order(order, seg_begin, seg_count, gl, leaf, new_leaf,
+                     valid):
+    """Reorder ``leaf``'s segment of ``order`` for its split: the rows
+    that ``gl`` ([R] bool, by row number) sends left first, the others
+    behind them, both still ascending; ``leaf`` keeps the left part and
+    ``new_leaf`` gets the right — the reference's DataPartition::Split
+    (data_partition.hpp:107) on ``indices_``, ``leaf_begin_`` and
+    ``leaf_count_``. Returns ``(order, seg_begin, seg_count)``; an
+    invalid step returns all three bit for bit as they were.
+
+    Only the parent's rows are touched: the smallest window of
+    ``_window_sizes`` that holds them is sliced out at the segment's
+    begin (``order``'s spare tail keeps the slice from ever being
+    shifted), its first ``cnt`` entries are placed by one prefix count
+    and one scatter of the window's size, and the chunks of the window
+    that hold them are written back. A scatter of every row number,
+    whatever the leaf's size, was a third of an iteration at 1M rows
+    (5.9 ns a row a split on the v5e, chip traces of PR 28-33)."""
+    begin, cnt = seg_begin[leaf], seg_count[leaf]
+    sizes = _window_sizes(gl.shape[0])
+
+    def make_branch(W):
+        @jax.named_scope("obs_window_%d" % W)
+        def branch(_):
+            win = jax.lax.dynamic_slice(order, (begin,), (W,))
+            inside = jnp.arange(W, dtype=jnp.int32) < cnt
+            dest, n_left = _lefts_first(
+                inside & gl.at[win].get(mode="promise_in_bounds"), inside)
+            moved = jnp.zeros_like(win).at[dest].set(
+                win, unique_indices=True, mode="promise_in_bounds")
+            return jnp.pad(moved, (0, sizes[0] - W)), n_left
+        return branch
+
+    moved, n_left = jax.lax.switch(
+        _ladder_branch(sizes, cnt), [make_branch(W) for W in sizes], 0)
+
+    # the window goes back chunk by chunk, in a loop and not in the
+    # switch: a branch that returned the updated ``order`` made XLA copy
+    # the whole of it (the branches of a conditional share one operand)
+    def write_chunk(i, order):
+        at = i * _ORDER_CHUNK
+        return jax.lax.dynamic_update_slice(
+            order, jax.lax.dynamic_slice(moved, (at,), (_ORDER_CHUNK,)),
+            (begin + at,))
+    order = jax.lax.fori_loop(
+        0, jnp.where(valid, -(-cnt // _ORDER_CHUNK), 0), write_chunk,
+        order)
+
+    def put(arr, at, new):
+        return arr.at[at].set(jnp.where(valid, new, arr[at]))
+    return (order, put(seg_begin, new_leaf, begin + n_left),
+            put(put(seg_count, leaf, n_left), new_leaf, cnt - n_left))
+
+
+@jax.named_scope("obs_compact")
+def _compact_child_hist(bins, state: GrowState, leaf, totals, sizes,
+                        hist_fn):
+    """Gather the rows of ``leaf``'s segment of ``state.order`` (the
+    smaller child's) into the smallest bucket of ``sizes`` that holds
+    them (``lax.switch`` over compiled sizes) and histogram only
+    those with ``hist_fn(bins, gh, totals)``, the learner's own
+    histogram. A leaf-wise tree's total smaller-child row count is
+    ~N·log2(L)/2, so this cuts per-tree histogram work by ~50x at 255
+    leaves vs masked full-row scans — the single-chip analogue of the
+    reference's per-leaf row iterators (data_partition.hpp:119
+    GetIndexOnLeaf). The bucket's row numbers are a slice of ``order``;
+    what a split step pays besides the histogram is the two gathers
+    ``gh[idx]`` and ``bins[idx]`` of the bucket's size. The rows keep
+    their ascending order, the bucket's tail is row 0 and zeroed in
+    ``gh``, so which bucket runs changes compiled programs, never
+    values."""
+    gh = state.gh
+    begin, count = state.seg_begin[leaf], state.seg_count[leaf]
 
     def make_branch(S):
         @jax.named_scope("obs_bucket_%d" % S)
         def branch(_):
-            idx = jnp.zeros((S,), dtype=jnp.int32)
-            idx = idx.at[jnp.where(mask, pos, S)].set(rows,
-                                                      mode="drop")
-            keep = (jnp.arange(S, dtype=jnp.int32)
-                    < count)[:, None]
-            gh_keep = jnp.where(keep, gh[idx],
+            live = jnp.arange(S, dtype=jnp.int32) < count
+            idx = jnp.where(
+                live, jax.lax.dynamic_slice(state.order, (begin,), (S,)),
+                0)
+            gh_keep = jnp.where(live[:, None], gh[idx],
                                 jnp.zeros((), dtype=gh.dtype))
             return hist_fn(bins[idx], gh_keep, totals)
         return branch
 
-    k = jnp.clip(
-        jnp.sum(jnp.asarray(sizes, dtype=jnp.int32) >= count) - 1,
-        0, len(sizes) - 1)
-    return jax.lax.switch(k, [make_branch(S) for S in sizes], 0)
+    return jax.lax.switch(_ladder_branch(sizes, count),
+                          [make_branch(S) for S in sizes], 0)
 
 
 def _subtract_child_hists(state: GrowState, rec: SplitRecord, leaf,
                           new_leaf, leaf_of_row, smaller_is_left, valid,
                           small_hist):
-    """Histogram the smaller child only, ``small_hist(mask, totals)``
-    over its rows and record sums, take the sibling by subtraction from
-    the parent's stored histogram — BIT-EXACT in quantized-integer mode
-    — and store both: ``(hists, hist_left, hist_right)``."""
+    """Histogram the smaller child only, ``small_hist(leaf id, mask,
+    totals)`` over its rows (its segment of ``state.order`` where the
+    learner compacts, the masked row space where not) and record sums,
+    take the sibling by subtraction from the parent's stored histogram
+    — BIT-EXACT in quantized-integer mode — and store both: ``(hists,
+    hist_left, hist_right)``."""
     small_id = jnp.where(smaller_is_left, leaf, new_leaf)
     small_sel = leaf_of_row == small_id
     small_totals = jnp.stack([
@@ -501,7 +646,7 @@ def _subtract_child_hists(state: GrowState, rec: SplitRecord, leaf,
         jnp.where(smaller_is_left, rec.left_count, rec.right_count),
         jnp.where(smaller_is_left, rec.left_total_count,
                   rec.right_total_count)])
-    hist_small = small_hist(small_sel, small_totals)
+    hist_small = small_hist(small_id, small_sel, small_totals)
     return _split_hist_store(state.hists, leaf, new_leaf, hist_small,
                              smaller_is_left, valid)
 
@@ -521,7 +666,8 @@ def _split_step(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
     smaller_is_left, valid, mask_left, mask_right, qscale)`` is the
     learner's part: it returns the updated per-leaf store, both
     children's histograms and their scan masks (``_subtract_child_hists``
-    for the learners that keep a store). ``row_sharding`` pins the new
+    for the learners that keep a store); the ``state`` it gets has the
+    rows ordered by the new leaves already. ``row_sharding`` pins the new
     partition to the mesh learners' row layout. ``children_allowed``
     None means: derive from the device-side leaf_depth."""
     gl = _rows_go_left(bins, rec, meta, btab, bundled, has_cat)
@@ -532,6 +678,13 @@ def _split_step(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
         if row_sharding is not None:
             leaf_of_row = jax.lax.with_sharding_constraint(
                 leaf_of_row, row_sharding)
+
+    if state.order is not None:
+        order, seg_begin, seg_count = _partition_order(
+            state.order, state.seg_begin, state.seg_count, gl, leaf,
+            new_leaf, valid)
+        state = state._replace(order=order, seg_begin=seg_begin,
+                               seg_count=seg_count)
 
     smaller_is_left = rec.left_total_count <= rec.right_total_count
     (hists, hist_left, hist_right, mask_left,

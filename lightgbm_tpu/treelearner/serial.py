@@ -134,9 +134,9 @@ def _split_body(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
 
     def child_hists(bins, state, rec, leaf, new_leaf, leaf_of_row,
                     smaller_is_left, valid, mask_left, mask_right, qscale):
-        def small_hist(mask, totals):
+        def small_hist(small, _mask, totals):
             return _compact_child_hist(
-                bins, state.gh, mask, totals,
+                bins, state, small, totals,
                 _compact_sizes(bins.shape[0]), hist_fn)
 
         return _subtract_child_hists(
@@ -171,7 +171,7 @@ def _root_fn_cached(L: int, B: int, Bg: int, bundled: bool,
             leaf_depth=jnp.int32(0), has_categorical=has_cat,
             hist_scale=qscale)
         state = make_root_state(gh, hist, leaf_of_row0, info, L, F, B,
-                                children_allowed)
+                                children_allowed, ordered=True)
         return state, _record_at(state, 0)
 
     return obs_compile.instrument_jit("serial.root", root)
@@ -228,7 +228,7 @@ def _cegb_root_fn_cached(L: int, B: int, Bg: int, bundled: bool,
             feature_mask, parent_output=parent_out, gain_penalty=pen,
             has_categorical=has_cat, hist_scale=qscale)
         state = make_root_state(gh, hist, leaf_of_row0, info, L, F, B,
-                                children_allowed)
+                                children_allowed, ordered=True)
         return state, _record_at(state, 0)
 
     return obs_compile.instrument_jit("serial.cegb_root", root)

@@ -355,3 +355,48 @@ def test_bucket_counters_stay_still_while_the_timer_is_off():
     before = _hist_rows()
     _train_data_learner()
     assert _hist_rows() == before
+
+
+# --- (e) the rows a split reorders ----------------------------------------
+
+def _partition_rows():
+    return (registry.count("grow/partition_parent_rows"),
+            registry.count("grow/partition_window_rows"))
+
+
+def _grow_trees_on_one_device(trees: int = 2):
+    """Trees of a learner that compacts: a one-device mesh, whatever the
+    number of devices the process has (``_train_data_learner`` spreads
+    over all of them)."""
+    rng = np.random.RandomState(3)
+    X = rng.randn(40000, 8)
+    y = (X[:, 0] * X[:, 1] + X[:, 2] > 0).astype(np.float32)
+    cfg = Config.from_params({"objective": "binary", "num_leaves": 31,
+                              "max_bin": 63, "verbosity": -1})
+    lrn = DataParallelTreeLearner(
+        cfg, BinnedDataset.from_matrix(X, cfg, label=y), make_mesh(1))
+    hess = jnp.full(len(y), 0.25, dtype=jnp.float32)
+    return [lrn.train(jnp.asarray(0.5 - y) * (1 + k), hess)[0]
+            for k in range(trees)]
+
+
+def test_partition_row_counters_follow_the_trees(timer_on):
+    parents0, windows0 = _partition_rows()
+    grown = _grow_trees_on_one_device()
+    parents, windows = _partition_rows()
+    splits = sum(t.num_leaves - 1 for t in grown)
+    assert splits >= 2 * 20
+    assert parents - parents0 == sum(
+        int(t.internal_count[:t.num_leaves - 1].sum()) for t in grown)
+    # every parent is padded to a window of 16,384 x 2^k entries, the
+    # roots' 40,000 rows to 65,536
+    assert windows - windows0 >= max(parents - parents0, 16384 * splits,
+                                     65536 * len(grown))
+    assert (windows - windows0) % 16384 == 0
+
+
+def test_partition_row_counters_stay_still_while_the_timer_is_off():
+    assert not registry.timer.enabled
+    before = _partition_rows()
+    _grow_trees_on_one_device(1)
+    assert _partition_rows() == before

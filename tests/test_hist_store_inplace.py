@@ -14,6 +14,11 @@ store was then copied twice per split (ISSUE 28). Two guards:
 
 ``store_copies_on_v5e`` is also the host-side check of
 ``.claude/skills/verify/SKILL.md`` at the benchmark cells' sizes.
+
+The same compiled program guards the rows ordered by leaf (ISSUE 34,
+``grow.py _partition_order``): a split step scatters its parent's window
+and nothing longer, and never copies ``GrowState.order``
+(``order_faults``).
 """
 import re
 from unittest import mock
@@ -27,7 +32,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.dataset import BinnedDataset
 from lightgbm_tpu.parallel import DataParallelTreeLearner, make_mesh
-from lightgbm_tpu.treelearner.grow import _split_hist_store
+from lightgbm_tpu.treelearner.grow import (_split_hist_store,
+                                           _window_sizes)
 
 
 def describe_v5e():
@@ -117,6 +123,28 @@ def store_copies_on_v5e(features: int, leaves: int, rows: int = 65536,
     return inside, outside
 
 
+def order_faults(hlo: str, rows: int) -> list:
+    """What a split step may not do to the rows ordered by leaf, as
+    lines of an optimised HLO module of the tree program at ``rows``
+    rows: a ``scatter`` that is not a window's own (``obs_window_<W>``
+    in its ``op_name``, W updates; the scatter of every row number into
+    a bucket's ``idx`` was 5.9 ns a row a split), and a ``copy`` of
+    ``order``'s shape, which a branch that returns the updated ``order``
+    brings (``copy-start`` is the compiler moving it between memories
+    whole, the same traffic)."""
+    order_shape = r"s32\[%d\]" % (rows + _window_sizes(rows)[0])
+    faults = []
+    for line in hlo.splitlines():
+        if re.search(r"=\s*\(?%s[^=]*\s(copy|copy-start)\(" % order_shape,
+                     line):
+            faults.append(line.strip())
+        got = re.search(r"=\s*\w+\[(\d+)\]\S*\s+scatter\(", line)
+        if " scatter(" in line and not (
+                got and "/obs_window_%s/scatter" % got.group(1) in line):
+            faults.append(line.strip())
+    return faults
+
+
 @pytest.fixture(scope="module")
 def topo():
     try:
@@ -130,6 +158,36 @@ def test_no_store_copy_inside_the_loop_on_v5e(topo):
     assert inside == [], (
         "the while body copies the whole histogram store: a read of "
         "state.hists is ordered after a write\n" + "\n".join(inside))
+
+
+def test_a_split_touches_its_parents_window_of_the_order_on_v5e(topo):
+    rows = 65536
+    hlo, _ = compile_tree_program(topo, 64, 31, rows)
+    assert "s32[%d]" % (rows + _window_sizes(rows)[0]) in hlo, (
+        "the program holds no array of order's shape: helper stale")
+    windows = set(re.findall(r"/obs_window_(\d+)/scatter", hlo))
+    assert windows == {str(w) for w in _window_sizes(rows)}
+    faults = order_faults(hlo, rows)
+    assert faults == [], (
+        "a split step scatters more than its parent's window, or "
+        "copies the rows ordered by leaf\n" + "\n".join(faults))
+
+
+def test_order_faults_sees_the_scatter_of_every_row_and_a_copy():
+    """The reader on lines of the parent commit's program (ISSUE 34) and
+    of a branch that returned the updated ``order``."""
+    old = ('  ROOT %scatter.9 = s32[7813]{0:T(1024)} scatter(%p.1, %t.2, '
+           '%t.3), metadata={op_name="jit(_tree_impl)/while/body/'
+           'obs_compact/cond/branch_3_fun/obs_bucket_7813/scatter"}')
+    copied = ('  %copy.221 = s32[2048576]{0:T(1024)} '
+              'copy(%get-tuple-element.64)')
+    moved = ('  %copy-start.5 = (s32[2048576]{0:T(1024)}, s32[2048576]'
+             '{0:T(1024)S(1)}, u32[]{:S(2)}) copy-start(%dus.1)')
+    own = ('  ROOT %scatter.34 = s32[16384]{0:T(1024)S(1)} scatter(%p.4, '
+           '%t.7, %t.8), metadata={op_name="jit(_tree_impl)/while/body/'
+           'obs_compact/cond/branch_6_fun/obs_window_16384/scatter"}')
+    assert order_faults("\n".join([old, copied, moved, own]), 1_000_000) \
+        == [old.strip(), copied.strip(), moved.strip()]
 
 
 def _numpy_hist(bins, gh, rows, B):
