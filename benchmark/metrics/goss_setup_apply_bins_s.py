@@ -1,0 +1,3 @@
+"""``setup_apply_bins_s``'s reading in a training cell under gradient-based
+sampling, under this cell's own name."""
+from benchmark.metrics.setup_apply_bins_s import read  # noqa: F401

@@ -10,6 +10,28 @@ rescaled (grad, hess) — the learner multiplies gradients by the indicator
 and counts in-bag rows via its histogram count channel, which is the same
 masked-row trick the CUDA learner's bagging path uses.
 
+What the mask costs, and where the in-bag counts live. The grower
+(treelearner/grow.py) ignores the bag: ``GrowState.order`` holds every
+row, the smaller child is the one with fewer rows in all, and each
+histogram pass, gather and window reorder visits the out-of-bag rows
+too, whose gradient and hessian are zero. On a TPU v5e at 1,000,000 x
+968 under GOSS (top 20% + 10% of the rest: the benchmark's cell
+``bosch-train-goss``, PERF.md, PR 35) a sampled iteration takes 3.11 s
+where an unsampled one takes 3.05 s: 13% of the rows the smaller
+children's histogram passes visit carry weight
+(``grow/hist_rows_in_bag`` over ``grow/hist_rows_bucketed``), and the
+sampling itself is 1.5 ms, 1.4 of them ``lax.top_k``, which is a full
+sort of the weights there (and 24 s of compiling). Users who set GOSS get
+no speed from it until the grower learns to skip rows that carry no
+weight. The in-bag indicator travels as channel 2 of ``gh``, so a split
+record's ``left_count``/``right_count`` and a tree's
+``internal_count``/``leaf_count`` (the model text's) are in-bag counts,
+while ``left_total_count``/``right_total_count`` count every row; the
+score update covers every row through ``leaf_of_row``. The sampling is
+named ``obs_goss`` on the device clock, and while the stage timer is on
+``sample/goss_trees`` counts the trees of the iterations GOSS sampled
+(docs/OBSERVABILITY.md).
+
 Draws happen ON DEVICE, keyed by ``fold_in(PRNGKey(bagging_seed),
 draw_index)`` where the draw index is a pure function of the iteration
 number (``iter // bagging_freq`` for bagging, the iteration itself for
@@ -39,6 +61,7 @@ import numpy as np
 
 from ..utils import log
 from ..obs import compile as obs_compile
+from ..obs.registry import registry as obs
 from ..utils.scalars import dev_i32
 
 
@@ -243,25 +266,29 @@ class GOSSStrategy(SampleStrategy):
     @obs_compile.instrument_jit_method("boost.goss")
     def _goss(self, grad, hess, base_key, iter_idx):
         # grad/hess: [N] or [N, K]
-        g2 = jnp.abs(grad * hess)
-        w = g2 if g2.ndim == 1 else jnp.sum(g2, axis=1)
-        n = w.shape[0]
-        thresh = jax.lax.top_k(w, self.top_k)[0][-1]
-        is_top = w >= thresh
-        multiply = (n - self.top_k) / self.other_k
-        prob = self.other_k / jnp.maximum(n - self.top_k, 1)
-        u = jax.random.uniform(jax.random.fold_in(base_key, iter_idx),
-                               (n,))
-        sampled = (~is_top) & (u < prob)
-        bag = (is_top | sampled).astype(jnp.float32)
-        scale = jnp.where(sampled, multiply, 1.0)
-        if grad.ndim > 1:
-            scale = scale[:, None]
-        return grad * scale, hess * scale, bag
+        with jax.named_scope("obs_goss"):
+            g2 = jnp.abs(grad * hess)
+            w = g2 if g2.ndim == 1 else jnp.sum(g2, axis=1)
+            n = w.shape[0]
+            thresh = jax.lax.top_k(w, self.top_k)[0][-1]
+            is_top = w >= thresh
+            multiply = (n - self.top_k) / self.other_k
+            prob = self.other_k / jnp.maximum(n - self.top_k, 1)
+            u = jax.random.uniform(jax.random.fold_in(base_key, iter_idx),
+                                   (n,))
+            sampled = (~is_top) & (u < prob)
+            bag = (is_top | sampled).astype(jnp.float32)
+            scale = jnp.where(sampled, multiply, 1.0)
+            if grad.ndim > 1:
+                scale = scale[:, None]
+            return grad * scale, hess * scale, bag
 
     def bagging(self, iter_idx, grad, hess):
         if iter_idx < self.warmup:
             return grad, hess, None
+        if obs.enabled:
+            # the trees of an iteration share one bag
+            obs.inc("sample/goss_trees", self.num_tree_per_iteration)
         return self._goss(grad, hess, self._base_key, dev_i32(iter_idx))
 
     def apply_traced(self, iter_idx, grad, hess):

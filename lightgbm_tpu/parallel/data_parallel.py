@@ -698,18 +698,29 @@ class DataParallelTreeLearner(CapabilityMixin):
                 self._count_hist_rows(recs_h, applied)
                 self._count_partition_splits(recs_h, applied)
                 self._count_partition_rows(recs_h, applied)
+                if bag is not None and applied:
+                    obs.inc("sample/rows_in_bag",
+                            int(recs_h.left_count[0]
+                                + recs_h.right_count[0]))
         return tree, self._finalize_partition(state.leaf_of_row)
 
     def _count_hist_rows(self, recs_h, applied: int) -> None:
         """``grow/hist_rows_needed``: rows of the smaller child of each
         applied split, which a histogram has to visit;
         ``grow/hist_rows_bucketed``: rows the learner's passes visited
-        for them."""
-        small = np.minimum(recs_h.left_total_count[:applied],
-                           recs_h.right_total_count[:applied])
+        for them; ``grow/hist_rows_in_bag``: those of the smaller
+        child's rows that are in the bag (all of them where nothing is
+        sampled), the only ones that carry weight."""
+        left_total = recs_h.left_total_count[:applied]
+        right_total = recs_h.right_total_count[:applied]
+        small = np.minimum(left_total, right_total)
         obs.inc("grow/hist_rows_needed", int(small.sum()))
         obs.inc("grow/hist_rows_bucketed",
                 int(self._hist_rows_bucketed(small)))
+        # the child _split_step takes as the smaller one (ties: left)
+        obs.inc("grow/hist_rows_in_bag", int(np.where(
+            left_total <= right_total, recs_h.left_count[:applied],
+            recs_h.right_count[:applied]).sum()))
 
     @staticmethod
     def _count_partition_splits(recs_h, applied: int) -> None:
