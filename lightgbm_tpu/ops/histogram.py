@@ -22,6 +22,7 @@ docs/GPU-Performance.rst precedent).
 from __future__ import annotations
 
 import functools
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -196,7 +197,7 @@ def _acc_dtype_of(gh_dtype):
 
 @jax.named_scope("obs_hist_scatter")
 def _segment_histogram(bins: jnp.ndarray, gh: jnp.ndarray,
-                       num_bins: int) -> jnp.ndarray:
+                       num_bins: int, acc=None) -> jnp.ndarray:
     """Scatter-add formulation via a flat segment-sum — the direct
     analogue of the reference's CPU hot loop (dense_bin.hpp:99
     ``ConstructHistogramInner``: per row, hist[bin] += (g, h)). On CPU
@@ -205,7 +206,12 @@ def _segment_histogram(bins: jnp.ndarray, gh: jnp.ndarray,
     forms, so this path is selected only for CPU backends. Integer gh
     accumulates int32/int64 — exact and order-invariant — and the int8
     value stream is 4x fewer bytes than f32 through the bandwidth-bound
-    broadcast+scatter."""
+    broadcast+scatter.
+
+    ``acc`` ([F, B, C] in the accumulator's dtype) continues a histogram
+    (``histogram_tiles``): the rows are scatter-added into it, each bin
+    taking its additions in the order one pass over all the rows would
+    give them, and it comes back uncast."""
     S, F = bins.shape
     C = gh.shape[1]
     acc_dtype = _acc_dtype_of(gh.dtype)
@@ -213,6 +219,9 @@ def _segment_histogram(bins: jnp.ndarray, gh: jnp.ndarray,
             + bins.astype(jnp.int32)).reshape(-1)            # [S*F]
     vals = jnp.broadcast_to(
         gh.astype(acc_dtype)[:, None, :], (S, F, C)).reshape(-1, C)
+    if acc is not None:
+        return acc.reshape(F * num_bins, C).at[flat].add(vals) \
+            .reshape(F, num_bins, C)
     out = jax.ops.segment_sum(vals, flat, num_segments=F * num_bins)
     out = out.reshape(F, num_bins, C)
     return out if jnp.issubdtype(acc_dtype, jnp.integer) \
@@ -240,10 +249,14 @@ def _tile_histogram(bins_tile: jnp.ndarray, gh_tile: jnp.ndarray,
         preferred_element_type=acc_dtype)
 
 
-def _hist_kernel_body(F: int, H: int, C: int, bins_ref, gh_ref, out_ref,
-                      bins32_ref):
+def _hist_kernel_body(F: int, H: int, C: int, bins_ref, gh_ref, acc_ref,
+                      out_ref, bins32_ref):
     """Pallas TPU kernel: one grid step accumulates a feature-major
-    [F, T] row tile into the [F, H, 16*C] VMEM-resident accumulator.
+    [F, T] row tile into the [F, H, 16*C] VMEM-resident accumulator,
+    which starts as ``acc_ref``: the histogram so far, left in HBM
+    (a block of it would be a second accumulator in VMEM, which F = 968
+    has no room for) and copied in by the first step. ``acc_ref`` and
+    the output are one buffer (``input_output_aliases``).
 
     The bin index factorizes as ``bin = hi*16 + lo``; per feature the
     contribution is ``A_f @ W_f^T`` where ``A_f[hi, t]`` is the
@@ -269,7 +282,7 @@ def _hist_kernel_body(F: int, H: int, C: int, bins_ref, gh_ref, out_ref,
     mask onto int8's (32, 128) tiling."""
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        pltpu.sync_copy(acc_ref, out_ref)
 
     T = bins_ref.shape[1]
     bins32_ref[...] = bins_ref[...].astype(jnp.int32)
@@ -297,6 +310,54 @@ def _hist_kernel_body(F: int, H: int, C: int, bins_ref, gh_ref, out_ref,
     jax.lax.fori_loop(0, F, body, 0)
 
 
+def _pallas_accumulate(acc: jnp.ndarray, bins: jnp.ndarray,
+                       gh: jnp.ndarray, row_tile: int,
+                       interpret: bool = False) -> jnp.ndarray:
+    """``acc`` ([F, H, 16*C], the kernel's own layout) plus the
+    histogram of [S, F] bins x [S, C] gh, S a whole number of row tiles:
+    the kernel adds one tile after another into ``acc``'s buffer, so a
+    histogram built over several calls takes the additions one call
+    over all the rows would give it."""
+    S, F = bins.shape
+    C = gh.shape[1]
+    H = acc.shape[1]
+    T = row_tile
+    return pl.pallas_call(
+        functools.partial(_hist_kernel_body, F, H, C),
+        name="hist_kernel",
+        grid=(S // T,),
+        in_specs=[
+            pl.BlockSpec((F, T), lambda i: (0, i)),
+            pl.BlockSpec((C, T), lambda i: (0, i)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((F, H, 16 * C), lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
+        scratch_shapes=[pltpu.VMEM((F, T), jnp.int32)],
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=PALLAS_VMEM_LIMIT),
+        interpret=interpret,
+    )(bins.T, gh.T, acc)
+
+
+def _kernel_zeros(F: int, num_bins: int, C: int, gh_dtype) -> jnp.ndarray:
+    """An empty histogram in the kernel's layout."""
+    gh_dtype = jnp.dtype(gh_dtype)
+    return jnp.zeros(
+        (F, _hi_rows(num_bins, gh_dtype.itemsize), 16 * C),
+        dtype=(jnp.int32 if jnp.issubdtype(gh_dtype, jnp.integer)
+               else jnp.float32))
+
+
+def _from_kernel_layout(acc: jnp.ndarray, num_bins: int) -> jnp.ndarray:
+    """[F, H, C*16] -> [F, H*16, C] -> [F, B, C]"""
+    F, H, C16 = acc.shape
+    hist = acc.reshape(F, H, C16 // 16, 16).transpose(0, 1, 3, 2)
+    return hist.reshape(F, H * 16, C16 // 16)[:, :num_bins, :]
+
+
 def _pallas_histogram_body(bins: jnp.ndarray, gh: jnp.ndarray,
                            num_bins: int, row_tile: int,
                            interpret: bool = False) -> jnp.ndarray:
@@ -305,34 +366,14 @@ def _pallas_histogram_body(bins: jnp.ndarray, gh: jnp.ndarray,
     product entry ``_pallas_histogram`` does not expose it."""
     S, F = bins.shape
     C = gh.shape[1]
-    H = _hi_rows(num_bins, gh.dtype.itemsize)
-    T = row_tile
-    pad = (-S) % T
+    pad = (-S) % row_tile
     if pad:
         bins = jnp.concatenate(
             [bins, jnp.zeros((pad, F), dtype=bins.dtype)])
         gh = jnp.concatenate([gh, jnp.zeros((pad, C), dtype=gh.dtype)])
-    quantized = jnp.issubdtype(gh.dtype, jnp.integer)
-    out_dtype = jnp.int32 if quantized else jnp.float32
-    out = pl.pallas_call(
-        functools.partial(_hist_kernel_body, F, H, C),
-        name="hist_kernel",
-        grid=(bins.shape[0] // T,),
-        in_specs=[
-            pl.BlockSpec((F, T), lambda i: (0, i)),
-            pl.BlockSpec((C, T), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((F, H, 16 * C), lambda i: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((F, H, 16 * C), out_dtype),
-        scratch_shapes=[pltpu.VMEM((F, T), jnp.int32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=PALLAS_VMEM_LIMIT),
-        interpret=interpret,
-    )(bins.T, gh.T)
-    # [F, H, C*16] -> [F, H*16, C] -> [F, B, C]
-    hist = out.reshape(F, H, C, 16).transpose(0, 1, 3, 2)
-    return hist.reshape(F, H * 16, C)[:, :num_bins, :]
+    return _from_kernel_layout(
+        _pallas_accumulate(_kernel_zeros(F, num_bins, C, gh.dtype), bins,
+                           gh, row_tile, interpret), num_bins)
 
 
 _pallas_histogram = obs_compile.instrument_jit(
@@ -367,6 +408,36 @@ def _pallas_excluded(S: int, F: int, num_bins: int, C: int, gh_dtype,
     return None
 
 
+def _choose_backend(S: int, F: int, num_bins: int, C: int, gh_dtype,
+                    bins_itemsize: int, pallas_ok: bool,
+                    hist_impl: tuple) -> tuple:
+    """``(path, f64)`` for a histogram of these static shapes: the
+    Pallas kernel (``"pallas"``) where this is a TPU and the static gate
+    admits it, else the scatter (``"scatter"``) on the CPU or on
+    request, else the one-hot einsum (``"einsum"``); ``f64`` is whether
+    the latter two accumulate in float64."""
+    backend, f64 = hist_impl[0], hist_impl[1]
+    if jnp.issubdtype(jnp.dtype(gh_dtype), jnp.integer):
+        f64 = False
+    excluded = _pallas_excluded(S, F, num_bins, C, gh_dtype,
+                                bins_itemsize, pallas_ok, f64, backend)
+    on_tpu = jax.default_backend() == "tpu"
+    if backend == "pallas" and (excluded or not on_tpu):
+        # Explicit request could not be honored — say why (a silent
+        # downgrade skews kernel benchmarks).
+        _warn_once("hist_backend=pallas requested but unavailable here "
+                   "(%s); using the einsum path"
+                   % (excluded or "no TPU backend"))
+    if on_tpu and excluded is None:
+        # chosen by shape: a kernel that does not compile is an error
+        # that reaches the user, never a silent einsum run
+        return "pallas", False
+    if backend == "scatter" or (backend == "auto"
+                                and jax.default_backend() == "cpu"):
+        return "scatter", f64
+    return "einsum", f64
+
+
 def build_histogram(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
                     row_tile: int = DEFAULT_ROW_TILE,
                     pallas_ok: bool = True,
@@ -393,45 +464,99 @@ def build_histogram(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
     exact and order-invariant, and the caller dequantizes once per
     split scan (ops/split.py).
     """
-    backend, f64 = hist_impl[0], hist_impl[1]
     S, F = bins.shape
-    C = gh.shape[1]
-    quantized = jnp.issubdtype(jnp.dtype(gh.dtype), jnp.integer)
-    if quantized:
-        f64 = False
-    excluded = _pallas_excluded(S, F, num_bins, C, gh.dtype,
-                                bins.dtype.itemsize, pallas_ok, f64,
-                                backend)
-    on_tpu = jax.default_backend() == "tpu"
-    if backend == "pallas" and (excluded or not on_tpu):
-        # Explicit request could not be honored — say why (a silent
-        # downgrade skews kernel benchmarks).
-        _warn_once("hist_backend=pallas requested but unavailable here "
-                   "(%s); using the einsum path"
-                   % (excluded or "no TPU backend"))
-    if on_tpu and excluded is None:
-        # chosen by shape: a kernel that does not compile is an error
-        # that reaches the user, never a silent einsum run
+    path, f64 = _choose_backend(S, F, num_bins, gh.shape[1], gh.dtype,
+                                bins.dtype.itemsize, pallas_ok, hist_impl)
+    if path == "pallas":
         with jax.named_scope("obs_hist_pallas"):
             return _pallas_histogram(bins, gh, num_bins,
                                      _pallas_row_tile(gh.dtype))
     if f64:
         gh = gh.astype(jnp.float64)
-    if backend == "scatter" or (backend == "auto"
-                                and jax.default_backend() == "cpu"):
+    if path == "scatter":
         return _segment_histogram(bins, gh, num_bins)
-    return _einsum_histogram(bins, gh, num_bins, row_tile, quantized)
+    return _einsum_histogram(
+        bins, gh, num_bins, row_tile,
+        jnp.issubdtype(jnp.dtype(gh.dtype), jnp.integer))
+
+
+class HistogramTiles(NamedTuple):
+    """A histogram built ``rows`` rows at a time, for a caller whose row
+    count is traced (grow.py's ``_compact_child_hist``): ``zeros()`` is
+    the empty accumulator, in the path's own layout and accumulation
+    dtype; ``add(acc, bins [rows, F], gh [rows, C])`` continues it;
+    ``result(acc)`` is what ``build_histogram`` returns for the same
+    rows in the same order, addition for addition. The layout change
+    and the cast are ``result``'s, once a histogram and not once a
+    tile."""
+    rows: int
+    zeros: Callable
+    add: Callable
+    result: Callable
+
+
+def histogram_tiles(bins, gh, num_bins: int, pallas_ok: bool = True,
+                    hist_impl: tuple = ("auto", False)) -> HistogramTiles:
+    """The tile-by-tile form of ``build_histogram(bins, gh, ...)``, on
+    the path that call takes: ``bins`` and ``gh`` are the whole data (or
+    their ``ShapeDtypeStruct``), read for shape and dtype alone. A tile
+    is what one step of that path's own row loop takes, so its
+    arithmetic is the whole pass's."""
+    (S, F), C = bins.shape, gh.shape[1]
+    gh_dtype = jnp.dtype(gh.dtype)
+    path, f64 = _choose_backend(S, F, num_bins, C, gh_dtype,
+                                jnp.dtype(bins.dtype).itemsize, pallas_ok,
+                                hist_impl)
+    if path == "pallas":
+        T = _pallas_row_tile(gh_dtype)
+
+        def add(acc, bins_tile, gh_tile):
+            with jax.named_scope("obs_hist_pallas"):
+                return _pallas_accumulate(acc, bins_tile, gh_tile, T)
+
+        def result(acc):
+            with jax.named_scope("obs_hist_pallas"):
+                return _from_kernel_layout(acc, num_bins)
+
+        return HistogramTiles(
+            T, lambda: _kernel_zeros(F, num_bins, C, gh_dtype), add,
+            result)
+    quantized = jnp.issubdtype(gh_dtype, jnp.integer)
+    row_dtype = jnp.dtype(jnp.float64) if f64 else gh_dtype
+    acc_dtype = _acc_dtype_of(row_dtype)
+
+    def add(acc, bins_tile, gh_tile):
+        gh_tile = gh_tile.astype(row_dtype)
+        if path == "scatter":
+            return _segment_histogram(bins_tile, gh_tile, num_bins, acc)
+        return _einsum_histogram(bins_tile, gh_tile, num_bins,
+                                 DEFAULT_ROW_TILE, quantized, acc)
+
+    # Read off the v5e (PR 36; a tile's gathers, layout copies and loop
+    # step are 20-80 us, against half a tile of zero rows a histogram):
+    # the int8 einsum runs 588 ns a row in tiles of two scan steps for
+    # 628 in tiles of one (F = 968), the float32 einsum 1,609 for 1,548
+    # (F = 2,000), and the kernel gains nothing from a second grid step.
+    steps = 2 if path == "einsum" and quantized else 1
+    return HistogramTiles(
+        steps * DEFAULT_ROW_TILE,
+        lambda: jnp.zeros((F, num_bins, C), dtype=acc_dtype), add,
+        lambda acc: acc.astype(acc_dtype if quantized else jnp.float32))
 
 
 @jax.named_scope("obs_hist_einsum")
 def _einsum_histogram(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
-                      row_tile: int, quantized: bool) -> jnp.ndarray:
-    """One-hot contraction over ``row_tile``-row tiles, scanned."""
+                      row_tile: int, quantized: bool,
+                      acc=None) -> jnp.ndarray:
+    """One-hot contraction over ``row_tile``-row tiles, scanned.
+    ``acc`` ([F, B, C] in the accumulator's dtype) continues a histogram
+    (``histogram_tiles``): it is the scan's first carry, and comes back
+    uncast."""
     S, F = bins.shape
     C = gh.shape[1]
     acc_dtype = _acc_dtype_of(gh.dtype)
     out_dtype = acc_dtype if quantized else jnp.float32
-    if S <= row_tile:
+    if acc is None and S <= row_tile:
         return _tile_histogram(bins, gh, num_bins).astype(out_dtype)
     # Pad S to a tile multiple; padded rows use gh = 0 so they vanish.
     pad = (-S) % row_tile
@@ -448,6 +573,10 @@ def _einsum_histogram(bins: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
         return acc + _tile_histogram(b, g, num_bins).astype(acc.dtype), \
             None
 
+    if acc is not None:
+        if n_tiles == 1:
+            return step(acc, (bins, gh))[0]
+        return jax.lax.scan(step, acc, (bins_t, gh_t))[0]
     init = jnp.zeros((F, num_bins, C), dtype=acc_dtype)
     hist, _ = jax.lax.scan(step, init, (bins_t, gh_t))
     return hist.astype(out_dtype)
@@ -488,8 +617,13 @@ def unpack_bundle_histogram(bhist: jnp.ndarray,
     totals : [C] — the leaf's (grad, hess, count, total) sums, in the
         histogram's own dtype (f32, or int32/int64 in quantized mode —
         where the zero-bin residual reconstruction is EXACT integer
-        arithmetic instead of an f32 cancellation).
+        arithmetic instead of an f32 cancellation). None: the sums over
+        the first bundle's bins, every row lying in one of them — the
+        rows' own sums where the histogram is of integers, which is
+        where a caller has no others to give.
     """
+    if totals is None:
+        totals = jnp.sum(bhist[0], axis=0)
     F = gidx_g.shape[0]
     zero = jnp.zeros((), dtype=bhist.dtype)
     safe_g = jnp.maximum(gidx_g, 0)

@@ -51,7 +51,7 @@ from ..io.dataset import BinnedDataset
 from ..models.tree import Tree
 from ..obs import compile as obs_compile
 from ..obs.registry import registry as obs
-from ..ops.histogram import (build_histogram, mask_gh,
+from ..ops.histogram import (build_histogram, histogram_tiles, mask_gh,
                              unpack_bundle_histogram)
 from ..ops.quantize import dequantize_sums, sum_gh
 from ..ops.split import (FeatureMeta, SplitParams, calculate_leaf_output,
@@ -60,8 +60,7 @@ from ..treelearner.capabilities import (CapabilityMixin, _cegb_penalty,
                                         train_cegb, train_monotone,
                                         train_stepwise)
 from ..treelearner.grow import (GrowState, SplitRecord,
-                                _compact_child_hist, _compact_sizes,
-                                _grow_tree, _maybe_rand_bins, _record_at,
+                                _compact_child_hist, _grow_tree, _maybe_rand_bins, _record_at,
                                 _rows_go_left, _split_step, _store_info,
                                 _subtract_child_hists, _window_sizes,
                                 apply_split_record,
@@ -72,8 +71,8 @@ from ..utils import log
 
 def _padded_to_ladder(counts: np.ndarray, sizes: list) -> int:
     """``counts`` summed, each padded to the smallest of ``sizes`` (a
-    ladder of grow.py, largest first) that holds it: the host's twin of
-    ``grow._ladder_branch``."""
+    window ladder of grow.py, largest first) that holds it: the host's
+    twin of ``grow._ladder_branch``."""
     sizes = np.asarray(sizes[::-1])
     return sizes[np.minimum(np.searchsorted(sizes, counts),
                             len(sizes) - 1)].sum()
@@ -260,21 +259,41 @@ class DataParallelTreeLearner(CapabilityMixin):
         zero-bin rows of bundled features, io/efb.py). Quantized mode:
         the local partials are int32 — the XLA-inserted cross-device
         psum then moves HALF the bytes of the f32 histogram (and a
-        quarter on int8 gh rows vs f32 through the local pass).
-
-        pallas_ok only on a 1-device mesh: pallas_call has no SPMD
-        partitioning rule, so with real sharding GSPMD would all-gather
-        the bins; unsharded, the kernel is safe (and is the fast path
-        for single-chip tree_learner=data runs)."""
-        p_ok = self.mesh.devices.size == 1
+        quarter on int8 gh rows vs f32 through the local pass)."""
         if jnp.issubdtype(gh.dtype, jnp.integer):
             # callers hold dequantized f32 record totals; the bundled
             # zero-bin fix needs the exact int sums of THESE (already
             # masked) rows
             totals = sum_gh(gh)
+        return self._summed_hist(
+            build_histogram(bins, gh, self._hist_bins(),
+                            pallas_ok=self._pallas_ok(),
+                            hist_impl=self._hist_impl), totals)
+
+    def _pallas_ok(self) -> bool:
+        """Only on a 1-device mesh: pallas_call has no SPMD partitioning
+        rule, so with real sharding GSPMD would all-gather the bins;
+        unsharded, the kernel is safe (and is the fast path for
+        single-chip tree_learner=data runs)."""
+        return self.mesh.devices.size == 1
+
+    def _hist_bins(self) -> int:
+        """Bins of the histogram the rows are summed into: the bundle
+        histogram's where the columns are bundles."""
+        return self.Bg if self._bundled else self.B
+
+    def _hist_tiles(self, bins, gh):
+        """``_mesh_hist``'s pass over all of ``bins`` and ``gh``, a tile
+        at a time (both for shape and dtype alone)."""
+        return histogram_tiles(bins, gh, self._hist_bins(),
+                               pallas_ok=self._pallas_ok(),
+                               hist_impl=self._hist_impl)
+
+    def _summed_hist(self, h, totals):
+        """The local histogram ``h`` summed over the mesh and, where the
+        columns are bundles, unpacked per feature (``totals`` None:
+        taken from ``h`` itself)."""
         if not self._bundled:
-            h = build_histogram(bins, gh, self.B, pallas_ok=p_ok,
-                                hist_impl=self._hist_impl)
             # named so the XLA-inserted cross-device reduce is
             # attributable in device traces; the feature-parallel
             # subclass keeps histograms sharded (no psum crosses here),
@@ -285,10 +304,8 @@ class DataParallelTreeLearner(CapabilityMixin):
             with jax.named_scope(name):
                 return jax.lax.with_sharding_constraint(
                     h, self.hist_sharding)
-        bh = build_histogram(bins, gh, self.Bg, pallas_ok=p_ok,
-                             hist_impl=self._hist_impl)
         with jax.named_scope("obs_psum_bundle_histogram"):
-            bh = jax.lax.with_sharding_constraint(bh, self.rep_sharding)
+            bh = jax.lax.with_sharding_constraint(h, self.rep_sharding)
         return unpack_bundle_histogram(bh, self._btab.gidx_g,
                                        self._btab.gidx_b,
                                        self._btab.zero_fix,
@@ -346,10 +363,11 @@ class DataParallelTreeLearner(CapabilityMixin):
         """The updated per-leaf store, the cross-device-summed child
         histograms and the per-child scan masks. Base learner: the
         smaller child's histogram, its sibling by subtraction
-        (``_subtract_child_hists``). On one device the child's rows are
-        compacted first (its segment of ``state.order``, which the split
-        step has just reordered), so histogram cost tracks the child's
-        size (the reference's DataPartition + per-leaf iterators,
+        (``_subtract_child_hists``). On one device the pass visits the
+        child's rows alone (its segment of ``state.order``, which the
+        split step has just reordered, a tile at a time), so histogram
+        cost tracks the child's size (the reference's DataPartition +
+        per-leaf iterators,
         data_partition.hpp:21); a sharded mesh keeps the masked
         histogram over the full row space (the analogue of the
         reference ranks histogramming their local leaf rows then
@@ -360,9 +378,14 @@ class DataParallelTreeLearner(CapabilityMixin):
         store."""
         def small_hist(small, mask, totals):
             if self._compacts():
-                return _compact_child_hist(
-                    bins, state, small, totals, _compact_sizes(self.R),
-                    self._mesh_hist)
+                # quantized rows: the exact int sums of the child's
+                # rows, which the record does not hold, come from the
+                # histogram itself
+                return self._summed_hist(
+                    _compact_child_hist(bins, state, small,
+                                        self._hist_tiles(bins, state.gh)),
+                    None if jnp.issubdtype(state.gh.dtype, jnp.integer)
+                    else totals)
             # dtype-preserving mask (an f32 multiply would de-quantize
             # integer gh rows)
             return self._mesh_hist(bins, mask_gh(state.gh, mask), totals)
@@ -734,12 +757,15 @@ class DataParallelTreeLearner(CapabilityMixin):
 
     def _hist_rows_bucketed(self, small: np.ndarray) -> int:
         """Rows ``_children_histograms`` passes over for splits whose
-        smaller children hold ``small`` rows: the bucket
-        ``_compact_child_hist`` picks on one device, the whole masked
+        smaller children hold ``small`` rows: the whole tiles
+        ``_compact_child_hist`` takes on one device, the whole masked
         row space on a sharded mesh."""
         if not self._compacts():
             return self.R * len(small)
-        return _padded_to_ladder(small, _compact_sizes(self.R))
+        T = self._hist_tiles(self.bins, jax.ShapeDtypeStruct(
+            (self.R, 4),
+            self._qdtype if self._quantized else jnp.float32)).rows
+        return int((-(-small.astype(np.int64) // T) * T).sum())
 
     def _count_partition_rows(self, recs_h, applied: int) -> None:
         """Where the learner keeps the rows ordered by leaf:

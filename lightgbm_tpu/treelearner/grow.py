@@ -26,10 +26,11 @@ handled as:
   (``_window_sizes``) that holds the parent is sliced out, stably
   partitioned into left rows then right rows, and written back.
 - **per-leaf row gather** (``_compact_child_hist``): the smaller
-  child's rows, a slice of ``order`` and so in ascending order, fill the
-  smallest bucket of a static ladder (``_compact_sizes``) that holds
-  them, one ``lax.switch`` branch per bucket; the bucket's tail carries
-  gh 0 and vanishes from every sum.
+  child's rows, a slice of ``order`` and so in ascending order, are
+  histogrammed a tile at a time by a loop whose trip count is the
+  child's row count over the tile's rows (``ops/histogram.py``
+  ``histogram_tiles``); the last tile's tail carries gh 0 and vanishes
+  from every sum.
 
 max_depth gating follows BeforeFindBestSplit (serial_tree_learner.cpp:287):
 a leaf at depth d is splittable iff max_depth <= 0 or d < max_depth —
@@ -191,7 +192,7 @@ def make_root_state(gh, hist, leaf_of_row, info, L: int, F: int, B: int,
     ``order[:R]`` is leaf 0's rows, ascending, then the pad rows (leaf
     -1); ``order[R:]`` is W entries of row 0 that no segment owns, W
     the largest window of ``_window_sizes``, there so that a window or
-    a bucket sliced from any segment's begin never passes the end
+    a tile sliced from inside any segment never passes the end
     (``dynamic_slice`` would shift it left, and the rows would reach
     the histogram at other positions)."""
     hist_slots = hist_slots or L
@@ -467,28 +468,15 @@ def _finish_split(state: GrowState, rec: SplitRecord, leaf, new_leaf,
     return state
 
 
-def _compact_sizes(R: int) -> list:
-    """The compaction ladder's bucket sizes for ``R`` rows, largest
-    first: half the rows, then a quarter of the last until 16,384 or
-    under."""
-    sizes = []
-    s = -(-R // 2)
-    while s > 16384:
-        sizes.append(s)
-        s = -(-s // 4)
-    sizes.append(s)
-    return sizes
-
-
 _ORDER_CHUNK = 16384
 
 
 def _window_sizes(R: int) -> list:
     """The window ladder's sizes for ``R`` rows, largest first: a chunk
     of ``_ORDER_CHUNK`` entries doubled until it holds every row, so
-    each is a whole number of chunks. Doubled, where the buckets
-    quadruple: a window's cost is its size, and the branches hold no
-    histogram, so a finer ladder costs next to nothing to compile."""
+    each is a whole number of chunks. A window's cost is its size, and
+    the branches hold no histogram, so a ladder this fine costs next to
+    nothing to compile."""
     sizes = [_ORDER_CHUNK]
     while sizes[0] < R:
         sizes.insert(0, 2 * sizes[0])
@@ -592,39 +580,40 @@ def _partition_order(order, seg_begin, seg_count, gl, leaf, new_leaf,
 
 
 @jax.named_scope("obs_compact")
-def _compact_child_hist(bins, state: GrowState, leaf, totals, sizes,
-                        hist_fn):
-    """Gather the rows of ``leaf``'s segment of ``state.order`` (the
-    smaller child's) into the smallest bucket of ``sizes`` that holds
-    them (``lax.switch`` over compiled sizes) and histogram only
-    those with ``hist_fn(bins, gh, totals)``, the learner's own
-    histogram. A leaf-wise tree's total smaller-child row count is
-    ~N·log2(L)/2, so this cuts per-tree histogram work by ~50x at 255
-    leaves vs masked full-row scans — the single-chip analogue of the
-    reference's per-leaf row iterators (data_partition.hpp:119
-    GetIndexOnLeaf). The bucket's row numbers are a slice of ``order``;
-    what a split step pays besides the histogram is the two gathers
-    ``gh[idx]`` and ``bins[idx]`` of the bucket's size. The rows keep
-    their ascending order, the bucket's tail is row 0 and zeroed in
-    ``gh``, so which bucket runs changes compiled programs, never
-    values."""
-    gh = state.gh
+def _compact_child_hist(bins, state: GrowState, leaf, tiles):
+    """Histogram the rows of ``leaf``'s segment of ``state.order`` (the
+    smaller child's) and no others, ``tiles.rows`` of them at a time
+    (``tiles``: ``ops/histogram.py`` ``histogram_tiles`` of the
+    learner's data): a loop whose trip count is the segment's row count
+    over the tile's rows takes each tile's row numbers as a slice of
+    ``order`` (whose spare tail keeps the last slice from being
+    shifted), gathers ``gh[idx]`` and ``bins[idx]`` for the tile alone
+    and adds its histogram to the accumulator it carries. A leaf-wise
+    tree's total smaller-child row count is ~N·log2(L)/2, so this cuts
+    per-tree histogram work by ~50x at 255 leaves vs masked full-row
+    scans — the single-chip analogue of the reference's per-leaf row
+    iterators (data_partition.hpp:119 GetIndexOnLeaf). The passes visit
+    the child's rows and less than a tile besides; the histogram's time
+    follows the rows visited. The rows keep their ascending order and
+    the last tile's tail is row 0 with ``gh`` zeroed, so the tile's
+    size changes the compiled program, never values."""
+    gh, order = state.gh, state.order
     begin, count = state.seg_begin[leaf], state.seg_count[leaf]
+    T = tiles.rows
+    if T > order.shape[0] - gh.shape[0]:
+        raise ValueError("a tile of %d rows passes the spare tail of "
+                         "the rows ordered by leaf" % T)
 
-    def make_branch(S):
-        @jax.named_scope("obs_bucket_%d" % S)
-        def branch(_):
-            live = jnp.arange(S, dtype=jnp.int32) < count
-            idx = jnp.where(
-                live, jax.lax.dynamic_slice(state.order, (begin,), (S,)),
-                0)
-            gh_keep = jnp.where(live[:, None], gh[idx],
-                                jnp.zeros((), dtype=gh.dtype))
-            return hist_fn(bins[idx], gh_keep, totals)
-        return branch
+    def add_tile(i, acc):
+        live = i * T + jnp.arange(T, dtype=jnp.int32) < count
+        idx = jnp.where(
+            live, jax.lax.dynamic_slice(order, (begin + i * T,), (T,)), 0)
+        gh_keep = jnp.where(live[:, None], gh[idx],
+                            jnp.zeros((), dtype=gh.dtype))
+        return tiles.add(acc, bins[idx], gh_keep)
 
-    return jax.lax.switch(_ladder_branch(sizes, count),
-                          [make_branch(S) for S in sizes], 0)
+    return tiles.result(jax.lax.fori_loop(
+        0, -(-count // T), add_tile, tiles.zeros()))
 
 
 def _subtract_child_hists(state: GrowState, rec: SplitRecord, leaf,

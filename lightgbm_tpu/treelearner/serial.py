@@ -36,7 +36,8 @@ from ..io.dataset import BinnedDataset
 from ..models.tree import Tree
 from ..obs import compile as obs_compile
 from ..obs.registry import registry as obs
-from ..ops.histogram import build_histogram, unpack_bundle_histogram
+from ..ops.histogram import (build_histogram, histogram_tiles,
+                             unpack_bundle_histogram)
 from ..ops.quantize import dequantize_sums, sum_gh
 from ..ops.split import (FeatureMeta, SplitParams, calculate_leaf_output,
                          find_best_split)
@@ -45,7 +46,7 @@ from ..utils.scalars import dev_bool, dev_i32
 from .capabilities import (CapabilityMixin, _cegb_penalty, train_cegb,
                            train_monotone, train_stepwise)
 from .grow import (GrowState, SplitRecord, _compact_child_hist,
-                   _compact_sizes, _grow_tree, _maybe_rand_bins, _record_at,
+                   _grow_tree, _maybe_rand_bins, _record_at,
                    _rows_go_left, _split_step, _store_info,
                    _subtract_child_hists, apply_split_record,
                    build_bundle_tables, make_root_state, record_is_valid)
@@ -113,6 +114,12 @@ def _leaf_histogram(bins, gh, meta, btab, *, B: int, Bg: int,
     bhist = build_histogram(bins, gh, Bg, hist_impl=hist_impl)
     if totals is None or jnp.issubdtype(gh.dtype, jnp.integer):
         totals = sum_gh(gh)
+    return _unbundled(bhist, meta, btab, totals)
+
+
+def _unbundled(bhist, meta, btab, totals):
+    """Per-feature [Fp, B, 4] from the bundle histogram (``totals``
+    None: the histogram's own, ``unpack_bundle_histogram``)."""
     return unpack_bundle_histogram(bhist, btab.gidx_g, btab.gidx_b,
                                    btab.zero_fix, meta.zero_bin, totals)
 
@@ -122,22 +129,24 @@ def _split_body(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
                 B: int, Bg: int, bundled: bool,
                 hist_impl: tuple = ("auto", False), **step) -> GrowState:
     """grow.py's split step over this learner's histogram: the smaller
-    child's rows compacted (``_compact_child_hist``) and histogrammed by
-    ``_leaf_histogram``. ``step`` is ``_split_step``'s keywords."""
-    # quantized mode: the record's totals are dequantized f32, but the
-    # bundled zero-bin fix needs exact int sums — _leaf_histogram
-    # recomputes them from the gathered integer rows
-    def hist_fn(bins, gh, totals):
-        return _leaf_histogram(bins, gh, meta, btab, B=B, Bg=Bg,
-                               bundled=bundled, totals=totals,
-                               hist_impl=hist_impl)
-
+    child's rows and no others (``_compact_child_hist``), a tile at a
+    time, on the path ``_leaf_histogram`` takes over all the rows.
+    ``step`` is ``_split_step``'s keywords."""
     def child_hists(bins, state, rec, leaf, new_leaf, leaf_of_row,
                     smaller_is_left, valid, mask_left, mask_right, qscale):
         def small_hist(small, _mask, totals):
-            return _compact_child_hist(
-                bins, state, small, totals,
-                _compact_sizes(bins.shape[0]), hist_fn)
+            hist = _compact_child_hist(
+                bins, state, small,
+                histogram_tiles(bins, state.gh, Bg if bundled else B,
+                                hist_impl=hist_impl))
+            if not bundled:
+                return hist
+            # quantized mode: the record's totals are dequantized f32,
+            # but the bundled zero-bin fix needs exact int sums, which
+            # the bundle histogram itself holds
+            if jnp.issubdtype(state.gh.dtype, jnp.integer):
+                totals = None
+            return _unbundled(hist, meta, btab, totals)
 
         return _subtract_child_hists(
             state, rec, leaf, new_leaf, leaf_of_row, smaller_is_left,

@@ -153,8 +153,9 @@ def _shard_step(shard_bins, leaf_seg, gh_seg, hist, rec, new_leaf, meta,
 
     ``S`` is the STATIC gather width: a power-of-two bucket of the
     smaller child's global row count (an upper bound on any shard's
-    share of it), the bucketing grow.py's ``_compact_child_hist`` does
-    on the device, to keep deep-tree steps from scanning all rows. Fill rows hit the
+    share of it), chosen on the host, which steps this learner split
+    by split, to keep deep-tree steps from scanning all rows. Fill
+    rows hit the
     shard's zero pad row (gh 0), so the bucket size changes compiled
     variants, never values. ``rec`` comes through ``_partition_rec``:
     on data with no categorical feature its categorical fields are

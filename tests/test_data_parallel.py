@@ -34,8 +34,8 @@ def mesh8():
 
 class TestDataParallel:
     def test_one_device_mesh_compaction_matches_serial(self):
-        """The 1-device mesh path compacts the smaller child's rows
-        before histogramming (lax.switch bucket ladder); the tree must
+        """The 1-device mesh path histograms the smaller child's rows
+        alone (the tile loop of ``_compact_child_hist``); the tree must
         equal the serial learner's exactly at tie-free scale."""
         X, grad, hess = _data(n=1500)
         cfg = Config.from_params({"num_leaves": 31, "min_data_in_leaf": 5,

@@ -102,21 +102,20 @@ def test_scope_is_in_the_lowered_program(lowered, program, scope):
         "%s has no operation under %s" % (program, scope))
 
 
-@pytest.mark.parametrize("program,buckets", [
-    ("tree", (20000, 5000)),
-    # 40,960 padded rows: the same loop and the same compaction
-    ("serial_tree", (20480, 5120)),
-])
-def test_ladder_branches_are_tagged_inside_the_compaction(lowered, program,
-                                                          buckets):
-    """40,000 rows: buckets of a half and an eighth of the learner's
-    rows, each its own branch of the switch inside the whole-tree
-    loop's body, with the histogram's scope inside. Both learners reach
-    the one ``_grow_tree`` and the one ``_compact_child_hist``."""
-    for branch, size in enumerate(buckets):
-        assert re.search(
-            r"while/body/obs_compact/cond/branch_%d_fun/obs_bucket_%d/"
-            r"obs_hist_scatter/" % (branch, size), lowered[program])
+@pytest.mark.parametrize("program", ["tree", "serial_tree"])
+def test_tile_loop_is_tagged_inside_the_compaction(lowered, program):
+    """The smaller child's tiles are one loop inside the whole-tree
+    loop's body, under the compaction's scope, with the tile's gathers
+    there and the histogram's own scope inside; no branch by size is
+    left. Both learners reach the one ``_grow_tree`` and the one
+    ``_compact_child_hist``."""
+    text = lowered[program]
+    inner = r"while/body/obs_compact/while/body/"
+    assert re.search(inner + r"gather", text)
+    assert re.search(inner + r"(\w+/)*obs_hist_scatter/", text)
+    assert "obs_bucket_" not in text
+    assert not re.search(r"obs_compact/cond/branch_\d+_fun/(\w+/)*obs_hist_",
+                         text)
 
 
 def test_pallas_path_is_scoped_and_the_kernel_named(monkeypatch):
@@ -132,6 +131,26 @@ def test_pallas_path_is_scoped_and_the_kernel_named(monkeypatch):
     assert "/obs_hist_pallas/jit(_pallas_histogram_body)" in text
     assert '"hist_kernel/pallas_call"' in text
     assert "tpu_custom_call" in text
+
+
+def test_tile_loop_keeps_the_kernel_under_its_scope_and_name(monkeypatch):
+    """What a tile of the loop lowers to on a TPU: the kernel under
+    ``obs_hist_pallas``, by its name, continuing its third operand in
+    place."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sds = jax.ShapeDtypeStruct
+    rows = 4 * histogram.PALLAS_ROW_TILE
+    tiles = histogram.histogram_tiles(sds((rows, 28), jnp.uint8),
+                                      sds((rows, 4), jnp.float32), 255)
+    tile = histogram.PALLAS_ROW_TILE
+    text = jax.jit(lambda a, b, g: tiles.result(tiles.add(a, b, g))) \
+        .trace(jax.eval_shape(tiles.zeros), sds((tile, 28), jnp.uint8),
+               sds((tile, 4), jnp.float32)) \
+        .lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert '/obs_hist_pallas/hist_kernel/pallas_call"' in text
+    assert "tpu_custom_call" in text
+    assert re.search(r"output_operand_aliases? = \[.*operand_index = 2",
+                     text)
 
 
 # --- (b) the reader ------------------------------------------------------
@@ -344,10 +363,35 @@ def test_bucket_counters_follow_the_trees(timer_on):
     counts = work.tree_counts_from_model_text(bst.model_to_string())
     assert len(counts) == 3
     assert needed - needed0 == sum(sum(smaller) for _, smaller in counts)
-    # every split is padded to a bucket of 20,000 or 5,000 rows
+    # a mesh of several devices passes over every row for every split
     splits = sum(len(smaller) for _, smaller in counts)
-    assert bucketed - bucketed0 >= max(needed - needed0, 5000 * splits)
-    assert (bucketed - bucketed0) % 5000 == 0
+    assert bucketed - bucketed0 == 40000 * splits
+
+
+def _smaller_children(tree) -> list:
+    """Rows of the smaller child of each of a grown tree's splits."""
+    def rows(child):
+        return (tree.internal_count[child] if child >= 0
+                else tree.leaf_count[~child])
+    return [int(min(rows(tree.left_child[k]), rows(tree.right_child[k])))
+            for k in range(tree.num_leaves - 1)]
+
+
+def test_bucket_counters_count_whole_tiles_where_the_learner_compacts(
+        timer_on):
+    """One device: every split's pass visits whole tiles, its smaller
+    child's rows and less than a tile besides."""
+    needed0, bucketed0 = _hist_rows()
+    grown = _grow_trees_on_one_device()
+    needed, bucketed = _hist_rows()
+    smaller = [rows for t in grown for rows in _smaller_children(t)]
+    tile = histogram.DEFAULT_ROW_TILE
+    assert needed - needed0 == sum(smaller)
+    assert bucketed - bucketed0 == sum(-(-rows // tile) * tile
+                                       for rows in smaller)
+    assert (bucketed - bucketed0) % tile == 0
+    assert needed - needed0 <= bucketed - bucketed0 \
+        < needed - needed0 + tile * len(smaller)
 
 
 def test_bucket_counters_stay_still_while_the_timer_is_off():

@@ -18,7 +18,10 @@ store was then copied twice per split (ISSUE 28). Two guards:
 The same compiled program guards the rows ordered by leaf (ISSUE 34,
 ``grow.py _partition_order``): a split step scatters its parent's window
 and nothing longer, and never copies ``GrowState.order``
-(``order_faults``).
+(``order_faults``); and the tile loop of the smaller child's histogram
+(ISSUE 36, ``grow.py _compact_child_hist``): its body gathers a tile's
+rows and continues the accumulator in place, and copies none of the
+arrays it reads a tile of (``whole_copies_in_loops``).
 """
 import re
 from unittest import mock
@@ -44,17 +47,20 @@ def describe_v5e():
 
 
 def compile_tree_program(topo, features: int, leaves: int,
-                         rows: int = 65536, max_bin: int = 255) -> tuple:
+                         rows: int = 65536, max_bin: int = 255,
+                         quantized: bool = False) -> tuple:
     """``(optimised HLO, the store's shape)`` of
     ``DataParallelTreeLearner._tree_impl`` compiled for one described
     v5e chip at ``rows`` x ``features``, with abstract arguments. The
     learner is built on the CPU from a few rows (it only
     lends its metadata and static sizes), then pointed at the described
     chip; ``jax.default_backend`` is steered so that the histogram takes
-    the path it takes on the chip."""
+    the path it takes on the chip. ``quantized``: int8 gradient rows
+    (``use_quantized_grad``), as ``bosch-train-quant`` has them."""
     rng = np.random.RandomState(0)
     cfg = Config.from_params({"num_leaves": leaves, "max_bin": max_bin,
-                              "min_data_in_leaf": 1, "verbosity": -1})
+                              "min_data_in_leaf": 1, "verbosity": -1,
+                              "use_quantized_grad": quantized})
     ds = BinnedDataset.from_matrix(rng.randn(1024, features), cfg)
     learner = DataParallelTreeLearner(cfg, ds, make_mesh(1))
     mesh = Mesh(np.array(topo.devices[:1]), (learner.axis,))
@@ -69,7 +75,8 @@ def compile_tree_program(topo, features: int, leaves: int,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     bins = spec((rows, features), ds.bins.dtype, learner.gh_sharding)
-    gh = spec((rows, 4), jnp.float32, learner.gh_sharding)
+    gh = spec((rows, 4), jnp.int8 if quantized else jnp.float32,
+              learner.gh_sharding)
     fmask = spec((features,), jnp.bool_)
     seed = spec((), jnp.int32)
     qscale = spec((2,), jnp.float32)
@@ -89,15 +96,15 @@ def compile_tree_program(topo, features: int, leaves: int,
 def store_copies(hlo: str, store_shape) -> dict:
     """``{computation name: [copy instructions whose result has the
     store's shape]}`` over every computation of an optimised HLO
-    module, the ``while`` bodies among them."""
-    shape = "f32[%s]" % ",".join(str(d) for d in store_shape)
+    module, the ``while`` bodies among them (``s32`` is the store of
+    quantized rows)."""
+    shape = r"[fs]32\[%s\]" % ",".join(str(d) for d in store_shape)
     found, name = {}, None
     for line in hlo.splitlines():
         head = re.match(r"^(?:ENTRY\s+)?(%?[\w.\-]+)\s.*\{\s*$", line)
         if head and not line.startswith(" "):
             name = head.group(1)
-        elif re.search(r"=\s*%s(\{[^}]*\})?\s+copy\(" % re.escape(shape),
-                       line):
+        elif re.search(r"=\s*%s(\{[^}]*\})?\s+copy\(" % shape, line):
             found.setdefault(name, []).append(line.strip())
     return found
 
@@ -145,6 +152,23 @@ def order_faults(hlo: str, rows: int) -> list:
     return faults
 
 
+def whole_copies_in_loops(hlo: str, shapes) -> list:
+    """The ``copy`` / ``copy-start`` instructions inside any ``while``
+    body of an optimised HLO module whose result has one of ``shapes``
+    (``"u8[65536,64]"``): a loop body that copies a whole array it
+    reads a tile of pays the array per trip."""
+    found, name, bodies = [], None, while_bodies(hlo)
+    shape = "|".join(re.escape(s) for s in shapes)
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?(%?[\w.\-]+)\s.*\{\s*$", line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+        elif name in bodies and re.search(
+                r"=\s*\(?(%s)[^=]*\s(copy|copy-start)\(" % shape, line):
+            found.append(line.strip())
+    return found
+
+
 @pytest.fixture(scope="module")
 def topo():
     try:
@@ -171,6 +195,50 @@ def test_a_split_touches_its_parents_window_of_the_order_on_v5e(topo):
     assert faults == [], (
         "a split step scatters more than its parent's window, or "
         "copies the rows ordered by leaf\n" + "\n".join(faults))
+
+
+@pytest.mark.parametrize("quantized", [False, True],
+                         ids=["float32", "int8"])
+def test_the_tile_loop_copies_no_whole_array_on_v5e(topo, quantized):
+    """The smaller child's tile loop, inside the whole-tree loop: one
+    ``hist_kernel`` call in the tree program (the ladder had one a
+    bucket), continuing its accumulator in place, and no copy of the
+    bin matrix, of the rows ordered by leaf or of the store in a loop
+    body."""
+    rows, features = 65536, 64
+    hlo, store = compile_tree_program(topo, features, 31, rows,
+                                      quantized=quantized)
+    kernels = [line for line in hlo.splitlines()
+               if re.search(r"=.* custom-call\(.*hist_kernel", line)]
+    assert len(kernels) == 1
+    assert "output_to_operand_aliasing={{}: (2, {})}" in kernels[0]
+    assert "obs_compact/while/body/obs_hist_pallas/hist_kernel" \
+        in kernels[0]
+    assert "obs_bucket_" not in hlo
+    shapes = ["u8[%d,%d]" % (rows, features),
+              "s32[%d]" % (rows + _window_sizes(rows)[0]),
+              "%s[%s]" % ("s32" if quantized else "f32",
+                          ",".join(str(d) for d in store))]
+    assert all(s in hlo for s in shapes), "helper stale: %s" % shapes
+    faults = whole_copies_in_loops(hlo, shapes)
+    assert faults == [], "\n".join(faults)
+
+
+def test_whole_copies_in_loops_reads_bodies_alone():
+    hlo = "\n".join([
+        "%body.1 (p: (s32[], u8[8,4])) -> (s32[], u8[8,4]) {",
+        "  %copy.1 = u8[8,4]{1,0:T(8,128)(4,1)} copy(%gte.1)",
+        "  %copy-start.2 = (s32[40]{0:T(1024)S(1)}, s32[40]{0:T(1024)}, "
+        "u32[]{:S(2)}) copy-start(%gte.2)",
+        "  %copy.3 = u8[2,4]{0,1:T(8,128)(4,1)} copy(%fusion.3)",
+        "}",
+        "ENTRY %main.2 (a: u8[8,4]) -> u8[8,4] {",
+        "  %copy.4 = u8[8,4]{1,0:T(8,128)(4,1)} copy(%a)",
+        "  %while.5 = (s32[], u8[8,4]) while(%t), condition=%cond.1, "
+        "body=%body.1",
+        "}"])
+    assert [line.split()[0] for line in whole_copies_in_loops(
+        hlo, ["u8[8,4]", "s32[40]"])] == ["%copy.1", "%copy-start.2"]
 
 
 def test_order_faults_sees_the_scatter_of_every_row_and_a_copy():
