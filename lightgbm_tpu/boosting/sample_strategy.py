@@ -30,7 +30,11 @@ while ``left_total_count``/``right_total_count`` count every row; the
 score update covers every row through ``leaf_of_row``. The sampling is
 named ``obs_goss`` on the device clock, and while the stage timer is on
 ``sample/goss_trees`` counts the trees of the iterations GOSS sampled
-(docs/OBSERVABILITY.md).
+(docs/OBSERVABILITY.md). Plain bagging's draw is ``obs_bag`` there, its
+trees ``sample/bag_trees`` and its draws ``sample/bag_draws`` (one per
+``bagging_freq`` iterations); with ``feature_fraction`` 0.8 beside
+``bagging_fraction`` 0.8 (the cell ``bosch-train-subsample``, PR 37) 59%
+of the row-features the smaller children's passes visit carry weight.
 
 Draws happen ON DEVICE, keyed by ``fold_in(PRNGKey(bagging_seed),
 draw_index)`` where the draw index is a pure function of the iteration
@@ -71,10 +75,12 @@ def _bag_draw(base_key, draw_idx, frac, n: int):
     scalar (plain bagging) or an [n] per-row vector (balanced pos/neg
     bagging). Integer key bits → exact compare: the indicator is
     BIT-deterministic, identical inside a traced scan and as its own
-    dispatch."""
-    key = jax.random.fold_in(base_key, draw_idx)
-    u = jax.random.uniform(key, (n,))
-    return (u < frac).astype(jnp.float32)
+    dispatch. Named ``obs_bag`` on the device clock, in whichever program
+    it is traced (``boost.bag_draw``, the scan body of ``train_many``)."""
+    with jax.named_scope("obs_bag"):
+        key = jax.random.fold_in(base_key, draw_idx)
+        u = jax.random.uniform(key, (n,))
+        return (u < frac).astype(jnp.float32)
 
 
 bag_draw = obs_compile.instrument_jit("boost.bag_draw", _bag_draw,
@@ -202,10 +208,17 @@ class BaggingStrategy(SampleStrategy):
 
     def bagging(self, iter_idx, grad, hess):
         d = int(iter_idx) // self.freq
-        if self._bag is None or d != self._bag_draw_idx:
+        redraw = self._bag is None or d != self._bag_draw_idx
+        if redraw:
             self._bag = bag_draw(self._base_key, dev_i32(d), self._frac,
                                  self.num_data)
             self._bag_draw_idx = d
+        if obs.enabled:
+            # the trees of an iteration share one bag, which lasts
+            # ``freq`` iterations
+            obs.inc("sample/bag_trees", self.num_tree_per_iteration)
+            if redraw:
+                obs.inc("sample/bag_draws")
         return grad, hess, self._bag
 
     def apply_traced(self, iter_idx, grad, hess):

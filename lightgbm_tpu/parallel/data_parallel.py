@@ -225,20 +225,7 @@ class DataParallelTreeLearner(CapabilityMixin):
                        out_shardings=sh)()
 
     # ------------------------------------------------------------------
-    def _sample_features(self) -> jnp.ndarray:
-        ff = float(self.config.feature_fraction)
-        mask = np.ones(self.F, dtype=bool)
-        if 0.0 < ff < 1.0:
-            k = max(1, int(round(self.F * ff)))
-            mask[:] = False
-            mask[self._ff_rng.choice(self.F, k, replace=False)] = True
-        if self._constraint_groups is not None:
-            # root scan may only use features inside some constraint
-            # group (reference: ColSampler::SetUsedFeatureByNode)
-            allowed = np.zeros(self.F, dtype=bool)
-            for grp in self._constraint_groups:
-                allowed[list(grp)] = True
-            mask &= allowed
+    def _place_feature_mask(self, mask: np.ndarray) -> jnp.ndarray:
         return jax.device_put(jnp.asarray(mask), self.rep_sharding)
 
     # ------------------------------------------------------------------
@@ -679,7 +666,7 @@ class DataParallelTreeLearner(CapabilityMixin):
                 gh = self._make_gh(grad, hess, bag)
                 self._qscale = self._qs_ones
             obs.watch_ready("tree::stage_gh", gh)
-            feature_mask = self._sample_features()
+        feature_mask = self._tree_feature_mask()
 
         tree = Tree(self.L)
         self._tree_idx += 1

@@ -93,20 +93,6 @@ class FeatureParallelTreeLearner(DataParallelTreeLearner):
                                          dtype=jnp.float32),
                        out_shardings=self.rep_sharding)()
 
-    def _sample_features(self) -> jnp.ndarray:
-        mask = np.zeros(self.F_pad, dtype=bool)
-        real_f = len(self.dataset.bin_mappers)
-        base = np.ones(real_f, dtype=bool)
-        ff = float(self.config.feature_fraction)
-        if 0.0 < ff < 1.0:
-            k = max(1, int(round(real_f * ff)))
-            base[:] = False
-            base[self._ff_rng.choice(real_f, k, replace=False)] = True
-        mask[:real_f] = base
-        if self._constraint_groups is not None:
-            allowed = np.zeros(self.F_pad, dtype=bool)
-            for grp in self._constraint_groups:
-                allowed[list(grp)] = True
-            mask &= allowed
-        return jax.device_put(jnp.asarray(mask), self.rep_sharding)
-
+    # the column mask is the mixin's draw over the dataset's real
+    # features at this learner's padded width (``Fp`` = ``F_pad``),
+    # replicated by the mesh learner's ``_place_feature_mask``

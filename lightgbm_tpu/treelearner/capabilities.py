@@ -88,24 +88,51 @@ class CapabilityMixin:
         return (self._constraint_groups is not None
                 or 0.0 < float(self.config.feature_fraction_bynode) < 1.0)
 
-    def _sample_features(self) -> jnp.ndarray:
-        """Per-tree column sampling (reference: ColSampler,
-        src/treelearner/col_sampler.hpp:20). Shared by the serial and
-        sharded learners — the host RNG sequence is part of the
+    def _draw_feature_mask(self) -> np.ndarray:
+        """Host ``bool[Fp]`` column mask of one tree: per-tree column
+        sampling (reference: ColSampler,
+        src/treelearner/col_sampler.hpp:20) over the dataset's real
+        features, the padding columns off, and the interaction
+        constraints' allowed set (the root scan may only use features
+        inside some group, ColSampler::SetUsedFeatureByNode). The one
+        draw of every learner: the host RNG sequence is part of the
         bit-parity contract between them."""
+        n_real = self.dataset.num_features
         ff = float(self.config.feature_fraction)
         mask = np.zeros(self.Fp, dtype=bool)
-        mask[:self.F] = True
+        mask[:n_real] = True
         if 0.0 < ff < 1.0:
-            k = max(1, int(round(self.F * ff)))
+            k = max(1, int(round(n_real * ff)))
             mask[:] = False
-            mask[self._ff_rng.choice(self.F, k, replace=False)] = True
+            mask[self._ff_rng.choice(n_real, k, replace=False)] = True
         if self._constraint_groups is not None:
             allowed = np.zeros(self.Fp, dtype=bool)
             for grp in self._constraint_groups:
                 allowed[list(grp)] = True
             mask &= allowed
+        return mask
+
+    def _place_feature_mask(self, mask: np.ndarray) -> jnp.ndarray:
+        """The drawn mask on the device, where the learner's programs
+        take it (the mesh learners replicate it)."""
         return jnp.asarray(mask)
+
+    def _sample_features(self) -> jnp.ndarray:
+        return self._place_feature_mask(self._draw_feature_mask())
+
+    def _tree_feature_mask(self) -> jnp.ndarray:
+        """``_sample_features`` as every learner's ``train`` calls it:
+        the draw and the upload under the host span
+        ``tree::sample_features``, and while the stage timer is on the
+        sampled columns counted beside all of them
+        (``sample/cols_in_mask``, ``sample/cols_total``: nothing skips
+        a masked column yet, the histograms visit every one)."""
+        with obs.scope("tree::sample_features"):
+            mask = self._draw_feature_mask()
+            if obs.enabled:
+                obs.inc("sample/cols_in_mask", int(mask.sum()))
+                obs.inc("sample/cols_total", self.dataset.num_features)
+            return self._place_feature_mask(mask)
 
     # ------------------------------------------------------------------
     def _init_quantization(self, qbits: int, config, max_rows: int

@@ -534,8 +534,8 @@ class SerialTreeLearner(CapabilityMixin):
         self._init_cegb(config)
         self._init_monotone(config)
 
-    # _sample_features lives on CapabilityMixin (shared with the
-    # sharded out-of-core learner, treelearner/sharded.py)
+    # _sample_features and _tree_feature_mask live on CapabilityMixin
+    # (one draw for every learner)
 
     # ------------------------------------------------------------------
     def _build_bundle_tables(self, dataset: BinnedDataset) -> None:
@@ -645,7 +645,7 @@ class SerialTreeLearner(CapabilityMixin):
             # stage; sample/trace mode hands the output to the async
             # readiness drainer instead (no hot-path fence)
             obs.watch_ready("tree::stage_gh", gh)
-            feature_mask = self._sample_features()
+        feature_mask = self._tree_feature_mask()
 
         tree = Tree(self.L)
         # per-tree extra_trees seed (traced, so no retrace per tree);

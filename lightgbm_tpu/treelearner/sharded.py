@@ -574,7 +574,7 @@ class ShardedTreeLearner(CapabilityMixin):
                 self._qscale = self._qs_ones
                 gh = _stage_gh_fn_cached(self.R)(grad, hess, ind)
             obs.watch_ready("tree::stage_gh", gh)
-            feature_mask = self._sample_features()
+        feature_mask = self._tree_feature_mask()
         tree = Tree(self.L)
         self._tree_idx += 1
         rand_seed = dev_i32(
