@@ -37,15 +37,26 @@ DEFAULT_ROW_TILE = 512
 # Rows per Pallas grid step for f32 gh rows.
 PALLAS_ROW_TILE = 2048
 
-# int8 gh rows: 1-byte blocks and one-hot factors let 4x the rows ride
-# each grid step
-PALLAS_ROW_TILE_INT = 4 * PALLAS_ROW_TILE
+# int8 gh rows: 1-byte blocks and one-hot factors let more rows ride
+# each grid step. Read off the v5e at F = 968 (PR 38; ns a row a
+# feature, a tile at a time as the tile loop calls it / a whole pass of
+# 1M rows): 0.107 / 0.103 at 2,048 rows, 0.083 / 0.080 at 4,096, 0.072 /
+# 0.070 at 8,192, where the int8 einsum takes 0.585 and the float32
+# kernel 0.310. The tile loop visits whole tiles and most smaller
+# children hold less than one, so the rate is not the whole cost:
+# bosch-train-quant reads 1.151 s an iteration at 4,096 and 1.183 at
+# 8,192.
+PALLAS_ROW_TILE_INT = 2 * PALLAS_ROW_TILE
 
 # The scoped-VMEM limit pallas_call hands the compiler
 # (CompilerParams.vmem_limit_bytes) and _pallas_fits budgets against.
 # 32 MiB is above Mosaic's 16 MiB default scope and a quarter of a v5e
 # core's 128 MiB.
 PALLAS_VMEM_LIMIT = 32 * 1024 * 1024
+
+# Where the kernel's grid splits the features into blocks, a block's
+# size is a multiple of this (the sublane tile of 4-byte rows).
+FEATURE_BLOCK_ALIGN = 8
 
 
 def resolve_hist_impl(backend: str = "auto",
@@ -105,24 +116,28 @@ def _pallas_row_tile(gh_dtype) -> int:
             else PALLAS_ROW_TILE)
 
 
-def _pallas_vmem_bytes(F: int, num_bins: int, C: int, T: int,
-                       gh_itemsize: int, bins_itemsize: int = 1) -> int:
-    """Upper bound on the VMEM the compiled kernel holds at once,
-    counted the way Mosaic lays arrays out: a [rows, T] array pads its
-    rows to the dtype's sublane tile, a minor dimension pads to 128
-    lanes, and every BlockSpec'd operand is double-buffered. Transients
-    are counted as if each were materialized whole (the compiler keeps
-    most of them in vregs, so the real need is lower — an AOT bisection
-    of ``vmem_limit_bytes`` at the Higgs shape found 576 KiB for f32
-    and 1.8 MiB for int8 against this bound's 3.3 / 11.4 MiB)."""
+def _pallas_vmem_bytes(FB: int, num_bins: int, C: int, T: int,
+                       gh_itemsize: int, bins_itemsize: int = 1,
+                       blocked: bool = False) -> int:
+    """Upper bound on the VMEM the compiled kernel holds at once for a
+    block of ``FB`` features, counted the way Mosaic lays arrays out: a
+    [rows, T] array pads its rows to the dtype's sublane tile, a minor
+    dimension pads to 128 lanes, and every BlockSpec'd operand is
+    double-buffered: the accumulator's output block always, and where
+    the features are split into several blocks (``blocked``) the
+    accumulator's input block too. Transients are counted as if each
+    were materialized whole (the compiler keeps most of them in vregs,
+    so the real need is lower — an AOT bisection of
+    ``vmem_limit_bytes`` at the Higgs shape found 576 KiB for f32 and
+    1.8 MiB for int8 against this bound's 3.3 / 11.4 MiB)."""
     H = _hi_rows(num_bins, gh_itemsize)
 
     def rows_by_T(rows: int, itemsize: int) -> int:
         return _ceil_to(rows, _sublanes(itemsize)) * T * itemsize
 
-    blocks = 2 * (rows_by_T(F, bins_itemsize) + rows_by_T(C, gh_itemsize))
-    acc = 2 * F * H * _ceil_to(16 * C, 128) * 4
-    scratch = rows_by_T(F, 4)                    # int32 copy of the bins
+    blocks = 2 * (rows_by_T(FB, bins_itemsize) + rows_by_T(C, gh_itemsize))
+    acc = (4 if blocked else 2) * FB * H * _ceil_to(16 * C, 128) * 4
+    scratch = rows_by_T(FB, 4)                   # int32 copy of the bins
     # per feature: the bin row and its nibbles, both int32 iotas and
     # compare masks, the hi one-hot, W's C selected pieces and their
     # concatenation at 4 bytes, and the matmul's W operand
@@ -134,13 +149,37 @@ def _pallas_vmem_bytes(F: int, num_bins: int, C: int, T: int,
     return blocks + acc + scratch + trans
 
 
+def _pallas_feature_block(F: int, num_bins: int, C: int, T: int,
+                          gh_itemsize: int, bins_itemsize: int = 1) -> int:
+    """Features one block of the kernel's grid holds, a pure function
+    of the static shape and dtypes: all ``F`` where the VMEM bound
+    admits them (one block: the accumulator stays in HBM and is copied
+    in once), else the size of the fewest balanced blocks, each a
+    multiple of ``FEATURE_BLOCK_ALIGN`` (Mosaic refuses a block that is
+    neither that nor all of F), whose bound, the blocked accumulator
+    operand counted, stays under the limit; 0 where not even the
+    smallest block does (a huge ``num_bins`` or ``C``)."""
+    def fits(fb: int, blocked: bool) -> bool:
+        return _pallas_vmem_bytes(fb, num_bins, C, T, gh_itemsize,
+                                  bins_itemsize, blocked) \
+            <= PALLAS_VMEM_LIMIT
+
+    if fits(F, False):
+        return F
+    for n_blocks in range(2, -(-F // FEATURE_BLOCK_ALIGN) + 1):
+        fb = _ceil_to(-(-F // n_blocks), FEATURE_BLOCK_ALIGN)
+        if fits(fb, True):
+            return fb
+    return 0
+
+
 def _pallas_fits(F: int, num_bins: int, C: int,
                  T: int = PALLAS_ROW_TILE, itemsize: int = 4,
                  bins_itemsize: int = 1) -> bool:
-    """Static gate: the kernel's VMEM bound stays under the limit
-    pallas_call passes the compiler."""
-    return (_pallas_vmem_bytes(F, num_bins, C, T, itemsize,
-                               bins_itemsize) <= PALLAS_VMEM_LIMIT)
+    """Static gate: some block of features keeps the kernel's VMEM
+    bound under the limit pallas_call passes the compiler."""
+    return _pallas_feature_block(F, num_bins, C, T, itemsize,
+                                 bins_itemsize) > 0
 
 
 def _warn_once(msg: str, component: str = "ops.histogram") -> None:
@@ -249,14 +288,23 @@ def _tile_histogram(bins_tile: jnp.ndarray, gh_tile: jnp.ndarray,
         preferred_element_type=acc_dtype)
 
 
-def _hist_kernel_body(F: int, H: int, C: int, bins_ref, gh_ref, acc_ref,
-                      out_ref, bins32_ref):
+def _hist_kernel_body(FB: int, H: int, C: int, acc_in_hbm: bool,
+                      bins_ref, gh_ref, acc_ref, out_ref, bins32_ref):
     """Pallas TPU kernel: one grid step accumulates a feature-major
-    [F, T] row tile into the [F, H, 16*C] VMEM-resident accumulator,
-    which starts as ``acc_ref``: the histogram so far, left in HBM
-    (a block of it would be a second accumulator in VMEM, which F = 968
-    has no room for) and copied in by the first step. ``acc_ref`` and
-    the output are one buffer (``input_output_aliases``).
+    [FB, T] row tile of one block of features into the block's
+    [FB, H, 16*C] VMEM-resident accumulator. The grid's last axis runs
+    over the row tiles; where the features take several blocks an axis
+    over the blocks is outside it, so a block sees all its row tiles
+    before the next one starts and its accumulator stays resident
+    meanwhile. The accumulator starts as ``acc_ref``, the histogram so
+    far, and ``acc_ref`` and the output are one buffer
+    (``input_output_aliases``). With one block (``acc_in_hbm``)
+    ``acc_ref`` is left in HBM and copied in by the first step: a block
+    of it would be a second accumulator in VMEM, which F = 968 in
+    float32 has no room for. With several it is a blocked operand with
+    the output's index map (Mosaic cannot slice an HBM ref whose minor
+    dimension is 16*C = 64 lanes), which the block size leaves room
+    for.
 
     The bin index factorizes as ``bin = hi*16 + lo``; per feature the
     contribution is ``A_f @ W_f^T`` where ``A_f[hi, t]`` is the
@@ -271,7 +319,10 @@ def _hist_kernel_body(F: int, H: int, C: int, bins_ref, gh_ref, acc_ref,
 
     Feature ``f`` is a LEADING-axis index into refs (``bins32_ref[f]``
     row, ``out_ref[f]`` slab), so the loop stays a ``fori_loop`` whose
-    code size does not grow with F. Mosaic cannot take a dynamic
+    code size does not grow with the block. A feature's histogram takes
+    the same additions in the same order whatever the block size. The
+    rows of a ragged last block beyond F hold whatever the padded bin
+    block held and are never written back. Mosaic cannot take a dynamic
     sublane offset into a packed (1- or 2-byte) ref, hence the int32
     copy of the bin tile in scratch.
 
@@ -280,9 +331,12 @@ def _hist_kernel_body(F: int, H: int, C: int, bins_ref, gh_ref, acc_ref,
     contract at fp32 precision into f32. The int8 ``where`` is taken
     in int32 and narrowed after — Mosaic cannot move an int32 compare
     mask onto int8's (32, 128) tiling."""
-    @pl.when(pl.program_id(0) == 0)
+    @pl.when(pl.program_id(0 if acc_in_hbm else 1) == 0)
     def _init():
-        pltpu.sync_copy(acc_ref, out_ref)
+        if acc_in_hbm:
+            pltpu.sync_copy(acc_ref, out_ref)
+        else:
+            out_ref[...] = acc_ref[...]
 
     T = bins_ref.shape[1]
     bins32_ref[...] = bins_ref[...].astype(jnp.int32)
@@ -307,36 +361,53 @@ def _hist_kernel_body(F: int, H: int, C: int, bins_ref, gh_ref, acc_ref,
             preferred_element_type=out_ref.dtype)        # [H, 16C]
         return carry
 
-    jax.lax.fori_loop(0, F, body, 0)
+    jax.lax.fori_loop(0, FB, body, 0)
 
 
 def _pallas_accumulate(acc: jnp.ndarray, bins: jnp.ndarray,
                        gh: jnp.ndarray, row_tile: int,
-                       interpret: bool = False) -> jnp.ndarray:
+                       interpret: bool = False,
+                       feature_block: int = 0) -> jnp.ndarray:
     """``acc`` ([F, H, 16*C], the kernel's own layout) plus the
     histogram of [S, F] bins x [S, C] gh, S a whole number of row tiles:
     the kernel adds one tile after another into ``acc``'s buffer, so a
     histogram built over several calls takes the additions one call
-    over all the rows would give it."""
+    over all the rows would give it. The features go through the kernel
+    in blocks of ``_pallas_feature_block`` (``feature_block`` is the
+    tests', to split shapes small enough to interpret)."""
     S, F = bins.shape
     C = gh.shape[1]
     H = acc.shape[1]
     T = row_tile
+    # 16 * H bins have H hi-nibble rows: all the bound reads of them
+    FB = min(F, feature_block or _pallas_feature_block(
+        F, 16 * H, C, T, gh.dtype.itemsize, bins.dtype.itemsize))
+    one_block = FB == F
+    if one_block:
+        grid = (S // T,)
+        bins_at = gh_at = lambda i: (0, i)
+        out_at = lambda i: (0, 0, 0)
+        acc_spec = pl.BlockSpec(memory_space=pl.ANY)
+    else:
+        grid = (pl.cdiv(F, FB), S // T)
+        bins_at, gh_at = (lambda j, i: (j, i)), (lambda j, i: (0, i))
+        out_at = lambda j, i: (j, 0, 0)
+        acc_spec = pl.BlockSpec((FB, H, 16 * C), out_at)
     return pl.pallas_call(
-        functools.partial(_hist_kernel_body, F, H, C),
+        functools.partial(_hist_kernel_body, FB, H, C, one_block),
         name="hist_kernel",
-        grid=(S // T,),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((F, T), lambda i: (0, i)),
-            pl.BlockSpec((C, T), lambda i: (0, i)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((FB, T), bins_at),
+            pl.BlockSpec((C, T), gh_at),
+            acc_spec,
         ],
-        out_specs=pl.BlockSpec((F, H, 16 * C), lambda i: (0, 0, 0)),
+        out_specs=pl.BlockSpec((FB, H, 16 * C), out_at),
         out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
-        scratch_shapes=[pltpu.VMEM((F, T), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((FB, T), jnp.int32)],
         input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary",) * len(grid),
             vmem_limit_bytes=PALLAS_VMEM_LIMIT),
         interpret=interpret,
     )(bins.T, gh.T, acc)
@@ -404,7 +475,8 @@ def _pallas_excluded(S: int, F: int, num_bins: int, C: int, gh_dtype,
         return "C=%d > 8 stat columns" % C
     if not _pallas_fits(F, num_bins, C, T, gh_dtype.itemsize,
                         bins_itemsize):
-        return "VMEM bound (F=%d B=%d)" % (F, num_bins)
+        return ("VMEM bound (B=%d C=%d: no block of features fits)"
+                % (num_bins, C))
     return None
 
 
@@ -488,11 +560,12 @@ class HistogramTiles(NamedTuple):
     ``result(acc)`` is what ``build_histogram`` returns for the same
     rows in the same order, addition for addition. The layout change
     and the cast are ``result``'s, once a histogram and not once a
-    tile."""
+    tile. ``kernel``: the path is the Pallas kernel's."""
     rows: int
     zeros: Callable
     add: Callable
     result: Callable
+    kernel: bool = False
 
 
 def histogram_tiles(bins, gh, num_bins: int, pallas_ok: bool = True,
@@ -520,7 +593,7 @@ def histogram_tiles(bins, gh, num_bins: int, pallas_ok: bool = True,
 
         return HistogramTiles(
             T, lambda: _kernel_zeros(F, num_bins, C, gh_dtype), add,
-            result)
+            result, kernel=True)
     quantized = jnp.issubdtype(gh_dtype, jnp.integer)
     row_dtype = jnp.dtype(jnp.float64) if f64 else gh_dtype
     acc_dtype = _acc_dtype_of(row_dtype)

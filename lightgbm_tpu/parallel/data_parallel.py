@@ -718,15 +718,20 @@ class DataParallelTreeLearner(CapabilityMixin):
         """``grow/hist_rows_needed``: rows of the smaller child of each
         applied split, which a histogram has to visit;
         ``grow/hist_rows_bucketed``: rows the learner's passes visited
-        for them; ``grow/hist_rows_in_bag``: those of the smaller
-        child's rows that are in the bag (all of them where nothing is
-        sampled), the only ones that carry weight."""
+        for them; ``grow/hist_rows_kernel``: the rows of those passes
+        and of the root's that went through the Pallas kernel (all or
+        none: the static gate of ``ops/histogram.py`` is one for both);
+        ``grow/hist_rows_in_bag``: those of the smaller child's rows
+        that are in the bag (all of them where nothing is sampled), the
+        only ones that carry weight."""
         left_total = recs_h.left_total_count[:applied]
         right_total = recs_h.right_total_count[:applied]
         small = np.minimum(left_total, right_total)
+        bucketed = int(self._hist_rows_bucketed(small))
         obs.inc("grow/hist_rows_needed", int(small.sum()))
-        obs.inc("grow/hist_rows_bucketed",
-                int(self._hist_rows_bucketed(small)))
+        obs.inc("grow/hist_rows_bucketed", bucketed)
+        if self._compacts() and self._data_tiles().kernel:
+            obs.inc("grow/hist_rows_kernel", self.R + bucketed)
         # the child _split_step takes as the smaller one (ties: left)
         obs.inc("grow/hist_rows_in_bag", int(np.where(
             left_total <= right_total, recs_h.left_count[:applied],
@@ -749,10 +754,14 @@ class DataParallelTreeLearner(CapabilityMixin):
         row space on a sharded mesh."""
         if not self._compacts():
             return self.R * len(small)
-        T = self._hist_tiles(self.bins, jax.ShapeDtypeStruct(
-            (self.R, 4),
-            self._qdtype if self._quantized else jnp.float32)).rows
+        T = self._data_tiles().rows
         return int((-(-small.astype(np.int64) // T) * T).sum())
+
+    def _data_tiles(self):
+        """``_hist_tiles`` of the learner's own rows, for the counters."""
+        return self._hist_tiles(self.bins, jax.ShapeDtypeStruct(
+            (self.R, 4),
+            self._qdtype if self._quantized else jnp.float32))
 
     def _count_partition_rows(self, recs_h, applied: int) -> None:
         """Where the learner keeps the rows ordered by leaf:

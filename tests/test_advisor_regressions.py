@@ -9,17 +9,70 @@ import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.ops.histogram import (_pallas_fits, _warn_once,
-                                        build_histogram,
+from lightgbm_tpu.ops.histogram import (FEATURE_BLOCK_ALIGN,
+                                        PALLAS_ROW_TILE,
+                                        PALLAS_ROW_TILE_INT,
+                                        PALLAS_VMEM_LIMIT,
+                                        _pallas_feature_block,
+                                        _pallas_fits, _pallas_vmem_bytes,
+                                        _warn_once, build_histogram,
                                         resolve_hist_impl)
 
 
 def test_pallas_vmem_bound_rejects_wide_shapes():
     """A histogram whose VMEM-resident accumulator + transients exceed
     the budget must not select the Pallas kernel (round-3 finding: a
-    Mosaic compile/VMEM failure at real width killed training)."""
+    Mosaic compile/VMEM failure at real width killed training). Since
+    the kernel's grid runs over blocks of features the width that is
+    refused is a feature's own (bins, stat columns), not F."""
     assert _pallas_fits(28, 256, 4)          # Higgs shape fits
-    assert not _pallas_fits(8192, 256, 8)    # ~2 GB accumulator: no
+    assert _pallas_fits(8192, 256, 8)        # in blocks of features
+    assert _pallas_fits(2000, 256, 4)        # epsilon
+    assert _pallas_fits(968, 256, 4, PALLAS_ROW_TILE_INT, 1)
+    # 65,536 bins: 8 features' accumulators are 4 x 8 x 4,096 x 128 x 4
+    # bytes = 64 MiB, twice the limit
+    assert not _pallas_fits(28, 65536, 4, bins_itemsize=2)
+    assert _pallas_feature_block(28, 65536, 4, PALLAS_ROW_TILE, 4, 2) == 0
+
+
+def _bound(F, fb, T, itemsize):
+    return _pallas_vmem_bytes(fb, 256, 4, T, itemsize, 1, blocked=fb < F)
+
+
+@pytest.mark.parametrize("F,T,itemsize,one_block", [
+    (968, PALLAS_ROW_TILE, 4, True),         # bosch-train: today's program
+    (28, PALLAS_ROW_TILE, 4, True),
+    (28, PALLAS_ROW_TILE_INT, 1, True),
+    (2000, PALLAS_ROW_TILE, 4, False),       # epsilon-train
+    (968, PALLAS_ROW_TILE_INT, 1, False),    # bosch-train-quant
+], ids=["bosch-f32", "higgs-f32", "higgs-int8", "epsilon-f32",
+        "bosch-int8"])
+def test_feature_block_of_the_benchmark_shapes(F, T, itemsize, one_block):
+    """All of F where the bound admits it, else the fewest balanced
+    blocks, each a multiple of 8, under the limit."""
+    fb = _pallas_feature_block(F, 256, 4, T, itemsize, 1)
+    assert _bound(F, fb, T, itemsize) <= PALLAS_VMEM_LIMIT
+    if one_block:
+        assert fb == F
+        return
+    assert 0 < fb < F and fb % FEATURE_BLOCK_ALIGN == 0
+    blocks = -(-F // fb)
+    # balanced: the blocks differ by less than one alignment step each
+    assert blocks * fb - F < blocks * FEATURE_BLOCK_ALIGN
+    # the fewest: one block less would pass the limit
+    fewer = -(-F // (blocks - 1))
+    assert _bound(F, fewer, T, itemsize) > PALLAS_VMEM_LIMIT
+
+
+@pytest.mark.parametrize("T,itemsize", [(PALLAS_ROW_TILE, 4),
+                                        (PALLAS_ROW_TILE_INT, 1)],
+                         ids=["float32", "int8"])
+def test_feature_block_stays_under_the_limit_for_every_width(T, itemsize):
+    for F in list(range(1, 64)) + list(range(64, 8200, 37)):
+        fb = _pallas_feature_block(F, 256, 4, T, itemsize, 1)
+        assert 0 < fb <= F
+        assert fb == F or fb % FEATURE_BLOCK_ALIGN == 0
+        assert _bound(F, fb, T, itemsize) <= PALLAS_VMEM_LIMIT, F
 
 
 @pytest.fixture

@@ -133,24 +133,79 @@ def test_pallas_path_is_scoped_and_the_kernel_named(monkeypatch):
     assert "tpu_custom_call" in text
 
 
-def test_tile_loop_keeps_the_kernel_under_its_scope_and_name(monkeypatch):
+@pytest.mark.parametrize("features", [28, 2000],
+                         ids=["one-block", "three-blocks"])
+def test_tile_loop_keeps_the_kernel_under_its_scope_and_name(monkeypatch,
+                                                             features):
     """What a tile of the loop lowers to on a TPU: the kernel under
     ``obs_hist_pallas``, by its name, continuing its third operand in
-    place."""
+    place, whether the features go through in one block or several."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     sds = jax.ShapeDtypeStruct
     rows = 4 * histogram.PALLAS_ROW_TILE
-    tiles = histogram.histogram_tiles(sds((rows, 28), jnp.uint8),
+    tiles = histogram.histogram_tiles(sds((rows, features), jnp.uint8),
                                       sds((rows, 4), jnp.float32), 255)
     tile = histogram.PALLAS_ROW_TILE
     text = jax.jit(lambda a, b, g: tiles.result(tiles.add(a, b, g))) \
-        .trace(jax.eval_shape(tiles.zeros), sds((tile, 28), jnp.uint8),
+        .trace(jax.eval_shape(tiles.zeros),
+               sds((tile, features), jnp.uint8),
                sds((tile, 4), jnp.float32)) \
         .lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
     assert '/obs_hist_pallas/hist_kernel/pallas_call"' in text
     assert "tpu_custom_call" in text
     assert re.search(r"output_operand_aliases? = \[.*operand_index = 2",
                      text)
+
+
+def _kernel_call(features, gh_dtype, tile):
+    """The ``pallas_call`` equation of one tile's ``_pallas_accumulate``."""
+    sds = jax.ShapeDtypeStruct
+    acc = jax.eval_shape(
+        lambda: histogram._kernel_zeros(features, 255, 4, gh_dtype))
+    jaxpr = jax.make_jaxpr(
+        lambda a, b, g: histogram._pallas_accumulate(a, b, g, tile))(
+            acc, sds((3 * tile, features), jnp.uint8),
+            sds((3 * tile, 4), gh_dtype))
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    return call.params["grid_mapping"]
+
+
+def test_one_block_of_features_is_the_kernel_of_before():
+    """F = 968 in float32 (``bosch-train`` and its two sampled cells):
+    the chooser returns F, the grid runs over the row tiles alone and
+    the accumulator operand is left in HBM (``pl.ANY``), copied in by
+    the first step: the program those cells ran before the kernel had
+    an axis over feature blocks."""
+    tile = histogram.PALLAS_ROW_TILE
+    assert histogram._pallas_feature_block(968, 255, 4, tile, 4, 1) == 968
+    mapping = _kernel_call(968, jnp.float32, tile)
+    assert mapping.grid == (3,)
+    bins, gh, acc, out = mapping.block_mappings
+    assert str(acc.transformed_block_aval.memory_space) == "any"
+    assert [b.transformed_block_aval.shape for b in (bins, gh, out)] \
+        == [(968, tile), (4, tile), (968, 16, 64)]
+
+
+@pytest.mark.parametrize("features,gh_dtype,tile,hi_rows", [
+    (2000, jnp.float32, histogram.PALLAS_ROW_TILE, 16),
+    (968, jnp.int8, histogram.PALLAS_ROW_TILE_INT, 32)],
+    ids=["epsilon-f32", "bosch-int8"])
+def test_several_blocks_of_features_put_their_axis_outside_the_rows(
+        features, gh_dtype, tile, hi_rows):
+    """Where all of F passes the VMEM bound the grid gains a leading
+    axis over blocks of features, and the accumulator comes in as a
+    block with the output's index map."""
+    block = histogram._pallas_feature_block(
+        features, 255, 4, tile, jnp.dtype(gh_dtype).itemsize, 1)
+    mapping = _kernel_call(features, gh_dtype, tile)
+    assert mapping.grid == (-(-features // block), 3)
+    bins, gh, acc, out = mapping.block_mappings
+    assert acc.transformed_block_aval.memory_space is None
+    assert [b.transformed_block_aval.shape for b in (bins, gh, acc, out)] \
+        == [(block, tile), (4, tile), (block, hi_rows, 64),
+            (block, hi_rows, 64)]
+    assert str(acc.index_map_jaxpr) == str(out.index_map_jaxpr)
 
 
 # --- (b) the reader ------------------------------------------------------

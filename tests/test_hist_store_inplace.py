@@ -224,6 +224,34 @@ def test_the_tile_loop_copies_no_whole_array_on_v5e(topo, quantized):
     assert faults == [], "\n".join(faults)
 
 
+@pytest.mark.parametrize("features,gh_dtype", [
+    (2000, jnp.float32), (968, jnp.int8), (968, jnp.float32)],
+    ids=["epsilon-f32", "bosch-int8", "bosch-f32"])
+def test_the_kernel_compiles_through_mosaic_at_the_cells_widths(
+        topo, features, gh_dtype):
+    """``_pallas_accumulate`` through Mosaic for a described v5e at the
+    benchmark cells' widths and row tiles, feature blocks as the
+    chooser sets them: what Mosaic refuses (a block that is neither a
+    multiple of 8 nor all of F, an HBM slice off the 128-lane tiling,
+    more VMEM than the limit) is refused here, in a second, and not on
+    the chip."""
+    from lightgbm_tpu.ops import histogram
+    one_chip = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+    tile = histogram._pallas_row_tile(gh_dtype)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    acc = jax.eval_shape(
+        lambda: histogram._kernel_zeros(features, 255, 4, gh_dtype))
+    compiled = jax.jit(
+        lambda a, b, g: histogram._pallas_accumulate(a, b, g, tile),
+        donate_argnums=0).lower(
+            spec(acc.shape, acc.dtype), spec((2 * tile, features), jnp.uint8),
+            spec((2 * tile, 4), gh_dtype)).compile()
+    assert "hist_kernel" in compiled.as_text()
+
+
 def test_whole_copies_in_loops_reads_bodies_alone():
     hlo = "\n".join([
         "%body.1 (p: (s32[], u8[8,4])) -> (s32[], u8[8,4]) {",

@@ -7,6 +7,9 @@ import pytest
 
 from lightgbm_tpu.ops.histogram import (PALLAS_ROW_TILE,
                                         PALLAS_ROW_TILE_INT,
+                                        _from_kernel_layout,
+                                        _kernel_zeros,
+                                        _pallas_accumulate,
                                         _pallas_histogram_body,
                                         _segment_histogram,
                                         build_histogram,
@@ -71,14 +74,51 @@ def test_count_channel_exact():
 # same body — so a CPU-only change cannot break the kernel unseen
 # ----------------------------------------------------------------------
 
-@pytest.mark.parametrize("gh_dtype,tile", [
-    (jnp.float32, PALLAS_ROW_TILE), (jnp.int8, PALLAS_ROW_TILE_INT)])
-def test_pallas_kernel_lowers_for_tpu(gh_dtype, tile):
-    """Higgs width (F=28, B=255, C=4) at the product row tiles."""
-    bins = jax.ShapeDtypeStruct((2 * tile, 28), jnp.uint8)
+@pytest.mark.parametrize("gh_dtype,tile,features", [
+    (jnp.float32, PALLAS_ROW_TILE, 28), (jnp.int8, PALLAS_ROW_TILE_INT, 28),
+    (jnp.float32, PALLAS_ROW_TILE, 968), (jnp.float32, PALLAS_ROW_TILE, 2000),
+    (jnp.int8, PALLAS_ROW_TILE_INT, 968)],
+    ids=["higgs-f32", "higgs-int8", "bosch-f32", "epsilon-f32",
+         "bosch-int8"])
+def test_pallas_kernel_lowers_for_tpu(gh_dtype, tile, features):
+    """Higgs width (F=28, B=255, C=4) and the benchmark cells' widths
+    at the product row tiles: one block of features at F = 28 and at
+    F = 968 in float32, several at F = 2,000 and for int8 rows at
+    F = 968."""
+    bins = jax.ShapeDtypeStruct((2 * tile, features), jnp.uint8)
     gh = jax.ShapeDtypeStruct((2 * tile, 4), gh_dtype)
     jax.jit(lambda b, g: _pallas_histogram_body(b, g, 255, tile)) \
         .trace(bins, gh).lower(lowering_platforms=("tpu",))
+
+
+@pytest.mark.parametrize("gh_dtype", [np.float32, np.int8])
+@pytest.mark.parametrize("block", [8, 16, 24])
+def test_feature_blocks_give_the_one_block_kernels_bits(gh_dtype, block):
+    """The kernel interpreted with its grid's axis over blocks of
+    features (40 features in blocks of 8, 16 with a ragged last block
+    of 8, 24 with one of 16) against the one-block kernel, continuing a
+    histogram that is not empty: every feature takes the same additions
+    in the same order, so the bytes are equal, float32 too."""
+    S, F, B, tile = 1024, 40, 255, 256
+    rng = np.random.RandomState(5)
+    bins = jnp.asarray(rng.randint(0, B, size=(S, F)).astype(np.uint8))
+    if gh_dtype == np.int8:
+        gh = rng.randint(-127, 128, size=(S, 4)).astype(np.int8)
+    else:
+        gh = rng.randn(S, 4).astype(np.float32)
+    gh = jnp.asarray(gh)
+    before = _pallas_accumulate(_kernel_zeros(F, B, 4, gh.dtype),
+                                bins[-tile:], gh[:tile], tile,
+                                interpret=True)
+    one = _pallas_accumulate(before, bins, gh, tile, interpret=True)
+    blocked = _pallas_accumulate(before, bins, gh, tile, interpret=True,
+                                 feature_block=block)
+    assert np.asarray(blocked).tobytes() == np.asarray(one).tobytes()
+    np.testing.assert_allclose(
+        np.asarray(_from_kernel_layout(blocked, B)),
+        np.asarray(_segment_histogram(bins, gh, B)
+                   + _segment_histogram(bins[-tile:], gh[:tile], B)),
+        rtol=1e-5, atol=1e-4)
 
 
 @pytest.mark.parametrize("S,F,B,tile", [(512, 5, 64, 256),

@@ -872,6 +872,41 @@ class TestJLT010:
         assert rules_at(findings) == [("JLT010", 9)]
         assert "invoked with 1 array" in findings[0].message
 
+    def test_a_spec_bound_to_a_name_counts_as_one(self):
+        """An in_specs element that is a name (a spec chosen by shape
+        above the call) is one operand's spec: the arity checks count
+        the list's elements, and skip only what they cannot read."""
+        source = """
+            import jax
+            import jax.numpy as jnp
+            from jax.experimental import pallas as pl
+
+            PALLAS_VMEM_BUDGET = 1 << 20
+
+            def _acc_kernel_body(x_ref, w_ref, o_ref):
+                o_ref[...] = x_ref[...]
+
+            def run(x, w, whole):
+                w_spec = (pl.BlockSpec(memory_space=pl.ANY) if whole
+                          else pl.BlockSpec((128, 16), lambda i: (0, 0)))
+                return pl.pallas_call(
+                    _acc_kernel_body,
+                    grid=(4,),
+                    in_specs=[pl.BlockSpec((8, 128), lambda i: (i, 0)),
+                              w_spec],
+                    out_specs=pl.BlockSpec((8, 16), lambda i: (i, 0)),
+                    out_shape=jax.ShapeDtypeStruct((32, 16),
+                                                   jnp.float32),
+                )(%s)
+        """
+        findings, _ = lint(source % "x, w", relpath="ops/k.py",
+                           select=["JLT010"])
+        assert findings == []
+        findings, _ = lint(source % "x", relpath="ops/k.py",
+                           select=["JLT010"])
+        assert [f.rule for f in findings] == ["JLT010"]
+        assert "declares 2 in_specs" in findings[0].message
+
     def test_consistent_kernel_is_clean(self):
         findings, _ = lint("""
             import functools

@@ -109,22 +109,29 @@ def test_a_tile_may_not_pass_the_spare_tail_of_the_order():
 
 @pytest.mark.parametrize("gh_dtype", [np.float32, np.int8])
 @pytest.mark.parametrize("tiles_of_rows", [1, 3])
+@pytest.mark.parametrize("feature_block", [0, 8],
+                         ids=["one-block", "blocks-of-8"])
 def test_kernel_continues_its_accumulator_like_one_call(gh_dtype,
-                                                        tiles_of_rows):
+                                                        tiles_of_rows,
+                                                        feature_block):
     """The Pallas kernel, interpreted: ``tiles_of_rows`` row tiles a
     call, one call after another into the same accumulator, against one
-    call over all the rows (the root's pass): the same additions."""
-    tile, calls = 256, 4
+    call of the one-block kernel over all the rows (the root's pass):
+    the same additions, whether the features go through in one block or
+    in blocks of 8 (12 features: the last block is ragged)."""
+    tile, calls, features = 256, 4, 12
     S = tile * tiles_of_rows * calls
     rng = np.random.RandomState(2)
-    bins = jnp.asarray(rng.randint(0, B, size=(S, F)).astype(np.uint8))
+    bins = jnp.asarray(
+        rng.randint(0, B, size=(S, features)).astype(np.uint8))
     gh = jnp.asarray(_data(gh_dtype)[1][:S])
-    acc = histogram._kernel_zeros(F, B, 4, gh.dtype)
+    acc = histogram._kernel_zeros(features, B, 4, gh.dtype)
     step = S // calls
     for k in range(calls):
         acc = histogram._pallas_accumulate(
             acc, bins[k * step:(k + 1) * step],
-            gh[k * step:(k + 1) * step], tile, interpret=True)
+            gh[k * step:(k + 1) * step], tile, interpret=True,
+            feature_block=feature_block)
     got = np.asarray(histogram._from_kernel_layout(acc, B))
     whole = np.asarray(histogram._pallas_histogram_body(
         bins, gh, B, tile, interpret=True))
@@ -144,14 +151,30 @@ def test_tiles_follow_the_path_build_histogram_takes(monkeypatch):
         == histogram.PALLAS_ROW_TILE
     assert histogram_tiles(bins, i8, 255).rows \
         == histogram.PALLAS_ROW_TILE_INT
-    # what the kernel does not take: a sharded caller's rows, F = 2,000
-    # (its VMEM bound), fewer rows than one row tile
+    # the benchmark's widths take the kernel too, in blocks of features
+    # where all of them pass its VMEM bound: F = 2,000 in float32 and
+    # int8 rows at F = 968
+    wide, bosch = sds((65536, 2000), jnp.uint8), sds((65536, 968), jnp.uint8)
+    assert histogram_tiles(wide, f32, 255).rows \
+        == histogram.PALLAS_ROW_TILE
+    assert histogram_tiles(bosch, f32, 255).rows \
+        == histogram.PALLAS_ROW_TILE
+    assert histogram_tiles(bosch, i8, 255).rows \
+        == histogram.PALLAS_ROW_TILE_INT
+    # what the kernel does not take: a sharded caller's rows, float64
+    # sums, int16 rows, fewer rows than one row tile, a histogram so
+    # wide that not even 8 features' accumulators fit
     assert histogram_tiles(bins, f32, 255, pallas_ok=False).rows == T
     assert histogram_tiles(bins, i8, 255, pallas_ok=False).rows == 2 * T
-    assert histogram_tiles(sds((65536, 2000), jnp.uint8), f32,
-                           255).rows == T
+    assert histogram_tiles(wide, f32, 255, pallas_ok=False).rows == T
+    assert histogram_tiles(bins, f32, 255,
+                           hist_impl=("auto", True)).rows == T
+    assert histogram_tiles(bins, sds((65536, 4), jnp.int16),
+                           255).rows == 2 * T
     assert histogram_tiles(sds((1024, 28), jnp.uint8),
                            sds((1024, 4), jnp.float32), 255).rows == T
+    assert histogram_tiles(sds((65536, 28), jnp.uint16), f32,
+                           65536).rows == T
 
 
 # --- bundled columns: unpacked once a split, with the child's totals -----
@@ -260,3 +283,38 @@ def test_invalid_step_leaves_the_store_and_the_order_as_they_were():
     np.testing.assert_allclose(np.asarray(moved.hists[small]), want,
                                rtol=1e-5, atol=1e-4)
     assert int(moved.seg_count[small]) == len(rows)
+
+
+# --- the counter of the rows that went through the kernel ----------------
+
+def test_kernel_rows_are_counted_where_the_tiles_are_the_kernels(
+        timer_on, monkeypatch):
+    """``grow/hist_rows_kernel`` beside ``grow/hist_rows_bucketed``: the
+    root's rows and the smaller children's tiles of a tree whose passes
+    take the Pallas kernel; still on the CPU, whose passes scatter."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.obs.registry import registry
+    rng = np.random.RandomState(3)
+    X = rng.randn(3000, 6)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 7, "verbosity": -1,
+              "min_data_in_leaf": 5, "tree_learner": "data",
+              "mesh_shape": "data=1"}
+    bst = lgb.Booster(params=params, train_set=lgb.Dataset(
+        X, label=y, params=dict(params)).construct())
+    learner = bst.inner.learner
+    names = ("grow/hist_rows_kernel", "grow/hist_rows_bucketed")
+
+    def moved():
+        before = [registry.count(n) for n in names]
+        bst.update()
+        return [registry.count(n) - b for n, b in zip(names, before)]
+
+    kernel, bucketed = moved()
+    assert kernel == 0 and bucketed > 0
+    tiles = learner._data_tiles()
+    assert not tiles.kernel
+    monkeypatch.setattr(learner, "_data_tiles",
+                        lambda: tiles._replace(kernel=True))
+    kernel, bucketed = moved()
+    assert kernel == learner.R + bucketed

@@ -81,6 +81,15 @@ def _block_specs(node: Optional[ast.AST], ctx) -> List[ast.Call]:
     return out
 
 
+def _spec_count(node: Optional[ast.AST]) -> int:
+    """How many specs an in_specs expression holds: the elements of a
+    literal list/tuple, whatever each is (a BlockSpec call, or a name
+    bound to one earlier); 0 where the expression is not literal."""
+    if isinstance(node, (ast.List, ast.Tuple)):
+        return len(node.elts)
+    return 1 if isinstance(node, ast.Call) else 0
+
+
 def _spec_shape_rank(spec: ast.Call) -> Optional[int]:
     if spec.args and isinstance(spec.args[0], (ast.Tuple, ast.List)):
         return len(spec.args[0].elts)
@@ -128,6 +137,7 @@ class PallasInvariantsRule(Rule):
         grid_rank = len(grid.elts) if isinstance(
             grid, (ast.Tuple, ast.List)) else None
         in_specs = _block_specs(_kw(call, "in_specs"), ctx)
+        n_in = _spec_count(_kw(call, "in_specs"))
         out_specs = _block_specs(_kw(call, "out_specs"), ctx)
         for spec in in_specs + out_specs:
             rank = _spec_shape_rank(spec)
@@ -163,16 +173,16 @@ class PallasInvariantsRule(Rule):
                     "%d — the output BlockSpec must match the output "
                     "array's rank" % (got, want)))
         # immediate invocation arity: pallas_call(...)(a, b)
-        if invocation is not None and in_specs:
+        if invocation is not None and n_in:
             n_args = len(invocation.args)
             if not any(isinstance(a, ast.Starred)
                        for a in invocation.args) \
-                    and n_args != len(in_specs):
+                    and n_args != n_in:
                 out.append(self.finding(
                     ctx, invocation,
                     "pallas_call declares %d in_specs but is invoked "
                     "with %d array(s) — every operand needs exactly "
-                    "one BlockSpec" % (len(in_specs), n_args)))
+                    "one BlockSpec" % (n_in, n_args)))
         # kernel arity (name or functools.partial(name, bound...))
         names: Set[str] = set()
         if call.args:
@@ -189,12 +199,12 @@ class PallasInvariantsRule(Rule):
                 fi = ctx.project.resolve_symbol(ctx, k.id) \
                     if ctx.project else None
                 scratch = _kw(call, "scratch_shapes")
-                if fi is not None and in_specs and isinstance(
+                if fi is not None and n_in and isinstance(
                         scratch, (type(None), ast.List, ast.Tuple)):
                     n_out = 1 if len(out_specs) <= 1 else len(out_specs)
                     n_scratch = len(scratch.elts) if scratch else 0
                     n_refs = len(fi.params) - bound
-                    want = len(in_specs) + n_out + n_scratch
+                    want = n_in + n_out + n_scratch
                     if n_refs != want:
                         out.append(self.finding(
                             ctx, call,
@@ -203,7 +213,7 @@ class PallasInvariantsRule(Rule):
                             "supplies %d (in_specs=%d + outputs=%d + "
                             "scratch=%d)"
                             % (fi.qualname, n_refs, bound, want,
-                               len(in_specs), n_out, n_scratch)))
+                               n_in, n_out, n_scratch)))
         return names
 
     # -- kernel bodies -------------------------------------------------
