@@ -333,10 +333,10 @@ class Booster:
 
     def _eval_inner(self, valid_idx: Optional[int], name: str,
                     feval=None) -> List[Tuple]:
+        from .boosting.gbdt import compute_metrics, fetch_scores
         inner = self.inner
-        out = []
         if valid_idx is None:
-            score = np.asarray(inner.train_score, dtype=np.float64)
+            score = fetch_scores(inner.train_score)
             metrics = inner.train_metrics
             if not metrics:
                 # build lazily so eval_train works without
@@ -356,9 +356,7 @@ class Booster:
             metrics = vd.metrics
             label_holder = vd.dataset
         sq = score[:, 0] if inner.num_tree_per_iteration == 1 else score
-        for m in metrics:
-            for mname, v in zip(m.name, m.eval(sq, inner.objective)):
-                out.append((name, mname, v, m.factor_to_bigger_better > 0))
+        out = compute_metrics(name, metrics, sq, inner.objective)
         if feval is not None:
             for fe in (feval if isinstance(feval, (list, tuple))
                        else [feval]):

@@ -58,19 +58,40 @@ _K_MIN_SCORE = -np.inf
 # transfer_guard sanitizer in tests/test_jaxlint.py pins this). The
 # class index is a TRACED scalar (utils/scalars.dev_i32), so one
 # compile serves every class — a static index would compile per class
-# and trip the retrace warning past 32 classes.
-_take_col = obs_compile.instrument_jit(
-    "gbdt.take_col", lambda m, k: m[:, k])
+# and trip the retrace warning past 32 classes. jax names a program
+# after its function and a device trace shows no other name, so each
+# body is a function named as it is registered (``gbdt.score_delta``
+# runs as ``jit_gbdt_score_delta``).
+def gbdt_take_col(m, k):
+    return m[:, k]
+
+
+def gbdt_score_delta(score, leaf_values, leaf_of_row, k):
+    return score.at[:, k].add(leaf_values[leaf_of_row])
+
+
+def gbdt_score_add_col(score, delta, k):
+    return score.at[:, k].add(delta)
+
+
+def gbdt_valid_score_add(score, delta, k):
+    # the validation side of the score update, a program of its own name
+    return gbdt_score_add_col(score, delta, k)
+
+
+def gbdt_score_set_col(score, col, k):
+    return score.at[:, k].set(col)
+
+
+_take_col = obs_compile.instrument_jit("gbdt.take_col", gbdt_take_col)
 _apply_leaf_delta = obs_compile.instrument_jit(
-    "gbdt.score_delta",
-    lambda score, leaf_values, leaf_of_row, k:
-        score.at[:, k].add(leaf_values[leaf_of_row]))
+    "gbdt.score_delta", gbdt_score_delta)
 _add_score_col = obs_compile.instrument_jit(
-    "gbdt.score_add_col",
-    lambda score, delta, k: score.at[:, k].add(delta))
+    "gbdt.score_add_col", gbdt_score_add_col)
+_add_valid_score_col = obs_compile.instrument_jit(
+    "gbdt.valid_score_add", gbdt_valid_score_add)
 _set_score_col = obs_compile.instrument_jit(
-    "gbdt.score_set_col",
-    lambda score, col, k: score.at[:, k].set(col))
+    "gbdt.score_set_col", gbdt_score_set_col)
 
 
 def eval_hoist_due(count: int, last_count: int, eval_k: int,
@@ -103,18 +124,40 @@ def run_instrumented_eval(iter_idx: int, compute):
     return out
 
 
+def fetch_scores(scores_dev) -> np.ndarray:
+    """Host f64 copy of device scores for the metrics, which evaluate
+    on the host. ``gbdt::eval_fetch`` is the wait for the programs
+    that still write them (the validation walk of the newest tree) and
+    the copy; ``gbdt::eval_compute`` below is host arithmetic during
+    which the device idles. Both lie inside ``gbdt::eval_metrics``."""
+    with obs.scope("gbdt::eval_fetch"):
+        return np.asarray(scores_dev, dtype=np.float64)
+
+
+def compute_metrics(dataset_name: str, metrics: Sequence[Metric], score,
+                    objective) -> List[Tuple[str, str, float, bool]]:
+    """(dataset_name, metric_name, value, is_bigger_better) of every
+    metric over host scores, under ``gbdt::eval_compute``."""
+    with obs.scope("gbdt::eval_compute"):
+        return [(dataset_name, name, v, m.factor_to_bigger_better > 0)
+                for m in metrics
+                for name, v in zip(m.name, m.eval(score, objective))]
+
+
 def _device_tree_outputs(tree: Tree, bins_dev, dataset: BinnedDataset,
                          bin_meta):
-    """Device [n] f32 per-row output of one tree over the dataset's
-    binned rows via the vectorized traversal (ops/predict.py);
-    linear-leaf trees fall back to host raw-feature prediction. Returns
-    None for zero-valued stumps. Shared by train-side (DART/rollback) and
-    valid-side scoring."""
+    """``(delta, trips)``: the device [n] f32 per-row output of one tree
+    over the dataset's binned rows via the vectorized traversal
+    (ops/predict.py) and the hops that walk ran for every row;
+    linear-leaf trees fall back to host raw-feature prediction and
+    stumps are a constant (``trips`` None: no walk), a zero-valued
+    stump has no ``delta`` either. Shared by train-side (DART/rollback)
+    and valid-side scoring."""
     if tree.is_linear and dataset.raw_data is not None:
         from ..models.linear import linear_predict
         leaf = tree.predict_by_bin(dataset.feature_bins(), *bin_meta)
         return jnp.asarray(linear_predict(
-            tree, dataset.raw_data, leaf).astype(np.float32))
+            tree, dataset.raw_data, leaf).astype(np.float32)), None
     from ..ops.predict import build_device_tree, tree_output_on_device
     if dataset.bundle is not None:
         dtree = build_device_tree(
@@ -126,8 +169,8 @@ def _device_tree_outputs(tree: Tree, bins_dev, dataset: BinnedDataset,
     if dtree is None:  # stump: constant value
         if tree.num_leaves >= 1 and tree.leaf_value[0] != 0.0:
             return jnp.full((dataset.num_data,),
-                            np.float32(tree.leaf_value[0]))
-        return None
+                            np.float32(tree.leaf_value[0])), None
+        return None, None
     return tree_output_on_device(bins_dev, dtree)
 
 
@@ -154,21 +197,43 @@ class ValidData:
     @property
     def scores(self) -> np.ndarray:
         """Host f64 snapshot (metrics evaluate on host)."""
-        return np.asarray(self.scores_dev, dtype=np.float64)
+        return fetch_scores(self.scores_dev)
 
     def add_tree(self, tree: Tree, class_id: int, bin_meta,
                  sign: float = 1.0) -> None:
-        delta = self._tree_outputs(tree, bin_meta)
+        delta, trips = _device_tree_outputs(tree, self.bins_dev,
+                                            self.dataset, bin_meta)
         if delta is None:
             return
+        if obs.enabled and trips:
+            self._count_walk(tree, trips)
         if sign != 1.0:
             delta = delta * np.float32(sign)
-        self.scores_dev = self.scores_dev.at[:, class_id].add(delta)
+        self.scores_dev = _add_valid_score_col(self.scores_dev, delta,
+                                               dev_i32(class_id))
+
+    def _count_walk(self, tree: Tree, trips: int) -> None:
+        """``valid/walk_hops_run``: rows times ``trips``, the hops the
+        lockstep loop of ``ops/predict.py`` ran for every row, as the
+        walk reports them; ``valid/walk_hops_needed``: rows times the
+        tree's mean leaf depth weighted by ``leaf_count``, the hops the
+        rows need if they fall into the leaves as the training rows
+        (those in the bag) did. An estimate: counting these rows' own
+        leaves would take a device pass that an untraced run does not
+        make."""
+        rows = self.dataset.num_data
+        depth = tree.leaf_depth[:tree.num_leaves]
+        weight = np.asarray(tree.leaf_count[:tree.num_leaves],
+                            dtype=np.float64)
+        mean_depth = np.average(depth, weights=weight) if weight.any() \
+            else depth.mean()
+        obs.inc("valid/walk_hops_run", rows * trips)
+        obs.inc("valid/walk_hops_needed", int(round(rows * mean_depth)))
 
     def _tree_outputs(self, tree: Tree, bin_meta):
         """Device [n] f32 output of one tree over this valid set."""
         return _device_tree_outputs(tree, self.bins_dev, self.dataset,
-                                    bin_meta)
+                                    bin_meta)[0]
 
     def add_const(self, val: float, class_id: int) -> None:
         self.scores_dev = self.scores_dev.at[:, class_id].add(
@@ -775,7 +840,7 @@ class GBDT:
         itself reuses the learner's partition in _update_score)."""
         return _device_tree_outputs(
             tree, self._train_bins_device(), self.train_data,
-            self._bin_meta)
+            self._bin_meta)[0]
 
     # ------------------------------------------------------------------
     def eval_metrics(self) -> List[Tuple[str, str, float, bool]]:
@@ -786,23 +851,17 @@ class GBDT:
 
     def _eval_metrics_inner(self) -> List[Tuple[str, str, float, bool]]:
         out = []
+        sets = [("valid_%d" % i, vd.metrics, vd.scores_dev)
+                for i, vd in enumerate(self.valid_data)]
         if self.train_metrics:
-            score = np.asarray(self.train_score, dtype=np.float64)
-            score = score[:, 0] if self.num_tree_per_iteration == 1 \
-                else score
-            for m in self.train_metrics:
-                for name, v in zip(m.name,
-                                   m.eval(score, self.objective)):
-                    out.append(("training", name, v,
-                                m.factor_to_bigger_better > 0))
-        for i, vd in enumerate(self.valid_data):
-            score = vd.scores[:, 0] \
-                if self.num_tree_per_iteration == 1 else vd.scores
-            for m in vd.metrics:
-                for name, v in zip(m.name,
-                                   m.eval(score, self.objective)):
-                    out.append(("valid_%d" % i, name, v,
-                                m.factor_to_bigger_better > 0))
+            sets.insert(0, ("training", self.train_metrics,
+                            self.train_score))
+        for name, metrics, scores_dev in sets:
+            score = fetch_scores(scores_dev)
+            if self.num_tree_per_iteration == 1:
+                score = score[:, 0]
+            out.extend(compute_metrics(name, metrics, score,
+                                       self.objective))
         return out
 
     def _check_early_stopping(self, eval_list) -> bool:

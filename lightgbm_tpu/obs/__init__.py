@@ -5,8 +5,7 @@ The reference builds per-stage observability directly into the trainer
 stage, include/LightGBM/utils/common.h:973,1037, aggregated table printed
 at exit under -DUSE_TIMETAG). This package is the TPU-native superset:
 
-- :mod:`registry`  — counters, gauges, and the stage timer (absorbs the
-  old ``utils/timer.py``; scopes still open
+- :mod:`registry`  — counters, gauges, and the stage timer (scopes open
   ``jax.profiler.TraceAnnotation`` ranges so stages are attributable in
   TensorBoard/perfetto device traces).
 - :mod:`events`    — a JSON-lines event sink (``LIGHTGBM_TPU_EVENT_LOG``

@@ -299,6 +299,12 @@ def instrument_jit(name: str, fun: Callable, **jit_kwargs) -> Callable:
     learner/serving jit site: same call signature, same donation /
     static-argument semantics (positional passthrough).
 
+    jax names the program after ``fun`` (``_tree_impl`` runs as
+    ``jit__tree_impl``), and that name is all a device trace shows of
+    it: hand in a named function, since every lambda runs as
+    ``jit__lambda`` (tests/test_device_scopes.py holds the package to
+    it).
+
     Hot-path cost: with capture off, two env lookups per dispatch; with
     capture on, one thread-local set/restore per dispatch — the
     expensive lowering runs ONLY on calls that actually compiled (a

@@ -1,6 +1,6 @@
 """Metrics registry: counters, gauges, stage timers.
 
-Absorbs the old ``utils/timer.py`` ``Timer`` (reference:
+The stage timer is the reference's ``Timer`` (reference:
 ``Common::Timer``/``FunctionTimer``, include/LightGBM/utils/common.h:973,
 1037 — RAII scopes around every pipeline stage, aggregated table printed
 at exit when built with USE_TIMETAG). The TPU twist: enabled scopes also

@@ -150,7 +150,10 @@ def _build_bundled_device_tree(tree: Tree, bin_meta, B: int,
 
 
 def _traverse_body(bins, dt: DeviceTree, trips: int) -> jnp.ndarray:
-    """Lockstep binned traversal: [n, F] uint bins → [n] i32 leaf ids."""
+    """Lockstep binned traversal: [n, F] uint bins → [n] i32 leaf ids.
+    Every row runs all ``trips`` hops, whatever the depth of its leaf
+    (``valid/walk_hops_run`` against ``valid/walk_hops_needed``,
+    boosting/gbdt.py)."""
     n = bins.shape[0]
 
     def body(_, node):
@@ -176,10 +179,12 @@ _traverse = obs_compile.instrument_jit(
     "predict.traverse", _traverse_body, static_argnames=("trips",))
 
 
-def predict_leaf_on_device(bins_dev: jnp.ndarray,
-                           dtree: DeviceTree) -> jnp.ndarray:
-    """[n] leaf index of every binned row (device)."""
-    return _traverse(bins_dev, dtree, _next_pow2(dtree.depth))
+def predict_leaf_on_device(bins_dev: jnp.ndarray, dtree: DeviceTree):
+    """``(leaf, trips)``: [n] leaf index of every binned row (device)
+    and the hops the walk ran for each of them, a power of two so that
+    trees of many depths share few compiled programs."""
+    trips = _next_pow2(dtree.depth)
+    return _traverse(bins_dev, dtree, trips), trips
 
 
 # ---------------------------------------------------------------------------
@@ -469,8 +474,8 @@ _gather_leaf_values = obs_compile.instrument_jit(
     "predict.gather_leaf", _gather_leaf_values_body)
 
 
-def tree_output_on_device(bins_dev: jnp.ndarray,
-                          dtree: DeviceTree) -> jnp.ndarray:
-    """[n] f32 per-row output of one tree over binned rows (device)."""
-    leaf = predict_leaf_on_device(bins_dev, dtree)
-    return _gather_leaf_values(dtree.leaf_value, leaf)
+def tree_output_on_device(bins_dev: jnp.ndarray, dtree: DeviceTree):
+    """``(output, trips)``: [n] f32 per-row output of one tree over
+    binned rows (device) and the hops its walk ran."""
+    leaf, trips = predict_leaf_on_device(bins_dev, dtree)
+    return _gather_leaf_values(dtree.leaf_value, leaf), trips

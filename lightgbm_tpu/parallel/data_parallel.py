@@ -570,10 +570,10 @@ class DataParallelTreeLearner(CapabilityMixin):
         # (extra_trees is ignored under intermediate monotone — serial
         # learner contract, _mono_root in treelearner/serial.py)
         if self._mono_root_fn is None:
+            def mesh_mono_root(b, g, f, r, q):
+                return self._root_impl_opts(b, g, f, r, False, q)
             self._mono_root_fn = obs_compile.instrument_jit(
-                "mesh.mono_root",
-                lambda b, g, f, r, q: self._root_impl_opts(b, g, f, r,
-                                                           False, q))
+                "mesh.mono_root", mesh_mono_root)
         return self._mono_root_fn(self.bins, gh, feature_mask,
                                   jnp.int32(rand_seed), self._qscale)
 

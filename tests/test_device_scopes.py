@@ -499,3 +499,182 @@ def test_partition_row_counters_stay_still_while_the_timer_is_off():
     before = _partition_rows()
     _grow_trees_on_one_device(1)
     assert _partition_rows() == before
+
+
+# --- (f) the rest of the iteration: program names, hops, spans ----------
+
+def _module_name(fn, *args) -> str:
+    return re.search(r"module @(\S+)", fn.lower(*args).as_text()).group(1)
+
+
+def _score_program(name):
+    """A jitted program of ``boosting/gbdt.py``'s score plumbing or of the
+    validation walk with abstract arguments to lower it by."""
+    from lightgbm_tpu.boosting import gbdt
+    from lightgbm_tpu.ops import predict
+    from lightgbm_tpu.utils.scalars import dev_i32
+    sds = jax.ShapeDtypeStruct
+    score, col = sds((100, 1), jnp.float32), sds((100,), jnp.float32)
+    rows_i32, k = sds((100,), jnp.int32), dev_i32(0)
+    if name == "predict.traverse":
+        inner = _small_booster(np.random.RandomState(0).randn(300, 4)).inner
+        dtree = predict.build_device_tree(inner.models[0], inner._bin_meta,
+                                          64)
+        return predict._traverse, (jnp.asarray(inner.train_data.bins),
+                                   dtree, 4)
+    return {
+        "gbdt.take_col": (gbdt._take_col, (score, k)),
+        "gbdt.score_delta": (gbdt._apply_leaf_delta,
+                             (score, sds((15,), jnp.float32), rows_i32, k)),
+        "gbdt.score_add_col": (gbdt._add_score_col, (score, col, k)),
+        "gbdt.valid_score_add": (gbdt._add_valid_score_col,
+                                 (score, col, k)),
+        "gbdt.score_set_col": (gbdt._set_score_col, (score, col, k)),
+        "predict.gather_leaf": (predict._gather_leaf_values,
+                                (sds((16,), jnp.float32), rows_i32)),
+    }[name]
+
+
+def _small_booster(X, rounds=1, **kw):
+    import lightgbm_tpu as lgb
+    y = (X[:, 0] * X[:, 1] + X[:, 2] > 0).astype(float)
+    return lgb.train({"objective": "binary", "verbose": -1,
+                      "num_leaves": 15, "max_bin": 63, "metric": "auc"},
+                     lgb.Dataset(X, label=y), num_boost_round=rounds, **kw)
+
+
+def _gradient_program():
+    obj = _small_booster(np.random.RandomState(1).randn(300, 4)) \
+        .inner.objective
+    return type(obj)._grads, (obj, jax.ShapeDtypeStruct((300,), jnp.float32),
+                              obj.label_sign, obj.label_weight, obj.weights)
+
+
+@pytest.mark.parametrize("name", [
+    "gbdt.take_col", "gbdt.score_delta", "gbdt.score_add_col",
+    "gbdt.valid_score_add", "gbdt.score_set_col"])
+def test_a_score_program_runs_under_its_registered_name(name):
+    """jax names a program after its function, and the device trace shows
+    nothing else of it: as lambdas all five ran as ``jit__lambda``."""
+    fn, args = _score_program(name)
+    assert _module_name(fn, *args) == "jit_" + name.replace(".", "_")
+
+
+def test_the_mesh_learner_s_monotone_root_runs_under_its_registered_name():
+    cfg, ds = _dataset("auto")
+    lrn = DataParallelTreeLearner(cfg, ds, make_mesh(1))
+    gh = jnp.zeros((lrn.R, 4), jnp.float32)
+    lrn._mono_root(gh, lrn._sample_features(), 1)
+    assert _module_name(lrn._mono_root_fn, lrn.bins, gh,
+                        lrn._sample_features(), jnp.int32(1),
+                        lrn._qs_ones) == "jit_mesh_mono_root"
+
+
+@pytest.mark.parametrize("name,module", [
+    ("predict.traverse", "jit__traverse_body"),
+    ("predict.gather_leaf", "jit__gather_leaf_values_body"),
+    ("obj.binary.grads", "jit__grads"),
+    ("test.named", "jit_scopes_named_fn")])
+def test_a_named_function_keeps_its_own_name(name, module):
+    """The names the benchmark's readers and the ledger know."""
+    if name == "test.named":
+        def scopes_named_fn(x):
+            return x + 1
+        fn = obs_compile.instrument_jit(name, scopes_named_fn)
+        args = (jax.ShapeDtypeStruct((3,), jnp.float32),)
+    elif name == "obj.binary.grads":
+        fn, args = _gradient_program()
+    else:
+        fn, args = _score_program(name)
+    assert _module_name(fn, *args) == module
+
+
+def test_no_instrument_jit_site_is_a_lambda():
+    """One rule names every program: its function's name. A lambda would
+    run as ``jit__lambda`` beside every other one."""
+    import ast
+    import pathlib
+    import lightgbm_tpu
+    sites = []
+    for path in pathlib.Path(lightgbm_tpu.__file__).parent.rglob("*.py"):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Call) and getattr(
+                    node.func, "attr", getattr(node.func, "id", None)) in (
+                    "instrument_jit", "instrument_jit_method"):
+                sites.append((path.name, node.lineno, any(
+                    isinstance(arg, ast.Lambda) for arg in node.args)))
+    assert len(sites) > 50      # the scan finds the package's sites
+    assert [site for site in sites if site[2]] == []
+
+
+VALID_COUNTERS = ("valid/walk_hops_run", "valid/walk_hops_needed")
+VALID_SPANS = ("gbdt::eval_fetch", "gbdt::eval_compute")
+
+
+def _train_with_validation(rounds=3):
+    import lightgbm_tpu as lgb
+    rng = np.random.RandomState(5)
+    X, Xv = rng.randn(6000, 6), rng.randn(1500, 6)
+    train = lgb.Dataset(X, label=(X[:, 0] * X[:, 1] + X[:, 2] > 0)
+                        .astype(float))
+    valid = lgb.Dataset(Xv, label=(Xv[:, 0] * Xv[:, 1] + Xv[:, 2] > 0)
+                        .astype(float), reference=train)
+    return lgb.train({"objective": "binary", "verbose": -1, "metric": "auc",
+                      "num_leaves": 31, "max_bin": 63}, train,
+                     num_boost_round=rounds, valid_sets=[valid])
+
+
+def _valid_counters():
+    return np.asarray([registry.count(name) for name in VALID_COUNTERS])
+
+
+def test_walk_counters_follow_the_trees(timer_on, monkeypatch):
+    """``walk_hops_run`` is what the traversal was handed as its static
+    ``trips``, for the validation rows, whatever rule chose them."""
+    from lightgbm_tpu.ops import predict
+    walks, traverse = [], predict._traverse
+
+    def spy(bins, dtree, trips):
+        walks.append((bins.shape[0], trips))
+        return traverse(bins, dtree, trips)
+    monkeypatch.setattr(predict, "_traverse", spy)
+    before = _valid_counters()
+    trees = _train_with_validation().inner.models
+    assert len(trees) == 3 and [rows for rows, _ in walks] == [1500] * 3
+    hops_run, hops_needed = _valid_counters() - before
+    assert hops_run == sum(rows * trips for rows, trips in walks)
+    assert hops_needed == sum(int(round(1500 * float(
+        (t.leaf_depth[:t.num_leaves] * t.leaf_count[:t.num_leaves]).sum()
+        / t.leaf_count[:t.num_leaves].sum()))) for t in trees)
+    # no row needs more hops than the deepest leaf, which the loop covers
+    assert 1500 * 3 < hops_needed <= hops_run
+
+
+def test_walk_counters_stay_still_while_the_timer_is_off():
+    assert not registry.timer.enabled
+    before = _valid_counters()
+    _train_with_validation(1)
+    assert (_valid_counters() == before).all()
+
+
+def test_walk_counters_stay_still_under_a_walk_of_no_iteration(timer_on):
+    """The benchmark warms the walk's programs through ``_tree_outputs``
+    at every hop count: not a tree of the run."""
+    bst = _train_with_validation(1)
+    before = _valid_counters()
+    inner = bst.inner
+    out = inner.valid_data[0]._tree_outputs(inner.models[-1],
+                                            inner._bin_meta)
+    assert out.shape == (1500,)
+    assert (_valid_counters() == before).all()
+
+
+@pytest.mark.parametrize("span", VALID_SPANS)
+def test_span_is_recorded_once_an_iteration(timer_on, span):
+    calls0 = {s: timer_on.counts.get(s, 0)
+              for s in VALID_SPANS + ("gbdt::eval_metrics",)}
+    _train_with_validation(3)
+    assert timer_on.counts[span] - calls0[span] == 3
+    # the two parts lie inside the whole, which is entered as often
+    assert timer_on.counts["gbdt::eval_metrics"] \
+        - calls0["gbdt::eval_metrics"] == 3

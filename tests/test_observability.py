@@ -76,10 +76,24 @@ def test_stage_timer_disabled_records_nothing():
     assert "nope" not in t.counts
 
 
-def test_timer_shim_is_registry_timer():
-    # utils/timer.py callers and obs consumers must observe ONE timer
-    from lightgbm_tpu.utils import timer
-    assert timer.global_timer is registry.timer
+def test_the_process_has_one_stage_timer():
+    # the scopes the package opens (obs.scope) record into the registry's
+    # timer, the one the learners' obs.enabled gates and the benchmark's
+    # enable_spans switch
+    from lightgbm_tpu import obs
+    assert not registry.enabled
+    with obs.scope("test::one_timer"):
+        pass
+    assert "test::one_timer" not in registry.timer.counts
+    registry.enable()
+    try:
+        assert registry.timer.enabled
+        with obs.scope("test::one_timer"):
+            pass
+    finally:
+        registry.disable()
+    assert registry.timer.counts["test::one_timer"] == 1
+    assert not registry.timer.enabled
 
 
 def test_registry_counters_gauges_snapshot():
