@@ -34,6 +34,13 @@ def counter(name: str):
     return registry.counters.get(name)
 
 
+def counters() -> dict:
+    """Every counter of the registry as it stands; a runner notes them
+    when the window opens, and a reader takes what they moved by since."""
+    from lightgbm_tpu.obs.registry import registry
+    return dict(registry.counters)
+
+
 def counts_cache_outcomes() -> bool:
     """Whether this program counts what the persistent compilation cache
     did (``jit_cache_hits``/``jit_cache_misses``), so that a counter it

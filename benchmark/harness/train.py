@@ -3,9 +3,10 @@
 Set-up builds one booster, drives it through its first ``checked_steps``
 iterations with ``Booster.update`` (the first compiles) and keeps the
 training rows' scores after each; the window goes on with that same booster
-and the same call. Once the window has closed and the peak memory is read,
-the booster is freed and the plain reference follows those first steps from
-the same table.
+and the same call (``drive_window``: by the clock, or in a traced run for
+``TRACED_ITERATIONS``). Once the window has closed and the peak memory is
+read, the booster is freed and the plain reference follows those first steps
+from the same table.
 """
 from __future__ import annotations
 
@@ -16,7 +17,7 @@ import shutil
 import sys
 import time
 
-from . import check, program
+from . import check, program, program_obs
 from .spec import CHECKOUT
 
 LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
@@ -25,6 +26,9 @@ TRACE_DIR = os.path.join(CHECKOUT, ".bench_trace")
 # faults of "How correct is decided", run by hand and by the tests
 VARIANTS = ("quantized", "ref-bf16", "ref-half", "ref-frozen")
 SPANS = r"^(bench|tree|gbdt|io|obj|jit)::"
+# a traced run's window: this many iterations, so that its trace holds the
+# same trees, and takes as long to stop and read, whatever the program's speed
+TRACED_ITERATIONS = 6
 
 
 class Run:
@@ -71,6 +75,29 @@ def start_trace(name: str) -> str:
     options.host_tracer_level = 2
     jax.profiler.start_trace(log_dir, profiler_options=options)
     return log_dir
+
+
+def drive_window(prog, seconds: float, trace: bool) -> tuple:
+    """``(updates issued, seconds)`` of the window: ``prog.update()`` under
+    ``bench::iteration`` until ``seconds`` have passed, and in a traced run
+    no more than ``TRACED_ITERATIONS`` times; the clock stops when the
+    scores of the last update are ready."""
+    import jax
+    attempted = 0
+    t0 = time.perf_counter()
+    if trace:
+        while (attempted < TRACED_ITERATIONS
+               and time.perf_counter() - t0 < seconds):
+            with jax.profiler.TraceAnnotation("bench::iteration"):
+                prog.update()
+            attempted += 1
+    else:
+        while time.perf_counter() - t0 < seconds:
+            with jax.profiler.TraceAnnotation("bench::iteration"):
+                prog.update()
+            attempted += 1
+    prog.wait()
+    return attempted, time.perf_counter() - t0
 
 
 def _reference_scores(reference, variant, X, y, params, steps, X_hold):
@@ -142,19 +169,16 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, device,
     this.end_to_end["setup_s"] = time.perf_counter() - t_process
 
     counts0 = program.trace_counts()
+    # set-up's trees are counted too: a reader of the counters takes what
+    # they were when the window opened from them
+    this.counters_at_window = program_obs.counters()
     attempted = 0
     log_dir = None
     if prog is not None and seconds > 0:
         if trace:
             log_dir = start_trace(cell["name"])
         compiles.listening = True
-        t0 = time.perf_counter()
-        while time.perf_counter() - t0 < seconds:
-            with jax.profiler.TraceAnnotation("bench::iteration"):
-                prog.update()
-            attempted += 1
-        prog.wait()
-        this.window_s = time.perf_counter() - t0
+        attempted, this.window_s = drive_window(prog, seconds, trace)
         compiles.listening = False
         if trace:
             jax.profiler.stop_trace()

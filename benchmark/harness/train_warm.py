@@ -24,7 +24,8 @@ import sys
 import time
 
 from . import check, program, program_obs
-from .train import SPANS, CompileCounter, Run, start_trace  # noqa: F401
+from .train import SPANS  # noqa: F401  (the runner's, as kind train's)
+from .train import CompileCounter, Run, drive_window, start_trace
 
 # variants of a run that the driver never asks for: a control or a fault in
 # the program's place over the checked steps, as (parameters changed,
@@ -39,9 +40,6 @@ CONTROLS = {
     "ref-frozen": ({}, {"freeze_scores": True}),
 }
 VARIANTS = tuple(CONTROLS)
-WINDOW_COUNTERS = ("sample/goss_trees", "sample/rows_in_bag",
-                   "grow/hist_rows_in_bag", "grow/hist_rows_needed",
-                   "grow/hist_rows_bucketed")
 
 
 def _reference_scores(reference, variant, X, y, params, steps, X_hold,
@@ -112,21 +110,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, device,
     counts0 = program.trace_counts()
     # the warm-up's trees are unsampled ones: a reader of this cell's
     # counters takes what they were when the window opened from them
-    this.counters_at_window = {name: program_obs.counter(name) or 0
-                               for name in WINDOW_COUNTERS}
+    this.counters_at_window = program_obs.counters()
     attempted = 0
     log_dir = None
     if seconds > 0:
         if trace:
             log_dir = start_trace(cell["name"])
         compiles.listening = True
-        t0 = time.perf_counter()
-        while time.perf_counter() - t0 < seconds:
-            with jax.profiler.TraceAnnotation("bench::iteration"):
-                prog.update()
-            attempted += 1
-        prog.wait()
-        this.window_s = time.perf_counter() - t0
+        attempted, this.window_s = drive_window(prog, seconds, trace)
         compiles.listening = False
         if trace:
             jax.profiler.stop_trace()
@@ -153,8 +144,9 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, device,
           "rows histogrammed %s; counters moved in the window by %s" % (
               [c[0] for c in tree_counts],
               [work.histogram_rows(c) for c in tree_counts],
-              {name: (program_obs.counter(name) or 0) - at
-               for name, at in this.counters_at_window.items()}),
+              {name: n - this.counters_at_window.get(name, 0)
+               for name, n in sorted(program_obs.counters().items())
+               if n != this.counters_at_window.get(name, 0)}),
           flush=True)
     prog.free()
     prog = None

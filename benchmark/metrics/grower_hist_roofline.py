@@ -1,5 +1,5 @@
-"""The histogram passes' least time (``trace/work.py``, as ``hist_roofline``
-reckons it; bound by bytes at these shapes) over the device time under the
+"""The histogram passes' least time (``trace/work.py``, from the window's
+own trees; bound by bytes at these shapes) over the device time under the
 ``obs_hist_pallas``/``einsum``/``scatter`` scopes: the histogram's share of
 its roofline whatever implements it."""
 from benchmark.metrics import _stages

@@ -1,12 +1,10 @@
 """Rows the smaller children's histograms had to visit over the rows of
 the buckets they were padded to (the program's ``grow/hist_rows_needed``
-over ``grow/hist_rows_bucketed``, counted while its stage timer is on)."""
-from benchmark.harness import program_obs
+over ``grow/hist_rows_bucketed``, counted while its stage timer is on), as
+they moved since the runner noted them at the window's start."""
+from benchmark.metrics import _goss
 
 
 def read(run):
-    needed = program_obs.counter("grow/hist_rows_needed")
-    bucketed = program_obs.counter("grow/hist_rows_bucketed")
-    if not needed or not bucketed:
-        return None
-    return 100.0 * needed / bucketed
+    return _goss.counters_share(run, "grow/hist_rows_needed",
+                                "grow/hist_rows_bucketed")

@@ -8,7 +8,11 @@ object produced against the plain reference, and prints phase lines and
 then, as the last line of its standard output, one JSON object. With
 ``--trace 0`` its metrics are the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from a profiler trace of the
-window. Without a TPU that ``trace/peaks.json`` knows it exits 3 and prints
+window, which in a traced run of a training cell is the first
+``harness/train.py`` ``TRACED_ITERATIONS`` (6) iterations after set-up, or
+fewer where ``--seconds`` runs out first: the trace then holds the same
+trees, and takes as long to stop and read, whatever the program's speed.
+Without a TPU that ``trace/peaks.json`` knows it exits 3 and prints
 no result line.
 
 A cell is run by the runner of its traffic mix's ``kind``,

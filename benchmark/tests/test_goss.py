@@ -20,6 +20,18 @@ from benchmark.reference import gbdt_goss
 from benchmark.trace import work, work_goss
 
 CELL = "bosch-train-goss"
+# the cell's own per-layer entries, taken by name: later entries list the
+# cell too
+METRICS = (
+    "goss_train_step_mfu_pct", "device_idle_pct.train_goss",
+    "goss_sample_ms_per_iter", "goss_sample_roofline",
+    "goss_grower_ms_per_iter", "goss_hist_ms_per_iter", "goss_hist_roofline",
+    "goss_hist_inbag_pct", "goss_grower_partition_ms_per_iter",
+    "goss_grower_compact_ms_per_iter", "goss_grower_hist_store_ms_per_iter",
+    "goss_grower_split_scan_ms_per_iter", "goss_grower_unscoped_ms_per_iter",
+    "goss_hist_bucket_fill_pct", "goss_setup_bin_s", "goss_setup_compile_s",
+    "goss_setup_find_bins_s", "goss_setup_apply_bins_s",
+    "goss_setup_backend_compile_s", "goss_setup_cache_miss_programs")
 ROWS, FEATURES, HOLD, LEAVES = 20000, 40, 2048, 31
 
 # Readings at this size (CPU, seeds 11 and 2**31 + 11, which read alike),
@@ -211,8 +223,7 @@ def test_cell_is_found_from_appended_entries_in_a_new_checkout(tmp_path):
         "configs/bosch-goss.json", "limits/bosch-train-goss.json",
         "traffic/train-looped-valid-warm10.json", "harness/train_warm.py",
         "reference/gbdt_goss.py", "trace/work_goss.py", "tests/test_goss.py",
-    } | {"metrics/%s.py" % m["name"] for m in here.doc["per_layer"]
-         if m.get("workloads") == [CELL]}
+    } | {"metrics/%s.py" % name for name in METRICS}
     checkout = tmp_path / "checkout"
     shutil.copytree(spec.BENCH_DIR, checkout / "benchmark",
                     ignore=lambda d, names: [
@@ -220,10 +231,9 @@ def test_cell_is_found_from_appended_entries_in_a_new_checkout(tmp_path):
                         or os.path.relpath(os.path.join(d, n),
                                            spec.BENCH_DIR) in added])
     doc = json.loads(json.dumps(here.doc))
-    new = {k: [e for e in doc[k] if CELL in (e.get("name"),
-                                             *e.get("workloads", []))
-               or e.get("name") == "bosch-goss"]
-           for k in ("configs", "workloads", "per_layer")}
+    names = {"configs": {"bosch-goss"}, "workloads": {CELL},
+             "per_layer": set(METRICS)}
+    new = {k: [e for e in doc[k] if e["name"] in names[k]] for k in names}
     assert [len(new[k]) for k in ("configs", "workloads", "per_layer")] \
         == [1, 1, 20]
     # taken out by name, not by position: a later PR appends after them
@@ -251,8 +261,9 @@ def test_cell_is_found_from_appended_entries_in_a_new_checkout(tmp_path):
     assert callable(runner.run) and runner.VARIANTS and runner.SPANS
     assert hasattr(bench.reference(cell), "Reference")
     assert set(bench.end_to_end(CELL)) == {"train_iter_s", "setup_s"}
-    assert bench.per_layer(CELL) == [m["name"] for m in new["per_layer"]]
-    for name in bench.per_layer(CELL):
+    assert sorted(bench.per_layer(CELL)) == sorted(here.per_layer(CELL))
+    assert set(METRICS) <= set(bench.per_layer(CELL))
+    for name in METRICS:
         read = bench.reader(name)
 
         class NoTrace:      # an untraced run: nothing to read, no error
@@ -270,7 +281,7 @@ def test_cell_is_found_from_appended_entries_in_a_new_checkout(tmp_path):
     assert set(after) - set(before) == added
     for other in ("bosch-train", "epsilon-train", "bosch-train-quant"):
         assert bench.per_layer(other) == here.per_layer(other)
-        assert not set(bench.per_layer(other)) & set(bench.per_layer(CELL))
+        assert not set(bench.per_layer(other)) & set(METRICS)
 
 
 # --- trace/work_goss.py against hand counts ------------------------------
@@ -367,7 +378,7 @@ def test_readers_of_the_cell_s_metrics_by_hand(monkeypatch):
         "grow/hist_rows_in_bag": 9250, "grow/hist_rows_bucketed": 22000,
         "grow/hist_rows_needed": 12900}.get)
     bench = spec.Spec()
-    got = {name: bench.reader(name)(run) for name in bench.per_layer(CELL)}
+    got = {name: bench.reader(name)(run) for name in METRICS}
     assert len(got) == 20
     assert got["goss_sample_ms_per_iter"] == pytest.approx(4.0)
     assert got["goss_sample_roofline"] == pytest.approx(

@@ -18,6 +18,12 @@ from benchmark.reference import gbdt_quant
 from benchmark.trace import work, work_quant
 
 CELL = "bosch-train-quant"
+# the cell's own per-layer entries, taken by name: later entries list the
+# cell too
+METRICS = ("quant_train_step_mfu_pct", "device_idle_pct.train_quant",
+           "quant_discretize_ms_per_iter", "quant_grower_ms_per_iter",
+           "quant_hist_ms_per_iter", "quant_hist_roofline",
+           "quant_grower_rest_ms_per_iter")
 ROWS, FEATURES, HOLD, LEAVES, STEPS = 4096, 24, 1024, 15, 3
 
 # Readings at this size (CPU, seeds 11 and 2**31 + 12, which read alike),
@@ -211,8 +217,7 @@ def test_cell_is_found_from_appended_entries_in_a_new_checkout(tmp_path):
         "configs/bosch-quant.json", "limits/bosch-train-quant.json",
         "reference/gbdt_quant.py", "trace/work_quant.py",
         "metrics/_quant.py", "tests/test_quant.py",
-    } | {"metrics/%s.py" % m["name"] for m in here.doc["per_layer"]
-         if m.get("workloads") == [CELL]}
+    } | {"metrics/%s.py" % name for name in METRICS}
     checkout = tmp_path / "checkout"
     shutil.copytree(spec.BENCH_DIR, checkout / "benchmark",
                     ignore=lambda d, names: [
@@ -220,15 +225,14 @@ def test_cell_is_found_from_appended_entries_in_a_new_checkout(tmp_path):
                         or os.path.relpath(os.path.join(d, n),
                                            spec.BENCH_DIR) in added])
     doc = json.loads(json.dumps(here.doc))
-    new = {k: [e for e in doc[k] if CELL in (e.get("name"),
-                                             *e.get("workloads", []))
-               or e.get("name") == "bosch-quant"]
-           for k in ("configs", "workloads", "per_layer")}
+    names = {"configs": {"bosch-quant"}, "workloads": {CELL},
+             "per_layer": set(METRICS)}
+    new = {k: [e for e in doc[k] if e["name"] in names[k]] for k in names}
     assert [len(new[k]) for k in ("configs", "workloads", "per_layer")] \
         == [1, 1, 7]
-    for k, entries in new.items():      # appended: they are the last ones
-        assert doc[k][-len(entries):] == entries
-        doc[k] = doc[k][:-len(entries)]
+    # taken out by name, not by position: later PRs appended after them
+    for k, entries in new.items():
+        doc[k] = [e for e in doc[k] if e not in entries]
     (checkout / "BENCHMARK.json").write_text(json.dumps(doc))
     old = spec.Spec(str(checkout), str(checkout / "benchmark"))
     with pytest.raises(spec.SpecError, match="no workload"):
@@ -246,8 +250,9 @@ def test_cell_is_found_from_appended_entries_in_a_new_checkout(tmp_path):
     assert cell["config"]["reference"] == "gbdt_quant"
     assert hasattr(bench.reference(cell), "Reference")
     assert set(bench.end_to_end(CELL)) == {"train_iter_s", "setup_s"}
-    assert bench.per_layer(CELL) == [m["name"] for m in new["per_layer"]]
-    for name in bench.per_layer(CELL):
+    assert sorted(bench.per_layer(CELL)) == sorted(here.per_layer(CELL))
+    assert set(METRICS) <= set(bench.per_layer(CELL))
+    for name in METRICS:
         read = bench.reader(name)
 
         class NoTrace:      # an untraced run: nothing to read, no error
@@ -359,7 +364,7 @@ def _fake_run(monkeypatch):
 def test_readers_of_the_seven_metrics_by_hand(monkeypatch):
     run = _fake_run(monkeypatch)
     bench = spec.Spec()
-    got = {name: bench.reader(name)(run) for name in bench.per_layer(CELL)}
+    got = {name: bench.reader(name)(run) for name in METRICS}
     assert got["quant_discretize_ms_per_iter"] == pytest.approx(3.0)
     assert got["quant_grower_ms_per_iter"] == pytest.approx(100.0)
     assert got["quant_hist_ms_per_iter"] == pytest.approx(40.0)
