@@ -13,10 +13,13 @@ accumulation as a one-hot contraction that runs on the MXU:
 
 i.e. for each feature a [B, T] @ [T, C] matmul. A `lax.scan` over row
 tiles bounds the materialized one-hot to a few MB so XLA keeps it in
-VMEM; accumulation is f32. ``precision=HIGHEST`` makes the f32 matmul
-exact-enough (bf16x6 passes) — the one-hot factor is exactly
-representable, so error is only the f32 accumulation order, same class
-as the reference's GPU path (single-precision hists, gpu_use_dp=0,
+VMEM; accumulation is f32. The one-hot factor is exact in bf16, so only
+``gh`` needs more than one bf16 MXU pass: the einsum asks for
+``precision=HIGHEST`` (six bf16 passes, both operands split in three),
+the Pallas kernel splits ``gh`` itself into three bf16 pieces whose f32
+sum is ``gh`` exactly (``_bf16_pieces``) and runs one bf16 pass a piece.
+Either way the error is only the f32 accumulation order, same class as
+the reference's GPU path (single-precision hists, gpu_use_dp=0,
 docs/GPU-Performance.rst precedent).
 """
 from __future__ import annotations
@@ -126,11 +129,12 @@ def _pallas_vmem_bytes(FB: int, num_bins: int, C: int, T: int,
     double-buffered: the accumulator's output block always, and where
     the features are split into several blocks (``blocked``) the
     accumulator's input block too. Transients are counted as if each
-    were materialized whole (the compiler keeps most of them in vregs,
-    so the real need is lower — an AOT bisection of
-    ``vmem_limit_bytes`` at the Higgs shape found 576 KiB for f32 and
-    1.8 MiB for int8 against this bound's 3.3 / 11.4 MiB)."""
+    were materialized whole (an AOT bisection of ``vmem_limit_bytes``
+    at the Higgs shape, F = 28, found 5.4 MiB for float32 rows and 5.4
+    MiB for int8 against this bound's 5.8 / 6.1 MiB)."""
     H = _hi_rows(num_bins, gh_itemsize)
+    # float32 rows reach the MXU as three bf16 pieces, int8 rows as one
+    pieces, mxu_itemsize = (3, 2) if gh_itemsize == 4 else (1, gh_itemsize)
 
     def rows_by_T(rows: int, itemsize: int) -> int:
         return _ceil_to(rows, _sublanes(itemsize)) * T * itemsize
@@ -138,15 +142,19 @@ def _pallas_vmem_bytes(FB: int, num_bins: int, C: int, T: int,
     blocks = 2 * (rows_by_T(FB, bins_itemsize) + rows_by_T(C, gh_itemsize))
     acc = (4 if blocked else 2) * FB * H * _ceil_to(16 * C, 128) * 4
     scratch = rows_by_T(FB, 4)                   # int32 copy of the bins
+    # once a grid step, where there are three: the bf16 pieces of gh and
+    # their float32 forms
+    split = (pieces * (rows_by_T(C, 2) + rows_by_T(C, 4))
+             if pieces > 1 else 0)
     # per feature: the bin row and its nibbles, both int32 iotas and
-    # compare masks, the hi one-hot, W's C selected pieces and their
-    # concatenation at 4 bytes, and the matmul's W operand
+    # compare masks, the hi one-hot; per piece W's C selected pieces and
+    # their concatenation at 4 bytes, and the matmul's W operand
     trans = (3 * rows_by_T(1, 4)
              + 2 * (rows_by_T(H, 4) + rows_by_T(16, 4))
-             + rows_by_T(H, gh_itemsize)
-             + 2 * rows_by_T(16 * C, 4)
-             + rows_by_T(16 * C, gh_itemsize))
-    return blocks + acc + scratch + trans
+             + rows_by_T(H, mxu_itemsize)
+             + pieces * (2 * rows_by_T(16 * C, 4)
+                         + rows_by_T(16 * C, mxu_itemsize)))
+    return blocks + acc + scratch + split + trans
 
 
 def _pallas_feature_block(F: int, num_bins: int, C: int, T: int,
@@ -288,6 +296,21 @@ def _tile_histogram(bins_tile: jnp.ndarray, gh_tile: jnp.ndarray,
         preferred_element_type=acc_dtype)
 
 
+def _bf16_pieces(g: jnp.ndarray) -> tuple:
+    """float32 ``g`` as three bfloat16 pieces, each the rounding of what
+    the ones before it leave: ``f32(hi) + f32(mid) + f32(lo) == g`` bit
+    for bit wherever the pieces stay in bf16's normal range (every
+    gradient, hessian and indicator the objectives give), the 24-bit
+    significand ``Precision.HIGHEST`` keeps. A remainder under bf16's
+    smallest normal may lose bits (or be flushed to zero), so the sum
+    is then within 2^-126 of ``g``."""
+    hi = g.astype(jnp.bfloat16)
+    rest = g - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
 def _hist_kernel_body(FB: int, H: int, C: int, acc_in_hbm: bool,
                       bins_ref, gh_ref, acc_ref, out_ref, bins32_ref):
     """Pallas TPU kernel: one grid step accumulates a feature-major
@@ -326,11 +349,17 @@ def _hist_kernel_body(FB: int, H: int, C: int, acc_in_hbm: bool,
     sublane offset into a packed (1- or 2-byte) ref, hence the int32
     copy of the bin tile in scratch.
 
-    The body is dtype-generic: quantized int8 gh rows contract as
-    int8 x int8 MXU matmuls into an int32 accumulator; f32 rows
-    contract at fp32 precision into f32. The int8 ``where`` is taken
-    in int32 and narrowed after — Mosaic cannot move an int32 compare
-    mask onto int8's (32, 128) tiling."""
+    The body branches on the dtype of gh. Quantized int8 rows contract
+    as one int8 x int8 MXU matmul a feature into an int32 accumulator.
+    Float32 rows are split once a grid step into three bf16 pieces
+    (``_bf16_pieces``), and each feature contracts the exact bf16
+    one-hot against each piece's W in one bf16 pass into f32: three
+    passes where ``Precision.HIGHEST`` runs six and splits both
+    operands again for every feature (0.18 against 0.31 ns a row a
+    feature on the v5e at F = 968). ``W`` is selected in 32 bits and
+    narrowed after (exact: the values are already int8 or bf16) —
+    Mosaic cannot move an int32 compare mask onto int8's (32, 128) or
+    bf16's (16, 128) tiling."""
     @pl.when(pl.program_id(0 if acc_in_hbm else 1) == 0)
     def _init():
         if acc_in_hbm:
@@ -342,23 +371,34 @@ def _hist_kernel_body(FB: int, H: int, C: int, acc_in_hbm: bool,
     bins32_ref[...] = bins_ref[...].astype(jnp.int32)
     g = gh_ref[...]                                      # [C, T]
     quantized = jnp.issubdtype(g.dtype, jnp.integer)
-    g_sel = g.astype(jnp.int32) if quantized else g
-    zero = jnp.zeros((), dtype=g_sel.dtype)
+    if quantized:
+        mxu_dtype, pieces = g.dtype, (g.astype(jnp.int32),)
+    else:
+        mxu_dtype = jnp.bfloat16
+        pieces = tuple(p.astype(jnp.float32) for p in _bf16_pieces(g))
+    zero = jnp.zeros((), dtype=pieces[0].dtype)
     iota_hi = jax.lax.broadcasted_iota(jnp.int32, (H, T), 0)
     iota_lo = jax.lax.broadcasted_iota(jnp.int32, (16, T), 0)
 
     def body(f, carry):
         row = bins32_ref[pl.ds(f, 1), :]                 # [1, T]
-        A = ((row >> 4) == iota_hi).astype(g.dtype)      # [H, T]
+        A = ((row >> 4) == iota_hi).astype(mxu_dtype)    # [H, T]
         lo_hit = (row & 15) == iota_lo                   # [16, T]
-        W = jnp.concatenate(
-            [jnp.where(lo_hit, g_sel[c:c + 1, :], zero)
-             for c in range(C)], axis=0).astype(g.dtype)  # [16C, T]
-        out_ref[f] += jax.lax.dot_general(
-            A, W, dimension_numbers=(((1,), (1,)), ((), ())),
-            precision=(None if quantized
-                       else jax.lax.Precision.HIGHEST),
-            preferred_element_type=out_ref.dtype)        # [H, 16C]
+        Ws = [jnp.concatenate(
+            [jnp.where(lo_hit, p[c:c + 1, :], zero)
+             for c in range(C)], axis=0).astype(mxu_dtype)  # [16C, T]
+            for p in pieces]
+
+        def product(W):                                  # [H, 16C]
+            return jax.lax.dot_general(
+                A, W, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=out_ref.dtype)
+
+        if quantized:
+            out_ref[f] += product(Ws[0])
+        else:
+            hi, mid, lo = Ws
+            out_ref[f] += product(hi) + (product(mid) + product(lo))
         return carry
 
     jax.lax.fori_loop(0, FB, body, 0)

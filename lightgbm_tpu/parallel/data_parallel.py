@@ -721,9 +721,11 @@ class DataParallelTreeLearner(CapabilityMixin):
         for them; ``grow/hist_rows_kernel``: the rows of those passes
         and of the root's that went through the Pallas kernel (all or
         none: the static gate of ``ops/histogram.py`` is one for both);
-        ``grow/hist_rows_in_bag``: those of the smaller child's rows
-        that are in the bag (all of them where nothing is sampled), the
-        only ones that carry weight."""
+        ``grow/hist_rows_kernel_pieces``: those of them the kernel took
+        as float32 rows, in three bf16 pieces (all where the rows are
+        not quantized); ``grow/hist_rows_in_bag``: those of the smaller
+        child's rows that are in the bag (all of them where nothing is
+        sampled), the only ones that carry weight."""
         left_total = recs_h.left_total_count[:applied]
         right_total = recs_h.right_total_count[:applied]
         small = np.minimum(left_total, right_total)
@@ -732,6 +734,8 @@ class DataParallelTreeLearner(CapabilityMixin):
         obs.inc("grow/hist_rows_bucketed", bucketed)
         if self._compacts() and self._data_tiles().kernel:
             obs.inc("grow/hist_rows_kernel", self.R + bucketed)
+            if not self._quantized:
+                obs.inc("grow/hist_rows_kernel_pieces", self.R + bucketed)
         # the child _split_step takes as the smaller one (ties: left)
         obs.inc("grow/hist_rows_in_bag", int(np.where(
             left_total <= right_total, recs_h.left_count[:applied],

@@ -7,9 +7,11 @@ import pytest
 
 from lightgbm_tpu.ops.histogram import (PALLAS_ROW_TILE,
                                         PALLAS_ROW_TILE_INT,
+                                        _bf16_pieces,
                                         _from_kernel_layout,
                                         _kernel_zeros,
                                         _pallas_accumulate,
+                                        _pallas_feature_block,
                                         _pallas_histogram_body,
                                         _segment_histogram,
                                         build_histogram,
@@ -140,3 +142,118 @@ def test_pallas_kernel_interpreted_matches_segment_sum(S, F, B, tile):
     np.testing.assert_allclose(
         np.asarray(got_f), np.asarray(_segment_histogram(bins, gh_f, B)),
         rtol=1e-5, atol=1e-4)
+
+
+# ----------------------------------------------------------------------
+# the float32 kernel's three bf16 pieces of gh
+# ----------------------------------------------------------------------
+
+def _values(kind, rng):
+    n = 4096
+    if kind == "gradients":
+        return rng.uniform(-1, 1, n)
+    if kind == "hessians":
+        return rng.uniform(0, 0.25, n) + 2.0 ** -30
+    if kind == "goss-amplified":
+        return 8 * np.concatenate([rng.uniform(-1, 1, n // 2),
+                                   rng.uniform(0, 0.25, n // 2)])
+    if kind == "indicators":
+        return rng.randint(0, 2, n).astype(np.float64)
+    if kind == "tiny-and-huge":
+        mag = np.repeat([1e-30, 1e30], n // 2) * rng.uniform(1, 2, n)
+        return mag * rng.choice([-1, 1], n) * (1 + rng.rand(n) * 2 ** -10)
+    raise ValueError(kind)
+
+
+@pytest.mark.parametrize("kind", ["gradients", "hessians", "goss-amplified",
+                                  "indicators", "tiny-and-huge"])
+def test_bf16_pieces_add_back_to_the_float32_value_bit_for_bit(kind):
+    """hi + mid + lo in float32 is the value itself, for every kind the
+    kernel meets: the split keeps the 24-bit significand HIGHEST keeps."""
+    g = _values(kind, np.random.RandomState(7)).astype(np.float32)
+    g[:2] = 0.0
+    hi, mid, lo = jax.jit(_bf16_pieces)(jnp.asarray(g))
+    assert hi.dtype == mid.dtype == lo.dtype == jnp.bfloat16
+    back = (np.asarray(hi, np.float32) + np.asarray(mid, np.float32)) \
+        + np.asarray(lo, np.float32)
+    assert back.tobytes() == g.tobytes()
+    if kind != "indicators":
+        # the pieces carry bits: a value of more than 8 significant bits
+        # is not hi alone
+        assert np.any(np.asarray(mid, np.float32) != 0)
+
+
+def test_bf16_pieces_of_subnormal_remainders_stay_within_the_bound():
+    """Where a remainder falls under bf16's smallest normal, 2^-126, its
+    last bits cannot be held (and may be flushed): the sum is not bit
+    for bit there, and is within 2^-126 of the value."""
+    rng = np.random.RandomState(8)
+    g = (rng.uniform(1, 2, 4096) * 2.0 ** rng.randint(-126, -100, 4096)
+         * rng.choice([-1, 1], 4096)).astype(np.float32)
+    hi, mid, lo = jax.jit(_bf16_pieces)(jnp.asarray(g))
+    back = (np.asarray(hi, np.float32) + np.asarray(mid, np.float32)) \
+        + np.asarray(lo, np.float32)
+    gap = np.abs(back.astype(np.float64) - g.astype(np.float64))
+    assert gap.max() < 2.0 ** -126
+
+
+@pytest.mark.parametrize("pieces", ["hi+mid+lo", "hi alone"])
+def test_float32_kernel_within_the_accumulation_bound(pieces, monkeypatch):
+    """The interpreted float32 kernel against a float64 histogram: each
+    cell within 2^-16 of its sum of |gh|, the float32 accumulation
+    bound. The control keeps the hi piece alone (a kernel that rounds gh
+    to bf16): it must fail the same bound, so a kernel that drops the
+    pieces cannot pass."""
+    import lightgbm_tpu.ops.histogram as histogram
+    if pieces == "hi alone":
+        split = histogram._bf16_pieces
+
+        def hi_alone(g):
+            hi, mid, lo = split(g)
+            return hi, jnp.zeros_like(mid), jnp.zeros_like(lo)
+        monkeypatch.setattr(histogram, "_bf16_pieces", hi_alone)
+    S, F, B, tile = 1024, 6, 255, 256
+    rng = np.random.RandomState(9)
+    bins = rng.randint(0, B, size=(S, F)).astype(np.uint8)
+    ind = (rng.rand(S) < 0.8).astype(np.float32)
+    gh = np.stack([rng.uniform(-1, 1, S) * ind, rng.uniform(0, 0.25, S) * ind,
+                   ind, np.ones(S)], axis=1).astype(np.float32)
+    got = np.asarray(_pallas_histogram_body(
+        jnp.asarray(bins), jnp.asarray(gh), B, tile, interpret=True),
+        np.float64)
+    want = oracle(bins, gh.astype(np.float64), B)
+    bound = 2.0 ** -16 * oracle(bins, np.abs(gh).astype(np.float64), B)
+    within = bool(np.all(np.abs(got - want) <= bound))
+    assert within == (pieces == "hi+mid+lo")
+
+
+def test_float32_kernel_keeps_every_bit_of_a_lone_row():
+    """Each bin of each feature holds one row, so a cell's sum is the
+    row's value with nothing to round: the interpreted float32 kernel
+    gives it back bit for bit, which it can only do with all three
+    pieces (hi and mid alone keep 16 of the 24 bits)."""
+    S, F, B = 255, 3, 255
+    rng = np.random.RandomState(10)
+    bins = np.stack([rng.permutation(B) for _ in range(F)], 1) \
+        .astype(np.uint8)
+    gh = np.stack([rng.uniform(-1, 1, S), rng.uniform(0, 0.25, S),
+                   8 * rng.uniform(-1, 1, S), np.ones(S)],
+                  axis=1).astype(np.float32)
+    got = np.asarray(_pallas_histogram_body(
+        jnp.asarray(bins), jnp.asarray(gh), B, 256, interpret=True))
+    want = np.zeros((F, B, 4), np.float32)
+    for f in range(F):
+        want[f, bins[:, f]] = gh
+    assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("F,itemsize,tile,block", [
+    (968, 4, PALLAS_ROW_TILE, 968), (2000, 4, PALLAS_ROW_TILE, 504),
+    (968, 1, PALLAS_ROW_TILE_INT, 248)],
+    ids=["bosch-f32", "epsilon-f32", "bosch-int8"])
+def test_feature_block_of_the_cells_under_the_pieces_bound(F, itemsize, tile,
+                                                           block):
+    """The VMEM bound counts the float32 kernel's bf16 pieces and their
+    W operands: F = 968 in one block, F = 2,000 in four of 504, int8
+    rows at F = 968 in four of 248."""
+    assert _pallas_feature_block(F, 255, 4, tile, itemsize, 1) == block

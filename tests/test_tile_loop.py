@@ -318,3 +318,31 @@ def test_kernel_rows_are_counted_where_the_tiles_are_the_kernels(
                         lambda: tiles._replace(kernel=True))
     kernel, bucketed = moved()
     assert kernel == learner.R + bucketed
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["float32", "int8"])
+def test_kernel_rows_in_pieces_are_the_float32_rows(
+        quantized, timer_on, monkeypatch):
+    """``grow/hist_rows_kernel_pieces`` beside ``grow/hist_rows_kernel``:
+    every kernel row where the rows are float32 (the kernel splits them
+    into three bf16 pieces), none where they are int8."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.obs.registry import registry
+    rng = np.random.RandomState(4)
+    X = rng.randn(3000, 6)
+    y = (X[:, 0] - X[:, 3] > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 7, "verbosity": -1,
+              "min_data_in_leaf": 5, "tree_learner": "data",
+              "mesh_shape": "data=1", "use_quantized_grad": quantized}
+    bst = lgb.Booster(params=params, train_set=lgb.Dataset(
+        X, label=y, params=dict(params)).construct())
+    learner = bst.inner.learner
+    tiles = learner._data_tiles()
+    monkeypatch.setattr(learner, "_data_tiles",
+                        lambda: tiles._replace(kernel=True))
+    names = ("grow/hist_rows_kernel", "grow/hist_rows_kernel_pieces")
+    before = [registry.count(n) for n in names]
+    bst.update()
+    kernel, pieces = [registry.count(n) - b for n, b in zip(names, before)]
+    assert kernel > learner.R
+    assert pieces == (0 if quantized else kernel)
