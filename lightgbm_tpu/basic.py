@@ -71,7 +71,13 @@ class Dataset:
         if self.reference is not None:
             self.reference.construct()
         config = Config.from_params(self.params)
-        if hasattr(self.data, "tocsc") and not config.linear_tree:
+        reference = (self.reference._handle
+                     if self.reference is not None else None)
+        # a validation set of a linear-tree training set is scored on its
+        # raw values too
+        keep_raw = bool(config.linear_tree) or (
+            reference is not None and reference.raw_data is not None)
+        if hasattr(self.data, "tocsc") and not keep_raw:
             # scipy sparse stays sparse until binning (per-column pass +
             # EFB in BinnedDataset.from_matrix); no densification
             data = self.data
@@ -89,9 +95,7 @@ class Dataset:
             data, config, label=self.label, weights=self.weight,
             group=self.group, init_score=self.init_score,
             feature_names=feature_names, categorical_feature=cat,
-            reference=(self.reference._handle
-                       if self.reference is not None else None),
-            keep_raw_data=bool(config.linear_tree))
+            reference=reference, keep_raw_data=keep_raw)
         if self.free_raw_data:
             self.data = None
         return self
@@ -327,9 +331,11 @@ class Booster:
         # event, via the shared instrumentation point in boosting/gbdt.py
         from .boosting.gbdt import run_instrumented_eval
         self.inner._flush_valid_pending()  # eval-hoisting deferrals
-        return run_instrumented_eval(
+        out = run_instrumented_eval(
             self.inner.iter,
             lambda: self._eval_inner(valid_idx, name, feval))
+        self.inner.flush_linear_counts()
+        return out
 
     def _eval_inner(self, valid_idx: Optional[int], name: str,
                     feval=None) -> List[Tuple]:

@@ -111,6 +111,7 @@ class BinnedDataset:
         self.raw_data: Optional[np.ndarray] = None  # kept for linear trees
         # EFB: when set, ``bins`` is the bundled [N, G] matrix (io/efb.py)
         self.bundle = None
+        self._raw_dev = None
 
     # ------------------------------------------------------------------
     @classmethod
@@ -349,6 +350,18 @@ class BinnedDataset:
         zb = self.bin_mappers[j].default_bin
         return np.where(lay.member[g][col] == j, lay.unmap[g][col],
                         zb).astype(self.bins.dtype)
+
+    def raw_device(self):
+        """The raw values on the device, float32 as the reference keeps
+        them (linear leaves read them beside the bins), uploaded once;
+        None where the dataset keeps none."""
+        if getattr(self, "_raw_dev", None) is None \
+                and self.raw_data is not None:
+            import jax.numpy as jnp
+            with obs.scope("io::stage_raw_device"):
+                self._raw_dev = jnp.asarray(
+                    np.asarray(self.raw_data, dtype=np.float32))
+        return self._raw_dev
 
     def feature_bins(self) -> np.ndarray:
         """[N, F] per-feature bin matrix; materializes when bundled

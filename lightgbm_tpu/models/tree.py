@@ -70,11 +70,112 @@ class Tree:
         # traversal (not serialized; rebuilt models predict on raw values)
         self.cat_bin_masks: dict = {}
         # linear trees (reference: tree.h is_linear_/leaf_const_/
-        # leaf_features_/leaf_coeff_)
+        # leaf_features_/leaf_coeff_); a tree fit on the device holds its
+        # fit there (``_linear_dev``) until something reads the lists
         self.is_linear = False
-        self.leaf_const = np.zeros(0)
-        self.leaf_features: List[List[int]] = []
-        self.leaf_coeff: List[List[float]] = []
+        self._leaf_const = np.zeros(0)
+        self._leaf_features: List[List[int]] = []
+        self._leaf_coeff: List[List[float]] = []
+        self._linear_dev = None
+
+    # ------------------------------------------------------------------
+    @property
+    def leaf_const(self) -> np.ndarray:
+        self._fetch_linear()
+        return self._leaf_const
+
+    @leaf_const.setter
+    def leaf_const(self, value) -> None:
+        self._fetch_linear()
+        self._leaf_const = value
+
+    @property
+    def leaf_features(self) -> List[List[int]]:
+        self._fetch_linear()
+        return self._leaf_features
+
+    @leaf_features.setter
+    def leaf_features(self, value) -> None:
+        self._fetch_linear()
+        self._leaf_features = value
+
+    @property
+    def leaf_coeff(self) -> List[List[float]]:
+        self._fetch_linear()
+        return self._leaf_coeff
+
+    @leaf_coeff.setter
+    def leaf_coeff(self, value) -> None:
+        self._fetch_linear()
+        self._leaf_coeff = value
+
+    def set_constant_linear(self) -> None:
+        """A linear tree whose every leaf keeps its constant (the first
+        tree of a model: reference ``is_first_tree``)."""
+        self.is_linear = True
+        self._linear_dev = None
+        self._leaf_const = self.leaf_value.copy()
+        self._leaf_features = [[] for _ in range(self.max_leaves)]
+        self._leaf_coeff = [[] for _ in range(self.max_leaves)]
+
+    def attach_linear(self, lin) -> None:
+        """The leaf fits of ``ops/linear.py`` as they lie on the device,
+        ``(const, coeff, feat, keep, has)``, already shrunk; read back
+        when a reader first asks for the lists."""
+        self.is_linear = True
+        self._linear_dev = tuple(lin)
+
+    def _fetch_linear(self) -> None:
+        if self._linear_dev is None:
+            return
+        import jax
+        dev, self._linear_dev = self._linear_dev, None
+        # jaxlint: disable=JLT001 -- the model is read (text, host
+        # predict): the fit's coefficients come back once, long done
+        const, coeff, feat, keep, has = jax.device_get(dev)
+        self._leaf_const = self.leaf_value.copy()
+        self._leaf_features = [[] for _ in range(self.max_leaves)]
+        self._leaf_coeff = [[] for _ in range(self.max_leaves)]
+        for leaf in range(self.num_leaves):
+            if not has[leaf]:
+                continue
+            self._leaf_const[leaf] = float(const[leaf])
+            cols = np.nonzero(keep[leaf])[0]
+            self._leaf_features[leaf] = [int(f) for f in feat[leaf, cols]]
+            self._leaf_coeff[leaf] = [float(c) for c in coeff[leaf, cols]]
+
+    def linear_arrays(self, leaves: int, width: int) -> tuple:
+        """``(const, coeff, feat, keep, has)`` as ``ops/linear.py`` and the
+        server take them, float32 and padded to ``leaves`` x ``width``,
+        from the lists: every leaf of a linear tree serves its
+        ``leaf_const`` (the leaf value where nothing was fit)."""
+        const = np.zeros(leaves, dtype=np.float32)
+        coeff = np.zeros((leaves, width), dtype=np.float32)
+        feat = np.zeros((leaves, width), dtype=np.int32)
+        keep = np.zeros((leaves, width), dtype=bool)
+        has = np.zeros(leaves, dtype=bool)
+        if self.is_linear:
+            nl = self.num_leaves
+            has[:nl] = True
+            const[:nl] = self.leaf_const[:nl]
+            for leaf in range(nl):
+                k = len(self.leaf_features[leaf])
+                feat[leaf, :k] = self.leaf_features[leaf]
+                coeff[leaf, :k] = self.leaf_coeff[leaf]
+                keep[leaf, :k] = True
+        return const, coeff, feat, keep, has
+
+    def linear_device(self) -> tuple:
+        """``linear_arrays`` on the device: the fit as it lies there since
+        ``attach_linear``, else packed from the lists."""
+        if self._linear_dev is not None:
+            return self._linear_dev
+        import jax.numpy as jnp
+        from ..utils import next_pow2
+        width = next_pow2(max([len(f) for f in
+                               self.leaf_features[:self.num_leaves]] + [1]))
+        return tuple(jnp.asarray(a) for a in self.linear_arrays(
+            max(self.max_leaves, self.num_leaves), width))
 
     # ------------------------------------------------------------------
     def split(self, leaf: int, feature: int, feature_inner: int,
@@ -178,9 +279,12 @@ class Tree:
         self.shrinkage *= rate
 
     def add_bias(self, val: float) -> None:
-        """reference: Tree::AddBias — used by boost_from_average refit."""
+        """reference: Tree::AddBias — used by boost_from_average refit;
+        a linear tree's constants move too."""
         self.leaf_value[:self.num_leaves] += val
         self.internal_value[:max(self.num_leaves - 1, 0)] += val
+        if self.is_linear:
+            self.leaf_const[:self.num_leaves] += val
 
     def set_leaf_output(self, leaf: int, value: float) -> None:
         self.leaf_value[leaf] = _sane(value)

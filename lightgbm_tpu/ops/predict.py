@@ -377,19 +377,42 @@ def _walk_stacked(bins: jnp.ndarray, nodes: StackedNodes,
                               nodes.cat_slot)
 
 
+def leaf_path_values(X, leaf, feat):
+    """[n, C]: each row's raw values of the C columns ``feat[leaf]`` of
+    its leaf (``feat`` [NL, C]). Each value is picked from its row by a
+    compare and select over the row's F columns and a sum in which every
+    other term is 0, exact (a NaN stays NaN): on a TPU v5e 14.6 ms for
+    [1M, 968] -> [1M, 16], where the element gather of
+    ``take_along_axis`` takes 251 ms."""
+    cols = jnp.arange(X.shape[1], dtype=jnp.int32)
+    return jnp.sum(jnp.where(cols[None, None, :] == feat[leaf][:, :, None],
+                             X[:, None, :], jnp.zeros((), X.dtype)),
+                   axis=-1)
+
+
+def linear_leaf_output(xv, leaf, val, const, coeff, valid, has):
+    """[n]: each row's linear value ``const + coeff . xv`` over its
+    leaf's ``valid`` columns, or ``val`` (the leaf's constant value)
+    where its leaf has no fit or one of those values is NaN. ``xv`` is
+    ``leaf_path_values`` of the rows; ``const``/``has`` [NL],
+    ``coeff``/``valid`` [NL, C]."""
+    v = valid[leaf]
+    bad = jnp.any(jnp.isnan(xv) & v, axis=1)
+    s = const[leaf] + jnp.sum(
+        jnp.where(v, coeff[leaf] * xv, jnp.float32(0.0)), axis=1)
+    return jnp.where(has[leaf] & ~bad, s, val)
+
+
 def _linear_leaf_values(X, leaves, vals, lin: LinearLeaves):
     """Override stacked leaf values with each leaf's linear model where
     one exists and none of its fitted features is NaN (f32 device math —
     the throughput path; the bit-exact host path accumulates linear
-    values in f64 from the same device leaf ids)."""
+    values in f64 from the same device leaf ids). Training computes the
+    same values for its own rows (``ops/linear.py``)."""
     def lin_one(leaf_t, val_t, const_t, coeff_t, feat_t, valid_t, has_t):
-        f = feat_t[leaf_t]                                   # [n, C]
-        xv = jnp.take_along_axis(X, f, axis=1)               # [n, C]
-        v = valid_t[leaf_t]
-        bad = jnp.any(jnp.isnan(xv) & v, axis=1)
-        s = const_t[leaf_t] + jnp.sum(
-            jnp.where(v, coeff_t[leaf_t] * xv, jnp.float32(0.0)), axis=1)
-        return jnp.where(has_t[leaf_t] & ~bad, s, val_t)
+        xv = leaf_path_values(X, leaf_t, feat_t)             # [n, C]
+        return linear_leaf_output(xv, leaf_t, val_t, const_t, coeff_t,
+                                  valid_t, has_t)
 
     return jax.vmap(lin_one)(leaves, vals, lin.const, lin.coeff,
                              lin.feat, lin.valid, lin.has)

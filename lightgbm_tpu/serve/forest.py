@@ -339,7 +339,7 @@ class StackedForest:
             zero_feat=jnp.asarray((k_used == _KIND_NUM)
                                   & (m_used == MissingType.ZERO)),
             vmax=jnp.asarray(np.int32(vmax)))
-        self._lin = self._pack_linear(models, T, NL) \
+        self._lin = self._pack_linear(models, NL) \
             if self.has_linear else None
         self._device = None           # None = follow the default device
         self._placed = {}
@@ -347,30 +347,13 @@ class StackedForest:
 
     # ------------------------------------------------------------------
     @staticmethod
-    def _pack_linear(models, T, NL) -> LinearLeaves:
+    def _pack_linear(models, NL) -> LinearLeaves:
         C = max((len(t.leaf_coeff[leaf])
                  for t in models if t.is_linear
                  for leaf in range(t.num_leaves)
                  if t.leaf_features[leaf]), default=1)
-        C = max(C, 1)
-        const = np.zeros((T, NL), dtype=np.float32)
-        coeff = np.zeros((T, NL, C), dtype=np.float32)
-        lfeat = np.zeros((T, NL, C), dtype=np.int32)
-        valid = np.zeros((T, NL, C), dtype=bool)
-        has = np.zeros((T, NL), dtype=bool)
-        for ti, tree in enumerate(models):
-            if not tree.is_linear:
-                continue
-            for leaf in range(tree.num_leaves):
-                feats = tree.leaf_features[leaf]
-                if not feats:
-                    continue  # no fit: constant leaf_value serves
-                k = len(feats)
-                has[ti, leaf] = True
-                const[ti, leaf] = tree.leaf_const[leaf]
-                coeff[ti, leaf, :k] = tree.leaf_coeff[leaf]
-                lfeat[ti, leaf, :k] = feats
-                valid[ti, leaf, :k] = True
+        parts = zip(*(t.linear_arrays(NL, max(C, 1)) for t in models))
+        const, coeff, lfeat, valid, has = (np.stack(p) for p in parts)
         return LinearLeaves(
             const=jnp.asarray(const), coeff=jnp.asarray(coeff),
             feat=jnp.asarray(lfeat), valid=jnp.asarray(valid),
