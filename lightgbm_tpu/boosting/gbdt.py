@@ -146,14 +146,15 @@ def compute_metrics(dataset_name: str, metrics: Sequence[Metric], score,
 
 def _device_tree_outputs(tree: Tree, bins_dev, dataset: BinnedDataset,
                          bin_meta):
-    """``(delta, trips)``: the device [n] f32 per-row output of one tree
-    over the dataset's binned rows via the vectorized traversal
-    (ops/predict.py) and the hops that walk ran for every row; a linear
-    tree then takes its leaves' linear values over the dataset's raw
-    values on the device (``ops/linear.py``); stumps
-    are a constant (``trips`` None: no walk), a zero-valued stump has no
-    ``delta`` either. Shared by train-side (DART/rollback) and
-    valid-side scoring."""
+    """``(delta, dtree, trips)``: the device [n] f32 per-row output of
+    one tree over the dataset's binned rows via the traversal of
+    ops/predict.py, the tree as the device holds it and the hops the walk
+    ran for every row (None where the tree was scored all nodes at
+    once); a linear tree then takes its leaves' linear values over the
+    dataset's raw values on the device (``ops/linear.py``); stumps are a
+    constant (``dtree`` and ``trips`` None: no traversal), a zero-valued
+    stump has no ``delta`` either. Shared by train-side (DART/rollback)
+    and valid-side scoring."""
     from ..ops.predict import (build_device_tree, predict_leaf_on_device,
                                tree_output_on_device)
     if dataset.bundle is not None:
@@ -166,14 +167,15 @@ def _device_tree_outputs(tree: Tree, bins_dev, dataset: BinnedDataset,
     if dtree is None:  # stump: constant value
         if tree.num_leaves >= 1 and tree.leaf_value[0] != 0.0:
             return jnp.full((dataset.num_data,),
-                            np.float32(tree.leaf_value[0])), None
-        return None, None
+                            np.float32(tree.leaf_value[0])), None, None
+        return None, None, None
     raw = dataset.raw_device() if tree.is_linear else None
     if raw is not None:
         from ..ops.linear import tree_output
         leaf, trips = predict_leaf_on_device(bins_dev, dtree)
-        return tree_output(raw, leaf, tree), trips
-    return tree_output_on_device(bins_dev, dtree)
+        return tree_output(raw, leaf, tree), dtree, trips
+    delta, trips = tree_output_on_device(bins_dev, dtree)
+    return delta, dtree, trips
 
 
 class ValidData:
@@ -210,12 +212,12 @@ class ValidData:
 
     def add_tree(self, tree: Tree, class_id: int, bin_meta,
                  sign: float = 1.0) -> None:
-        delta, trips = _device_tree_outputs(tree, self.bins_dev,
-                                            self.dataset, bin_meta)
+        delta, dtree, trips = _device_tree_outputs(tree, self.bins_dev,
+                                                   self.dataset, bin_meta)
         if delta is None:
             return
-        if obs.enabled and trips:
-            self._count_walk(tree, trips)
+        if obs.enabled and dtree is not None:
+            self._count_scoring(tree, dtree, trips)
             if tree.is_linear:
                 # rows whose linear output was taken (ops/linear.py)
                 obs.inc("linear/valid_rows", self.dataset.num_data)
@@ -224,8 +226,12 @@ class ValidData:
         self.scores_dev = _add_valid_score_col(self.scores_dev, delta,
                                                dev_i32(class_id))
 
-    def _count_walk(self, tree: Tree, trips: int) -> None:
-        """``valid/walk_hops_run``: rows times ``trips``, the hops the
+    def _count_scoring(self, tree: Tree, dtree, trips) -> None:
+        """A tree scored all nodes at once (``trips`` None) counts in
+        ``valid/trees_all_nodes``, and its rows times its padded node
+        count in ``valid/node_decisions``. A walked tree counts in
+        ``valid/trees_walked`` and in the hop counters:
+        ``valid/walk_hops_run``: rows times ``trips``, the hops the
         lockstep loop of ``ops/predict.py`` ran for every row, as the
         walk reports them; ``valid/walk_hops_needed``: rows times the
         tree's mean leaf depth weighted by ``leaf_count``, the hops the
@@ -234,6 +240,11 @@ class ValidData:
         leaves would take a device pass that an untraced run does not
         make."""
         rows = self.dataset.num_data
+        if trips is None:
+            obs.inc("valid/trees_all_nodes")
+            obs.inc("valid/node_decisions", rows * int(dtree.feat.shape[0]))
+            return
+        obs.inc("valid/trees_walked")
         depth = tree.leaf_depth[:tree.num_leaves]
         weight = np.asarray(tree.leaf_count[:tree.num_leaves],
                             dtype=np.float64)

@@ -24,6 +24,7 @@ from lightgbm_tpu.ops import histogram
 from lightgbm_tpu.parallel.data_parallel import (DataParallelTreeLearner,
                                                  make_mesh)
 from lightgbm_tpu.treelearner.serial import SerialTreeLearner
+from lightgbm_tpu.utils import next_pow2
 
 SMALL_TRACE = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark", "tests", "data",
@@ -607,20 +608,32 @@ def test_no_instrument_jit_site_is_a_lambda():
     assert [site for site in sites if site[2]] == []
 
 
-VALID_COUNTERS = ("valid/walk_hops_run", "valid/walk_hops_needed")
+VALID_COUNTERS = ("valid/walk_hops_run", "valid/walk_hops_needed",
+                  "valid/trees_walked", "valid/trees_all_nodes",
+                  "valid/node_decisions")
 VALID_SPANS = ("gbdt::eval_fetch", "gbdt::eval_compute")
 
 
-def _train_with_validation(rounds=3):
+def _train_with_validation(rounds=3, categorical=False):
+    """``categorical``: column 0 holds six categories that decide the
+    label with the others, so every tree has a categorical node and
+    walks."""
     import lightgbm_tpu as lgb
     rng = np.random.RandomState(5)
     X, Xv = rng.randn(6000, 6), rng.randn(1500, 6)
-    train = lgb.Dataset(X, label=(X[:, 0] * X[:, 1] + X[:, 2] > 0)
-                        .astype(float))
-    valid = lgb.Dataset(Xv, label=(Xv[:, 0] * Xv[:, 1] + Xv[:, 2] > 0)
-                        .astype(float), reference=train)
+    kw = {}
+    if categorical:
+        X[:, 0], Xv[:, 0] = rng.randint(0, 6, 6000), rng.randint(0, 6, 1500)
+        kw = {"categorical_feature": [0]}
+
+    def label(A):
+        head = np.isin(A[:, 0], [1, 4]) if categorical else A[:, 0] > 0
+        return (head * A[:, 1] + A[:, 2] > 0).astype(float)
+    train = lgb.Dataset(X, label=label(X), **kw)
+    valid = lgb.Dataset(Xv, label=label(Xv), reference=train)
     return lgb.train({"objective": "binary", "verbose": -1, "metric": "auc",
-                      "num_leaves": 31, "max_bin": 63}, train,
+                      "num_leaves": 31, "max_bin": 63,
+                      "min_data_per_group": 5, "cat_smooth": 1}, train,
                      num_boost_round=rounds, valid_sets=[valid])
 
 
@@ -628,9 +641,7 @@ def _valid_counters():
     return np.asarray([registry.count(name) for name in VALID_COUNTERS])
 
 
-def test_walk_counters_follow_the_trees(timer_on, monkeypatch):
-    """``walk_hops_run`` is what the traversal was handed as its static
-    ``trips``, for the validation rows, whatever rule chose them."""
+def _spy_traversals(monkeypatch):
     from lightgbm_tpu.ops import predict
     walks, traverse = [], predict._traverse
 
@@ -638,16 +649,40 @@ def test_walk_counters_follow_the_trees(timer_on, monkeypatch):
         walks.append((bins.shape[0], trips))
         return traverse(bins, dtree, trips)
     monkeypatch.setattr(predict, "_traverse", spy)
+    return walks
+
+
+def test_walk_counters_follow_the_trees(timer_on, monkeypatch):
+    """``walk_hops_run`` is what the lockstep walk was handed as its
+    static ``trips``, for the validation rows, whatever rule chose them:
+    trees with a categorical node walk."""
+    walks = _spy_traversals(monkeypatch)
     before = _valid_counters()
-    trees = _train_with_validation().inner.models
-    assert len(trees) == 3 and [rows for rows, _ in walks] == [1500] * 3
-    hops_run, hops_needed = _valid_counters() - before
+    trees = _train_with_validation(categorical=True).inner.models
+    assert len(trees) == 3 and all(t.cat_bin_masks for t in trees)
+    assert [rows for rows, _ in walks] == [1500] * 3
+    hops_run, hops_needed, walked, all_nodes, decisions = \
+        _valid_counters() - before
+    assert (walked, all_nodes, decisions) == (3, 0, 0)
     assert hops_run == sum(rows * trips for rows, trips in walks)
     assert hops_needed == sum(int(round(1500 * float(
         (t.leaf_depth[:t.num_leaves] * t.leaf_count[:t.num_leaves]).sum()
         / t.leaf_count[:t.num_leaves].sum()))) for t in trees)
     # no row needs more hops than the deepest leaf, which the loop covers
     assert 1500 * 3 < hops_needed <= hops_run
+
+
+def test_numeric_trees_count_their_node_decisions(timer_on, monkeypatch):
+    """Trees of numerical nodes are scored all nodes at once: no hops run,
+    every row decides every (padded) node of the tree."""
+    walks = _spy_traversals(monkeypatch)
+    before = _valid_counters()
+    trees = _train_with_validation().inner.models
+    assert len(trees) == 3 and walks == [(1500, None)] * 3
+    hops_run, hops_needed, walked, all_nodes, decisions = \
+        _valid_counters() - before
+    assert (hops_run, hops_needed, walked, all_nodes) == (0, 0, 0, 3)
+    assert decisions == sum(1500 * next_pow2(t.num_internal) for t in trees)
 
 
 def test_walk_counters_stay_still_while_the_timer_is_off():
