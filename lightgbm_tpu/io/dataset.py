@@ -85,6 +85,18 @@ class Metadata:
         return 0 if self.query_boundaries is None else len(self.query_boundaries) - 1
 
 
+def _count_bundles(layout, conflict_rows: int) -> None:
+    """``efb/groups``: the bundled matrix's columns; ``efb/features_bundled``:
+    the features that share a column with another; ``efb/conflict_rows``:
+    the (row, bundle) pairs in which a member was dropped for another
+    (io/efb.py ``bundle_columns``). Counted once, when a training set is
+    bundled."""
+    obs.inc("efb/groups", layout.num_groups)
+    obs.inc("efb/features_bundled",
+            sum(len(g) for g in layout.groups if len(g) > 1))
+    obs.inc("efb/conflict_rows", conflict_rows)
+
+
 class BinnedDataset:
     """Quantized training data (reference: include/LightGBM/dataset.h:426).
 
@@ -264,8 +276,10 @@ class BinnedDataset:
                 zero_bins = np.asarray(
                     [m.default_bin for m in self.bin_mappers],
                     dtype=np.int32)
-                self.bins = bundle_columns(binned_col, self.bundle,
-                                           zero_bins, n, dtype)
+                self.bins, conflicts = bundle_columns(
+                    binned_col, self.bundle, zero_bins, n, dtype)
+                if reference is None:
+                    _count_bundles(self.bundle, conflicts)
             else:
                 dtype = np.uint8 if self.max_num_bin <= 256 else np.uint16
                 bins = np.empty((n, len(self.bin_mappers)), dtype=dtype)
@@ -335,8 +349,7 @@ class BinnedDataset:
                              max_bundle_bins)
         if all(len(g) == 1 for g in groups):
             return
-        self.bundle = build_layout(groups, self.num_bin_per_feature,
-                                   zero_bins, self.max_num_bin)
+        self.bundle = build_layout(groups, self.num_bin_per_feature)
         log.info("EFB: bundled %d features into %d columns"
                  % (F, self.bundle.num_groups))
 
@@ -344,12 +357,12 @@ class BinnedDataset:
         """Per-feature bin column, unbundling if needed (host)."""
         if self.bundle is None:
             return self.bins[:, j]
+        from .efb import member_bin
         lay = self.bundle
-        g = int(lay.group_of[j])
-        col = self.bins[:, g].astype(np.int64)
-        zb = self.bin_mappers[j].default_bin
-        return np.where(lay.member[g][col] == j, lay.unmap[g][col],
-                        zb).astype(self.bins.dtype)
+        col = self.bins[:, int(lay.group_of[j])].astype(np.int32)
+        return member_bin(col, lay.first_bin[j], lay.num_bins[j],
+                          self.bin_mappers[j].default_bin,
+                          lay.needs_zero_fix[j]).astype(self.bins.dtype)
 
     def raw_device(self):
         """The raw values on the device, float32 as the reference keeps

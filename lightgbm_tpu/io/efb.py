@@ -11,8 +11,9 @@ original bin order.
 Where the reference's histogram works directly on group columns and scans
 per-feature slices, the TPU build keeps the downstream learner unchanged:
 the [N, G] bundled matrix is histogrammed on device and the bundle
-histogram is *unpacked* back to per-feature [F, B] histograms with a
-static gather (ops/histogram.py unpack_bundle_histogram); a member's
+histogram is *unpacked* back to per-feature [F, B] histograms, each
+feature's bundle row shifted to its sub-range's start
+(ops/histogram.py unpack_bundle_histogram); a member's
 zero-bin row is reconstructed as leaf_total − Σ(non-zero bins) — valid
 because exclusivity means "some other member is non-zero" ⇒ "this member
 is zero" (the reference's FixHistogram plays the same trick,
@@ -28,26 +29,27 @@ from typing import List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+# Share of the bundling sample's rows in which the members of one bundle
+# may overlap: upstream's documented default (docs/Parameters.rst
+# ``max_conflict_rate``), so bundles are exclusive on the sample.
+MAX_CONFLICT_RATE = 0.0
+
 
 class BundleLayout(NamedTuple):
     """Static description of the bundled bin matrix.
 
-    For every bundle column g, ``member[g, b]`` is the used-feature index
-    owning bundle bin b (-1 for bin 0 of a multi-member bundle and for
-    padding), and ``unmap[g, b]`` the original bin id of that feature.
-    For single-member groups these are identity-like (member = the
-    feature for every bin, unmap = b). ``needs_zero_fix[f]`` marks
-    features living in multi-member bundles: their zero-bin histogram row
-    must be reconstructed as total − Σ(others).
+    ``needs_zero_fix[f]`` marks features living in multi-member bundles:
+    their zero-bin histogram row must be reconstructed as total −
+    Σ(others). Such a feature's non-zero bins, in order, are its bundle's
+    bins from ``first_bin[f]`` on (``member_bin``); a feature alone in its
+    column keeps its bins as they are.
     """
     groups: List[List[int]]          # used-feature indices per bundle
     group_of: np.ndarray             # [F] i32 bundle column per feature
-    member: np.ndarray               # [G, Bg] i32
-    unmap: np.ndarray                # [G, Bg] i32
     needs_zero_fix: np.ndarray       # [F] bool
-    # per-feature gather table into the bundle histogram:
-    gidx_g: np.ndarray               # [F, B] i32 bundle column (or -1)
-    gidx_b: np.ndarray               # [F, B] i32 bundle bin (or 0)
+    first_bin: np.ndarray            # [F] i32 (0 where alone)
+    num_bins: np.ndarray             # [F] i32 each feature's own bins
+    group_bins: np.ndarray           # [G] i32 each bundle's own bins
     num_bundled_bins: int            # Bg
 
     @property
@@ -55,11 +57,24 @@ class BundleLayout(NamedTuple):
         return len(self.groups)
 
 
+def member_bin(raw, first_bin, num_bins, zero_bin, zero_fix,
+               where=np.where):
+    """A feature's original bin where its bundle column holds ``raw``:
+    a member of a bundle (``zero_fix``) reads its non-zero bins, in
+    order, from the bundle bins ``first_bin .. first_bin + num_bins - 2``
+    and its zero bin everywhere else; a feature alone in its column reads
+    ``raw``. Comparisons alone, on numpy or (``where=jnp.where``) jax
+    arrays."""
+    slot = raw - first_bin
+    mine = (slot >= 0) & (slot < num_bins - 1)
+    return where(zero_fix, where(mine, slot + (slot >= zero_bin), zero_bin),
+                 raw)
+
+
 def find_groups(nonzero_masks: List[Optional[np.ndarray]],
                 num_bins: np.ndarray,
                 sample_cnt: int,
-                max_bundle_bins: int,
-                max_conflict_rate: float = 1e-4) -> List[List[int]]:
+                max_bundle_bins: int) -> List[List[int]]:
     """Greedy conflict-aware bundling over sampled non-zero masks
     (reference: Dataset::FindGroups, src/io/dataset.cpp:107: features
     sorted by non-zero count, each placed into the first group whose
@@ -68,9 +83,11 @@ def find_groups(nonzero_masks: List[Optional[np.ndarray]],
     ``nonzero_masks[f]`` is a bool[sample_cnt] mask of sampled rows where
     feature f is away from its zero bin, or None if the feature must not
     be bundled (dense/categorical/NaN) — those get singleton groups.
+    A group's members may overlap in at most
+    ``int(MAX_CONFLICT_RATE * sample_cnt)`` sampled rows.
     """
     F = len(nonzero_masks)
-    max_conflict = int(max_conflict_rate * sample_cnt)
+    max_conflict = int(MAX_CONFLICT_RATE * sample_cnt)
     candidates = [f for f in range(F) if nonzero_masks[f] is not None]
     # densest first, like the reference's sorted-by-cnt order
     candidates.sort(key=lambda f: -int(nonzero_masks[f].sum()))
@@ -106,77 +123,62 @@ def find_groups(nonzero_masks: List[Optional[np.ndarray]],
     return groups
 
 
-def build_layout(groups: List[List[int]], num_bins: np.ndarray,
-                 zero_bins: np.ndarray, max_num_bin: int) -> BundleLayout:
-    """Assign bundle bin ranges and build the member/unmap/gather
-    tables (reference: FeatureGroup bin offsets,
+def build_layout(groups: List[List[int]],
+                 num_bins: np.ndarray) -> BundleLayout:
+    """Assign bundle bin ranges (reference: FeatureGroup bin offsets,
     include/LightGBM/feature_group.h:25)."""
     F = len(num_bins)
-    G = len(groups)
     group_of = np.zeros(F, dtype=np.int32)
     needs_zero_fix = np.zeros(F, dtype=bool)
-    # width of the bundled matrix's bin axis
-    widths = []
+    first_bin = np.zeros(F, dtype=np.int32)
+    widths = []                          # each bundle's own bins
     for g, members in enumerate(groups):
+        group_of[members] = g
         if len(members) == 1:
             widths.append(int(num_bins[members[0]]))
-        else:
-            widths.append(1 + int(sum(num_bins[f] - 1 for f in members)))
-    Bg = max(max(widths), 2)
-    member = np.full((G, Bg), -1, dtype=np.int32)
-    unmap = np.zeros((G, Bg), dtype=np.int32)
-    gidx_g = np.full((F, max_num_bin), -1, dtype=np.int32)
-    gidx_b = np.zeros((F, max_num_bin), dtype=np.int32)
-    for g, members in enumerate(groups):
-        if len(members) == 1:
-            f = members[0]
-            group_of[f] = g
-            b = int(num_bins[f])
-            member[g, :b] = f
-            unmap[g, :b] = np.arange(b)
-            gidx_g[f, :b] = g
-            gidx_b[f, :b] = np.arange(b)
             continue
-        offset = 1
+        offset = 1                       # bin 0: every member at its zero
         for f in members:
-            group_of[f] = g
             needs_zero_fix[f] = True
-            zb = int(zero_bins[f])
-            nonzero = [t for t in range(int(num_bins[f])) if t != zb]
-            for k, t in enumerate(nonzero):
-                member[g, offset + k] = f
-                unmap[g, offset + k] = t
-                gidx_g[f, t] = g
-                gidx_b[f, t] = offset + k
-            offset += len(nonzero)
-    return BundleLayout(groups=groups, group_of=group_of, member=member,
-                        unmap=unmap, needs_zero_fix=needs_zero_fix,
-                        gidx_g=gidx_g, gidx_b=gidx_b,
-                        num_bundled_bins=Bg)
+            first_bin[f] = offset
+            offset += int(num_bins[f]) - 1
+        widths.append(offset)
+    return BundleLayout(groups=groups, group_of=group_of,
+                        needs_zero_fix=needs_zero_fix, first_bin=first_bin,
+                        num_bins=np.asarray(num_bins, dtype=np.int32),
+                        group_bins=np.asarray(widths, dtype=np.int32),
+                        num_bundled_bins=max(max(widths), 2))
 
 
 def bundle_columns(per_feature_bin_cols, layout: BundleLayout,
                    zero_bins: np.ndarray, n: int,
-                   dtype) -> np.ndarray:
-    """Pack per-feature bin columns into the bundled [N, G] matrix.
-    ``per_feature_bin_cols(f)`` yields the full bin column of used
-    feature f. Conflict rows (two members non-zero) keep the later
-    member's value, matching the reference's last-write-wins push."""
+                   dtype) -> Tuple[np.ndarray, int]:
+    """Pack per-feature bin columns into the bundled [N, G] matrix:
+    ``(matrix, conflict rows)``. ``per_feature_bin_cols(f)`` yields the
+    full bin column of used feature f. A row in which two members of a
+    bundle are away from their zero bins (a conflict: the bundling
+    sample admits ``MAX_CONFLICT_RATE`` of them, the rows outside it any
+    number) keeps the member with the higher feature number, as
+    upstream's ``FeatureGroup::PushData`` writes members in column
+    order; the other members read their zero bins there. ``conflict
+    rows`` counts such (row, bundle) pairs."""
     G = layout.num_groups
     out = np.zeros((n, G), dtype=dtype)
+    conflicts = 0
     for g, members in enumerate(layout.groups):
         if len(members) == 1:
             out[:, g] = per_feature_bin_cols(members[0])
             continue
-        col = np.zeros(n, dtype=np.int64)
-        offset = 1
-        for f in members:
-            fb = per_feature_bin_cols(f).astype(np.int64)
+        col = np.zeros(n, dtype=np.int32)
+        hits = np.zeros(n, dtype=np.uint8)
+        for f in sorted(members):           # the higher feature writes last
+            fb = np.asarray(per_feature_bin_cols(f))
             zb = int(zero_bins[f])
-            # map original bin t (≠ zero_bin) to its bundle slot
-            slot = np.where(fb < zb, fb, fb - 1)
-            nz = fb != zb
-            col = np.where(nz, offset + slot, col)
-            offset += int(np.sum(layout.member[g] == f))
+            rows = np.flatnonzero(fb != zb)
+            t = fb[rows].astype(np.int32)
+            # original bin t (!= zero bin) at its bundle slot
+            col[rows] = layout.first_bin[f] + t - (t > zb)
+            hits[rows] += 1
+        conflicts += int(np.count_nonzero(hits > 1))
         out[:, g] = col.astype(dtype)
-    return out
+    return out, conflicts

@@ -713,20 +713,29 @@ def mask_gh(gh: jnp.ndarray, keep) -> jnp.ndarray:
     return jnp.where(keep, gh, jnp.zeros((), dtype=gh.dtype))
 
 
-def unpack_bundle_histogram(bhist: jnp.ndarray,
-                            gidx_g: jnp.ndarray, gidx_b: jnp.ndarray,
+def unpack_bundle_histogram(bhist: jnp.ndarray, group_of: jnp.ndarray,
+                            first_bin: jnp.ndarray, num_bins: jnp.ndarray,
                             zero_fix: jnp.ndarray, zero_bins: jnp.ndarray,
-                            totals: jnp.ndarray) -> jnp.ndarray:
+                            totals, B: int) -> jnp.ndarray:
     """Bundle histogram [G, Bg, C] → per-feature histogram [F, B, C].
 
     EFB support (reference: the per-feature slicing of FeatureGroup
     histograms + FixHistogram zero-bin reconstruction,
-    src/io/dataset.cpp): a bundled feature's non-zero bins gather 1:1
-    from its bundle sub-range (static index tables ``gidx_g``/``gidx_b``,
-    -1 = no source), and its zero-bin row is leaf_total − Σ(non-zero) —
-    exclusivity means rows under other members' bins are zero rows of
-    this feature.
+    src/io/dataset.cpp). A member of a bundle (``zero_fix``) keeps its
+    non-zero bins, in their order, in the bundle bins from
+    ``first_bin`` on (io/efb.py ``member_bin``), so its histogram is
+    its bundle's row shifted left by ``first_bin`` with its zero bin
+    put back in: bins below the zero bin read the shifted row, bins
+    above it the shifted row one bin later, bins from ``num_bins`` on
+    nothing. Its zero bin is leaf_total − Σ(non-zero bins) — exclusivity
+    means rows under other members' bins are zero rows of this feature.
+    A feature alone in its column reads the row as it is. No element
+    gather: each feature takes its bundle's whole row, the shift is a
+    fixed ladder of rolls by powers of two, chosen per feature by the
+    bits of ``first_bin``.
 
+    num_bins : [F] — each feature's bins (0 for padding features, whose
+        histograms are then zero).
     totals : [C] — the leaf's (grad, hess, count, total) sums, in the
         histogram's own dtype (f32, or int32/int64 in quantized mode —
         where the zero-bin residual reconstruction is EXACT integer
@@ -737,12 +746,26 @@ def unpack_bundle_histogram(bhist: jnp.ndarray,
     """
     if totals is None:
         totals = jnp.sum(bhist[0], axis=0)
-    F = gidx_g.shape[0]
+    Bg = bhist.shape[1]
+    W = max(B, Bg)
     zero = jnp.zeros((), dtype=bhist.dtype)
-    safe_g = jnp.maximum(gidx_g, 0)
-    hist = bhist[safe_g, gidx_b]                       # [F, B, C]
-    hist = jnp.where((gidx_g >= 0)[..., None], hist, zero)
-    resid = (totals.astype(bhist.dtype)[None, :]
-             - jnp.sum(hist, axis=1))                  # [F, C]
-    fix = jnp.where(zero_fix[:, None], resid, zero)
-    return hist.at[jnp.arange(F), zero_bins].add(fix)
+    # [C, F, W]: the bins along the lanes, so that the rolls are lane
+    # rotations and no [.., 4]-wide minor dimension is laid out
+    rows = jnp.take(jnp.transpose(bhist, (2, 0, 1)), group_of, axis=1)
+    if W > Bg:
+        rows = jnp.pad(rows, ((0, 0), (0, 0), (0, W - Bg)))
+    shift = first_bin[None, :, None]
+    for k in range((W - 1).bit_length()):
+        rows = jnp.where((shift >> k) & 1 == 1,
+                         jnp.roll(rows, -(1 << k), axis=2), rows)
+    t = jnp.arange(B, dtype=jnp.int32)[None, None, :]
+    member = zero_fix[None, :, None]
+    zb = zero_bins[None, :, None]
+    after = jnp.roll(rows, 1, axis=2)[..., :B]
+    rows = rows[..., :B]
+    hist = jnp.where(member & (t > zb), after, rows)
+    keep = (t < num_bins[None, :, None]) & ~(member & (t == zb))
+    hist = jnp.where(keep, hist, zero)
+    resid = totals.astype(bhist.dtype)[:, None] - jnp.sum(hist, axis=2)
+    hist = jnp.where(member & (t == zb), resid[..., None], hist)
+    return jnp.transpose(hist, (1, 2, 0))

@@ -116,7 +116,7 @@ def build_device_tree(tree: Tree, bin_meta, B: int,
 
     ``bundle`` (io/efb.py BundleLayout): when the binned rows are EFB
     bundles, every node's decision becomes a boolean LUT over its bundle
-    column's bins (computed host-side from the member/unmap maps — the
+    column's bins (computed host-side by io/efb.py ``member_bin`` — the
     same mechanism as categorical masks), and ``feat`` points at the
     bundle column."""
     ni = tree.num_internal
@@ -175,35 +175,31 @@ def build_device_tree(tree: Tree, bin_meta, B: int,
 def _build_bundled_device_tree(tree: Tree, bin_meta, B: int,
                                bundle) -> DeviceTree:
     """LUT-mode DeviceTree over EFB-bundled bins: per node, a bool[B]
-    left/right table over the node's bundle column."""
+    left/right table over the node's bundle column, all nodes at once."""
     from ..io.binning import MissingType as MT
+    from ..io.efb import member_bin
     nan_bins, zero_bins, missing_types = bin_meta
     ni = tree.num_internal
     NI = _next_pow2(ni)
     NL = _next_pow2(tree.num_leaves)
+    f = tree.split_feature_inner[:ni]
     feat = np.zeros(NI, dtype=np.int32)
+    feat[:ni] = bundle.group_of[f]
+    # every node's original bin at each bin of its bundle column: [ni, W]
+    orig = member_bin(np.arange(min(B, bundle.num_bundled_bins))[None, :],
+                      bundle.first_bin[f, None], bundle.num_bins[f, None],
+                      zero_bins[f, None], bundle.needs_zero_fix[f, None])
+    dt = tree.decision_type[:ni]
+    dl = ((dt & kDefaultLeftMask) != 0)[:, None]
+    gl = orig <= tree.threshold_in_bin[:ni, None]
+    mt = missing_types[f, None]
+    gl = np.where((mt == MT.NAN) & (orig == nan_bins[f, None]), dl, gl)
+    gl = np.where((mt == MT.ZERO) & (orig == zero_bins[f, None]), dl, gl)
+    for node in np.flatnonzero(dt & kCategoricalMask):
+        mask = np.asarray(tree.cat_bin_masks[node], dtype=bool)
+        gl[node] = mask[np.minimum(orig[node], len(mask) - 1)]
     lut = np.zeros((NI, B), dtype=bool)
-    dt_bits = tree.decision_type
-    for node in range(ni):
-        f = int(tree.split_feature_inner[node])
-        g = int(bundle.group_of[f])
-        feat[node] = g
-        mb = bundle.member[g]
-        um = bundle.unmap[g]
-        zb = int(zero_bins[f])
-        orig = np.where(mb == f, um, zb)[:B]
-        if int(dt_bits[node]) & kCategoricalMask:
-            mask = np.asarray(tree.cat_bin_masks[node], dtype=bool)
-            gl = mask[np.minimum(orig, len(mask) - 1)]
-        else:
-            tb = int(tree.threshold_in_bin[node])
-            dl = bool(int(dt_bits[node]) & kDefaultLeftMask)
-            gl = orig <= tb
-            if missing_types[f] == MT.NAN:
-                gl = np.where(orig == nan_bins[f], dl, gl)
-            elif missing_types[f] == MT.ZERO:
-                gl = np.where(orig == zero_bins[f], dl, gl)
-        lut[node, :len(gl)] = gl
+    lut[:ni, :gl.shape[1]] = gl
     left = np.zeros(NI, dtype=np.int32)
     right = np.zeros(NI, dtype=np.int32)
     left[:ni] = tree.left_child[:ni]

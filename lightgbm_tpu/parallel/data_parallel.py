@@ -158,9 +158,7 @@ class DataParallelTreeLearner(CapabilityMixin):
         self.B = next_pow2(max(int(dataset.max_num_bin), 2))
         if self._bundled:
             self.Bg = next_pow2(max(dataset.bundle.num_bundled_bins, 2))
-            self._btab = build_bundle_tables(
-                dataset, self.F, dataset.bundle.num_groups, self.B,
-                self.Bg)
+            self._btab = build_bundle_tables(dataset, self.F)
         else:
             self.Bg = 0
             self._btab = jnp.int32(0)
@@ -293,10 +291,11 @@ class DataParallelTreeLearner(CapabilityMixin):
                     h, self.hist_sharding)
         with jax.named_scope("obs_psum_bundle_histogram"):
             bh = jax.lax.with_sharding_constraint(h, self.rep_sharding)
-        return unpack_bundle_histogram(bh, self._btab.gidx_g,
-                                       self._btab.gidx_b,
-                                       self._btab.zero_fix,
-                                       self.meta.zero_bin, totals)
+        with jax.named_scope("obs_unpack"):
+            return unpack_bundle_histogram(
+                bh, self._btab.group_of, self._btab.first_bin,
+                self._btab.num_bins, self._btab.zero_fix,
+                self.meta.zero_bin, totals, self.B)
 
     def _root_impl_opts(self, bins, gh, feature_mask, rand_seed,
                         extra_trees: bool, qscale):
@@ -708,6 +707,7 @@ class DataParallelTreeLearner(CapabilityMixin):
                 self._count_hist_rows(recs_h, applied)
                 self._count_partition_splits(recs_h, applied)
                 self._count_partition_rows(recs_h, applied)
+                self._count_unpacks(applied)
                 if bag is not None and applied:
                     obs.inc("sample/rows_in_bag",
                             int(recs_h.left_count[0]
@@ -740,6 +740,23 @@ class DataParallelTreeLearner(CapabilityMixin):
         obs.inc("grow/hist_rows_in_bag", int(np.where(
             left_total <= right_total, recs_h.left_count[:applied],
             recs_h.right_count[:applied]).sum()))
+
+    def _count_unpacks(self, applied: int) -> None:
+        """Where the columns are EFB bundles: ``efb/unpacks``, the bundle
+        histograms unpacked per feature (the root's and one a split);
+        ``efb/unpacked_entries``, the (feature, bin) pairs of the
+        features' own bins they made, and ``efb/bundle_entries``, the
+        (bundle, bin) pairs of the bundles' own bins they were made from:
+        what an unpack has to write and read, whatever width the store
+        pads a feature to."""
+        if not self._bundled:
+            return
+        calls = 1 + applied
+        obs.inc("efb/unpacks", calls)
+        obs.inc("efb/unpacked_entries",
+                calls * int(self.dataset.num_bin_per_feature.sum()))
+        obs.inc("efb/bundle_entries",
+                calls * int(self.dataset.bundle.group_bins.sum()))
 
     @staticmethod
     def _count_partition_splits(recs_h, applied: int) -> None:

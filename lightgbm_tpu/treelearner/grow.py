@@ -47,6 +47,7 @@ import numpy as np
 
 from ..io.binning import MissingType
 from ..io.dataset import BinnedDataset
+from ..io.efb import member_bin
 from ..models.tree import Tree
 from ..ops.histogram import subtract_histogram
 from ..ops.split import SplitInfo, find_best_split, make_rand_bins
@@ -336,55 +337,43 @@ def _maybe_rand_bins(extra_trees: bool, rand_seed, node_id, meta, params):
 
 
 class BundleTables(NamedTuple):
-    """Device-resident EFB tables (io/efb.py BundleLayout mirror).
-    ``member[g, b]``/``unmap[g, b]`` route a bundle bin back to its
-    owning feature and original bin; ``gidx_*`` gather the bundle
-    histogram into per-feature histograms; zero rows are reconstructed
-    for ``zero_fix`` features."""
-    group_of: jnp.ndarray       # [Fp] i32
-    member: jnp.ndarray         # [Gp, Bg] i32
-    unmap: jnp.ndarray          # [Gp, Bg] i32
-    gidx_g: jnp.ndarray         # [Fp, B] i32 (-1 = empty)
-    gidx_b: jnp.ndarray         # [Fp, B] i32
+    """Device-resident EFB tables (io/efb.py BundleLayout, per feature,
+    read by io/efb.py ``member_bin``). ``num_bins`` is 0 for padding
+    features."""
+    group_of: jnp.ndarray       # [Fp] i32 bundle column
+    first_bin: jnp.ndarray      # [Fp] i32 (0 where alone)
+    num_bins: jnp.ndarray       # [Fp] i32
     zero_fix: jnp.ndarray       # [Fp] bool
 
 
-def build_bundle_tables(dataset: BinnedDataset, Fp: int, Gp: int,
-                        B: int, Bg: int) -> BundleTables:
+def build_bundle_tables(dataset: BinnedDataset, Fp: int) -> BundleTables:
     """Device EFB tables from the dataset's BundleLayout, padded to
-    ``Fp`` features / ``Gp`` bundle columns (shared by the serial and
-    mesh-parallel learners)."""
+    ``Fp`` features (shared by the serial and mesh-parallel learners)."""
     lay = dataset.bundle
     F = dataset.num_features
-    G = lay.num_groups
-    member = np.full((Gp, Bg), -1, dtype=np.int32)
-    member[:G, :lay.member.shape[1]] = lay.member
-    unmap = np.zeros((Gp, Bg), dtype=np.int32)
-    unmap[:G, :lay.unmap.shape[1]] = lay.unmap
     group_of = np.zeros(Fp, dtype=np.int32)
     group_of[:F] = lay.group_of
-    gidx_g = np.full((Fp, B), -1, dtype=np.int32)
-    gidx_b = np.zeros((Fp, B), dtype=np.int32)
-    gidx_g[:F, :lay.gidx_g.shape[1]] = lay.gidx_g
-    gidx_b[:F, :lay.gidx_b.shape[1]] = lay.gidx_b
     zero_fix = np.zeros(Fp, dtype=bool)
     zero_fix[:F] = lay.needs_zero_fix
+    num_bins = np.zeros(Fp, dtype=np.int32)
+    num_bins[:F] = lay.num_bins
+    first_bin = np.zeros(Fp, dtype=np.int32)
+    first_bin[:F] = lay.first_bin
     return BundleTables(
-        group_of=jnp.asarray(group_of), member=jnp.asarray(member),
-        unmap=jnp.asarray(unmap), gidx_g=jnp.asarray(gidx_g),
-        gidx_b=jnp.asarray(gidx_b), zero_fix=jnp.asarray(zero_fix))
+        group_of=jnp.asarray(group_of), first_bin=jnp.asarray(first_bin),
+        num_bins=jnp.asarray(num_bins), zero_fix=jnp.asarray(zero_fix))
 
 
 @jax.named_scope("obs_partition")
 def _partition_col(bins, f, meta, btab, bundled: bool):
-    """The split feature's ORIGINAL bin value per row (unbundling via the
-    member/unmap LUTs when bundled; identity otherwise)."""
+    """The split feature's ORIGINAL bin value per row (unbundling a
+    member's slots of its bundle column when bundled, by comparisons
+    alone; identity otherwise)."""
     if not bundled:
         return jnp.take(bins, f, axis=1).astype(jnp.int32)
-    g = btab.group_of[f]
-    raw = jnp.take(bins, g, axis=1).astype(jnp.int32)
-    owner = btab.member[g][raw]
-    return jnp.where(owner == f, btab.unmap[g][raw], meta.zero_bin[f])
+    raw = jnp.take(bins, btab.group_of[f], axis=1).astype(jnp.int32)
+    return member_bin(raw, btab.first_bin[f], btab.num_bins[f],
+                      meta.zero_bin[f], btab.zero_fix[f], where=jnp.where)
 
 
 def _split_hist_store(hists, leaf, new_leaf, hist_small, smaller_is_left,

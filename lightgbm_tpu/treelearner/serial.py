@@ -114,14 +114,16 @@ def _leaf_histogram(bins, gh, meta, btab, *, B: int, Bg: int,
     bhist = build_histogram(bins, gh, Bg, hist_impl=hist_impl)
     if totals is None or jnp.issubdtype(gh.dtype, jnp.integer):
         totals = sum_gh(gh)
-    return _unbundled(bhist, meta, btab, totals)
+    return _unbundled(bhist, meta, btab, totals, B)
 
 
-def _unbundled(bhist, meta, btab, totals):
+def _unbundled(bhist, meta, btab, totals, B: int):
     """Per-feature [Fp, B, 4] from the bundle histogram (``totals``
     None: the histogram's own, ``unpack_bundle_histogram``)."""
-    return unpack_bundle_histogram(bhist, btab.gidx_g, btab.gidx_b,
-                                   btab.zero_fix, meta.zero_bin, totals)
+    with jax.named_scope("obs_unpack"):
+        return unpack_bundle_histogram(
+            bhist, btab.group_of, btab.first_bin, btab.num_bins,
+            btab.zero_fix, meta.zero_bin, totals, B)
 
 
 def _split_body(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
@@ -146,7 +148,7 @@ def _split_body(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
             # the bundle histogram itself holds
             if jnp.issubdtype(state.gh.dtype, jnp.integer):
                 totals = None
-            return _unbundled(hist, meta, btab, totals)
+            return _unbundled(hist, meta, btab, totals, B)
 
         return _subtract_child_hists(
             state, rec, leaf, new_leaf, leaf_of_row, smaller_is_left,
@@ -545,8 +547,7 @@ class SerialTreeLearner(CapabilityMixin):
             self._btab = jnp.int32(0)
             return
         self.Bg = _next_pow2(max(dataset.bundle.num_bundled_bins, 2))
-        self._btab = build_bundle_tables(dataset, self.Fp, self.Gp,
-                                         self.B, self.Bg)
+        self._btab = build_bundle_tables(dataset, self.Fp)
 
     def _step_fn(self):
         return _step_fn_cached(self.B, self.Bg, self._bundled,

@@ -68,9 +68,8 @@ class TestLayout:
     def test_unbundle_roundtrip(self):
         num_bins = np.array([5, 4, 6], dtype=np.int32)
         zero_bins = np.array([0, 1, 0], dtype=np.int32)
-        layout = build_layout([[0, 1, 2]], num_bins, zero_bins,
-                              max_num_bin=6)
-        from lightgbm_tpu.io.efb import bundle_columns
+        layout = build_layout([[0, 1, 2]], num_bins)
+        from lightgbm_tpu.io.efb import bundle_columns, member_bin
         rng = np.random.RandomState(0)
         n = 300
         cols = {}
@@ -81,15 +80,15 @@ class TestLayout:
             nz = [t for t in range(num_bins[f]) if t != zero_bins[f]]
             c[rows] = rng.choice(nz, len(rows))
             cols[f] = c
-        bundled = bundle_columns(lambda f: cols[f], layout, zero_bins,
-                                 n, np.uint8)
-        assert bundled.shape == (n, 1)
+        bundled, conflicts = bundle_columns(lambda f: cols[f], layout,
+                                            zero_bins, n, np.uint8)
+        assert bundled.shape == (n, 1) and conflicts == 0
         # unbundle each feature and compare
         for f in range(3):
             g = layout.group_of[f]
             col = bundled[:, g].astype(np.int64)
-            rec = np.where(layout.member[g][col] == f,
-                           layout.unmap[g][col], zero_bins[f])
+            rec = member_bin(col, layout.first_bin[f], num_bins[f],
+                             zero_bins[f], layout.needs_zero_fix[f])
             np.testing.assert_array_equal(rec, cols[f])
 
 

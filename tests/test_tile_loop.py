@@ -226,8 +226,9 @@ def test_bundled_child_histogram_is_unpacked_with_its_totals(quantized):
             histogram_tiles(bins, state.gh, lrn.Bg,
                             hist_impl=lrn._hist_impl))
         return unpack_bundle_histogram(
-            bh, lrn._btab.gidx_g, lrn._btab.gidx_b, lrn._btab.zero_fix,
-            lrn.meta.zero_bin, None if quantized else totals)
+            bh, lrn._btab.group_of, lrn._btab.first_bin,
+            lrn._btab.num_bins, lrn._btab.zero_fix, lrn.meta.zero_bin,
+            None if quantized else totals, lrn.B)
 
     got = np.asarray(child(lrn.bins, state))
     want = np.asarray(_leaf_histogram(
