@@ -65,7 +65,8 @@ from ..treelearner.grow import (GrowState, SplitRecord,
                                 _subtract_child_hists, _window_sizes,
                                 apply_split_record,
                                 build_bundle_tables, make_root_state,
-                                rec_valid, record_is_valid)
+                                rec_valid, record_is_valid,
+                                stage_bins_by_feature)
 from ..utils import log
 
 
@@ -131,6 +132,7 @@ class DataParallelTreeLearner(CapabilityMixin):
         with obs.scope("io::stage_bins_device"):
             self.bins = jax.make_array_from_callback(
                 (self.R, C), sharding, _shard)
+        self.bins_fm = stage_bins_by_feature(self.bins)
         self._init_cegb(config)
         self._init_monotone(config)
 
@@ -327,14 +329,14 @@ class DataParallelTreeLearner(CapabilityMixin):
         one device. Across a mesh it would be a global reshuffle."""
         return self.mesh.devices.size == 1
 
-    def _mesh_split_body(self, bins, state: GrowState, rec: SplitRecord,
-                         leaf, new_leaf, valid, mask_left, mask_right,
-                         rand_seed=0, extra_trees=None, pen_left=None,
-                         pen_right=None, qscale=None):
+    def _mesh_split_body(self, bins, bins_fm, state: GrowState,
+                         rec: SplitRecord, leaf, new_leaf, valid, mask_left,
+                         mask_right, rand_seed=0, extra_trees=None,
+                         pen_left=None, pen_right=None, qscale=None):
         """grow.py's split step over this learner's data, sharding and
         child histograms (``_children_histograms``)."""
         return _split_step(
-            bins, state, rec, leaf, new_leaf, valid, mask_left,
+            bins, bins_fm, state, rec, leaf, new_leaf, valid, mask_left,
             mask_right, self.meta, self.params, self._btab,
             self._children_histograms, bundled=self._bundled,
             has_cat=self._has_cat, max_depth=self.max_depth,
@@ -381,28 +383,29 @@ class DataParallelTreeLearner(CapabilityMixin):
             valid, small_hist) + (mask_left, mask_right)
 
     # ------------------------------------------------------------------
-    def _tree_impl(self, bins, state: GrowState, feature_mask, rand_seed,
-                   qscale):
+    def _tree_impl(self, bins, bins_fm, state: GrowState, feature_mask,
+                   rand_seed, qscale):
         """Grow the whole tree in one dispatch (``_grow_tree``)."""
         def step(state, rec, leaf, new_leaf, valid):
-            return self._mesh_split_body(bins, state, rec, leaf, new_leaf,
-                                         valid, feature_mask,
+            return self._mesh_split_body(bins, bins_fm, state, rec, leaf,
+                                         new_leaf, valid, feature_mask,
                                          feature_mask,
                                          rand_seed=rand_seed,
                                          qscale=qscale)
 
         return _grow_tree(state, step, self.L, self.B)
 
-    def _step_impl(self, bins, state: GrowState, leaf, new_leaf,
+    def _step_impl(self, bins, bins_fm, state: GrowState, leaf, new_leaf,
                    mask_left, mask_right, rand_seed, qscale):
         """Single split step with a host-chosen leaf — the stepwise path
         used when per-split host state steers the scan (per-node feature
         masks; CEGB and intermediate monotone have their own variants)."""
         rec = _record_at(state, leaf)
         valid = rec_valid(rec)
-        state = self._mesh_split_body(bins, state, rec, leaf, new_leaf,
-                                      valid, mask_left, mask_right,
-                                      rand_seed=rand_seed, qscale=qscale)
+        state = self._mesh_split_body(bins, bins_fm, state, rec, leaf,
+                                      new_leaf, valid, mask_left,
+                                      mask_right, rand_seed=rand_seed,
+                                      qscale=qscale)
         best = jnp.argmax(state.gain).astype(jnp.int32)
         return state, _record_at(state, best)
 
@@ -433,8 +436,8 @@ class DataParallelTreeLearner(CapabilityMixin):
                                 ordered=self._compacts())
         return state, _record_at(state, 0)
 
-    def _cegb_step_impl(self, bins, state, leaf, new_leaf, feature_mask,
-                        used, fetched, qscale):
+    def _cegb_step_impl(self, bins, bins_fm, state, leaf, new_leaf,
+                        feature_mask, used, fetched, qscale):
         """Mesh CEGB step (mirrors serial.py _cegb_step_fn_cached; the
         unfetched row sums reduce over the sharded row axis — XLA
         inserts the psum)."""
@@ -448,8 +451,8 @@ class DataParallelTreeLearner(CapabilityMixin):
                 on_leaf.astype(fetched.dtype)[:, None]
                 * jax.nn.one_hot(f, fetched.shape[1],
                                  dtype=fetched.dtype))
-            gl = _rows_go_left(bins, rec, self.meta, self._btab,
-                               self._bundled, self._has_cat)
+            gl = _rows_go_left(bins_fm, on_leaf.shape[0], rec, self.meta,
+                               self._btab, self._bundled, self._has_cat)
             unf = 1.0 - fetched2
             unf_left = jnp.einsum(
                 "r,rf->f", (on_leaf & gl).astype(jnp.float32), unf)
@@ -464,16 +467,17 @@ class DataParallelTreeLearner(CapabilityMixin):
         pen_r = _cegb_penalty(self.params, rec.right_total_count, used2,
                               self._cegb_coupled, unf_right, lazy)
         valid = rec_valid(rec)
-        state = self._mesh_split_body(bins, state, rec, leaf, new_leaf,
-                                      valid, feature_mask, feature_mask,
-                                      extra_trees=False, pen_left=pen_l,
-                                      pen_right=pen_r, qscale=qscale)
+        state = self._mesh_split_body(bins, bins_fm, state, rec, leaf,
+                                      new_leaf, valid, feature_mask,
+                                      feature_mask, extra_trees=False,
+                                      pen_left=pen_l, pen_right=pen_r,
+                                      qscale=qscale)
         best = jnp.argmax(state.gain).astype(jnp.int32)
         return state, _record_at(state, best), used2, fetched2
 
     # --- intermediate monotone (reference: monotone_constraints.hpp) --
-    def _mono_step_impl(self, bins, state, leaf, new_leaf, feature_mask,
-                        lmin, lmax, rmin, rmax, qscale):
+    def _mono_step_impl(self, bins, bins_fm, state, leaf, new_leaf,
+                        feature_mask, lmin, lmax, rmin, rmax, qscale):
         """The children's output bounds come from the host tracker
         (sibling-output based, monotone_constraints.hpp:543) instead of
         the mid-point rule baked into the stored candidate."""
@@ -484,9 +488,10 @@ class DataParallelTreeLearner(CapabilityMixin):
             cand_right_max=state.cand_right_max.at[leaf].set(rmax))
         rec = _record_at(state, leaf)
         valid = rec_valid(rec)
-        state = self._mesh_split_body(bins, state, rec, leaf, new_leaf,
-                                      valid, feature_mask, feature_mask,
-                                      extra_trees=False, qscale=qscale)
+        state = self._mesh_split_body(bins, bins_fm, state, rec, leaf,
+                                      new_leaf, valid, feature_mask,
+                                      feature_mask, extra_trees=False,
+                                      qscale=qscale)
         best = jnp.argmax(state.gain).astype(jnp.int32)
         return state, _record_at(state, best), state.gain
 
@@ -551,15 +556,15 @@ class DataParallelTreeLearner(CapabilityMixin):
                 "mesh.cegb_root", self._cegb_root_impl)
             self._cegb_step_fn = obs_compile.instrument_jit(
                 "mesh.cegb_step", self._cegb_step_impl,
-                donate_argnums=(1,))
+                donate_argnums=(2,))
         return self._cegb_root_fn(self.bins, gh, feature_mask,
                                   self._cegb_used, self._cegb_fetched,
                                   self._qscale)
 
     def _cegb_step(self, state, leaf, k, allowed, feature_mask):
         state, rec, self._cegb_used, self._cegb_fetched = \
-            self._cegb_step_fn(self.bins, state, jnp.int32(leaf),
-                               jnp.int32(k), feature_mask,
+            self._cegb_step_fn(self.bins, self.bins_fm, state,
+                               jnp.int32(leaf), jnp.int32(k), feature_mask,
                                self._cegb_used, self._cegb_fetched,
                                self._qscale)
         return state, rec
@@ -580,13 +585,13 @@ class DataParallelTreeLearner(CapabilityMixin):
         if self._mono_step_fn is None:
             self._mono_step_fn = obs_compile.instrument_jit(
                 "mesh.mono_step", self._mono_step_impl,
-                donate_argnums=(1,))
+                donate_argnums=(2,))
             self._rescan_fn = obs_compile.instrument_jit(
                 "mesh.rescan", self._rescan_impl,
                 donate_argnums=(0,))
         return self._mono_step_fn(
-            self.bins, state, jnp.int32(leaf), jnp.int32(k), feature_mask,
-            jnp.float32(bounds[0]), jnp.float32(bounds[1]),
+            self.bins, self.bins_fm, state, jnp.int32(leaf), jnp.int32(k),
+            feature_mask, jnp.float32(bounds[0]), jnp.float32(bounds[1]),
             jnp.float32(bounds[2]), jnp.float32(bounds[3]),
             self._qscale)
 
@@ -604,10 +609,11 @@ class DataParallelTreeLearner(CapabilityMixin):
         if self._step_fn is None:
             self._step_fn = obs_compile.instrument_jit(
                 "mesh.step", self._step_impl,
-                donate_argnums=(1,))
-        return self._step_fn(self.bins, state, jnp.int32(leaf),
-                             jnp.int32(k), mask_left, mask_right,
-                             jnp.int32(rand_seed), self._qscale)
+                donate_argnums=(2,))
+        return self._step_fn(self.bins, self.bins_fm, state,
+                             jnp.int32(leaf), jnp.int32(k), mask_left,
+                             mask_right, jnp.int32(rand_seed),
+                             self._qscale)
 
     # ------------------------------------------------------------------
     def _ensure_compiled(self):
@@ -616,7 +622,7 @@ class DataParallelTreeLearner(CapabilityMixin):
                 "mesh.root", self._root_impl)
             self._tree_fn = obs_compile.instrument_jit(
                 "mesh.tree", self._tree_impl,
-                donate_argnums=(1,))
+                donate_argnums=(2,))
 
     def _splittable(self, depth: int) -> bool:
         return self.max_depth <= 0 or depth < self.max_depth
@@ -690,8 +696,9 @@ class DataParallelTreeLearner(CapabilityMixin):
         # the device_get is the per-tree sync, so the scope covers the
         # real device time
         with obs.scope("tree::split_batches"):
-            state, recs = self._tree_fn(self.bins, state, feature_mask,
-                                        rand_seed, self._qscale)
+            state, recs = self._tree_fn(self.bins, self.bins_fm, state,
+                                        feature_mask, rand_seed,
+                                        self._qscale)
             # jaxlint: disable=JLT001 -- THE per-tree sync: the whole
             # tree's split records read back in one hop (scope comment)
             recs_h = jax.device_get(recs)
@@ -761,10 +768,14 @@ class DataParallelTreeLearner(CapabilityMixin):
     @staticmethod
     def _count_partition_splits(recs_h, applied: int) -> None:
         """``grow/partition_splits``: applied splits, each one partition
-        pass over every row; ``grow/partition_cat_splits``: those whose
+        pass over every row; ``grow/partition_cols_feature_major``:
+        those whose split column the pass read from the feature-major
+        copy of the bins (``grow.bins_by_feature``), which this learner
+        holds, so all of them; ``grow/partition_cat_splits``: those whose
         record is categorical, the only ones that need the table lookup
         of ``_go_left_by_bin``."""
         obs.inc("grow/partition_splits", applied)
+        obs.inc("grow/partition_cols_feature_major", applied)
         obs.inc("grow/partition_cat_splits",
                 int(recs_h.is_categorical[:applied].sum()))
 
@@ -881,14 +892,14 @@ class DataParallelTreeLearner(CapabilityMixin):
         out = jnp.zeros(L + 1, dtype=jnp.float32)
         return jax.lax.fori_loop(0, L - 1, body, out)[:L]
 
-    def _grow_one(self, bins, gh, feature_mask, seed, lr, qscale):
+    def _grow_one(self, bins, bins_fm, gh, feature_mask, seed, lr, qscale):
         """One tree inside the scan: root + whole-tree loop + leaf-output
         replay. Returns (records, per-row output deltas [N])."""
         barrier = jax.lax.optimization_barrier
         state, _ = self._root_impl(bins, gh, feature_mask, seed, qscale)
         state = barrier(state)
-        state, recs = self._tree_impl(bins, state, feature_mask, seed,
-                                      qscale)
+        state, recs = self._tree_impl(bins, bins_fm, state, feature_mask,
+                                      seed, qscale)
         state, recs = barrier((state, recs))
         outs = self._leaf_outputs_from_records(recs) * lr
         return recs, outs[state.leaf_of_row[:self.N]]
@@ -923,8 +934,8 @@ class DataParallelTreeLearner(CapabilityMixin):
             return g, h, None
         return jax.lax.optimization_barrier((g, h, ind))
 
-    def _many_impl(self, bins, score0, seeds, iters, feature_mask, lr,
-                   qkey=None, qctr0=None):
+    def _many_impl(self, bins, bins_fm, score0, seeds, iters, feature_mask,
+                   lr, qkey=None, qctr0=None):
         # optimization_barrier at every boundary that is a separate
         # dispatch in the per-iteration path: without them XLA fuses the
         # gradient math into the histogram kernels, changing rounding,
@@ -938,8 +949,8 @@ class DataParallelTreeLearner(CapabilityMixin):
             grad, hess = barrier(self._many_grad_fn(score))
             grad, hess, ind = self._apply_sampling(it, grad, hess)
             gh, qscale, ctr = self._step_gh(grad, hess, ind, qkey, ctr)
-            recs, delta = self._grow_one(bins, gh, feature_mask, seed,
-                                         lr, qscale)
+            recs, delta = self._grow_one(bins, bins_fm, gh, feature_mask,
+                                         seed, lr, qscale)
             grew = rec_valid(jax.tree_util.tree_map(
                 lambda a: a[0], recs))
             # after a stump step the score FREEZES: a quantized redraw
@@ -956,8 +967,8 @@ class DataParallelTreeLearner(CapabilityMixin):
         (score, ctr, _), recs = jax.lax.scan(step, carry, (seeds, iters))
         return (score, ctr), recs
 
-    def _many_impl_multi(self, bins, score0, seeds, iters, feature_mask,
-                         lr, qkey=None, qctr0=None):
+    def _many_impl_multi(self, bins, bins_fm, score0, seeds, iters,
+                         feature_mask, lr, qkey=None, qctr0=None):
         # K trees per iteration (multiclass): one gradient pass per step
         # over the [N, K] scores, then a statically unrolled per-class
         # tree (reference: the k-loop of GBDT::TrainOneIter)
@@ -976,8 +987,9 @@ class DataParallelTreeLearner(CapabilityMixin):
             for k in range(K):
                 gh, qscale, ctr = self._step_gh(grad[:, k], hess[:, k],
                                                 ind, qkey, ctr)
-                recs, delta = self._grow_one(bins, gh, feature_mask,
-                                             seeds_k[k], lr, qscale)
+                recs, delta = self._grow_one(bins, bins_fm, gh,
+                                             feature_mask, seeds_k[k], lr,
+                                             qscale)
                 grew = grew | rec_valid(jax.tree_util.tree_map(
                     lambda a: a[0], recs))
                 score = score.at[:, k].add(
@@ -1032,15 +1044,16 @@ class DataParallelTreeLearner(CapabilityMixin):
         lr = dev_f32(float(shrinkage))
         fn = self._many_multi_fn if seeds.ndim == 2 else self._many_fn
         if self._quantized:
-            out, recs = fn(self.bins, score0, seeds, iters, feature_mask,
-                           lr, self._quant_base_key, self._quant_ctr)
+            out, recs = fn(self.bins, self.bins_fm, score0, seeds, iters,
+                           feature_mask, lr, self._quant_base_key,
+                           self._quant_ctr)
             score_t, self._quant_ctr = out
             # the scan advanced the device counter once per tree slot;
             # keep the host mirror (the _quantize_stage assert) in step
             self._quant_ctr_host += int(seeds.size)
             self._count_discretized(int(seeds.size), self.N)
         else:
-            out, recs = fn(self.bins, score0, seeds, iters, feature_mask,
-                           lr)
+            out, recs = fn(self.bins, self.bins_fm, score0, seeds, iters,
+                           feature_mask, lr)
             score_t = out[0]
         return score_t, recs

@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..config import Config
 from ..io.dataset import BinnedDataset
 from ..obs.registry import registry as obs
+from ..treelearner.grow import stage_bins_by_feature
 from ..utils import log
 from .data_parallel import DataParallelTreeLearner
 
@@ -161,6 +162,7 @@ class DistributedDataParallelLearner(DataParallelTreeLearner):
         with obs.scope("io::stage_bins_device"):
             self.bins = jax.make_array_from_process_local_data(
                 NamedSharding(mesh, P(self.axis, None)), local_bins)
+        self.bins_fm = stage_bins_by_feature(self.bins)
         self._init_cegb(config)
         self._init_monotone(config)
 

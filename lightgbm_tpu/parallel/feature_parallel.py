@@ -21,6 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..io.dataset import BinnedDataset
 from ..obs.registry import registry as obs
 from ..ops.split import FeatureMeta
+from ..treelearner.grow import stage_bins_by_feature
 from .data_parallel import DataParallelTreeLearner
 
 
@@ -54,6 +55,7 @@ class FeatureParallelTreeLearner(DataParallelTreeLearner):
         with obs.scope("io::stage_bins_device"):
             self.bins = jax.device_put(
                 bins_host, NamedSharding(mesh, P(None, self.axis)))
+        self.bins_fm = stage_bins_by_feature(self.bins)
         self.row_sharding = NamedSharding(mesh, P())  # rows replicated
         # feature metadata padded to Fp: padded features are trivial
         # (num_bin 1 → never valid thresholds)

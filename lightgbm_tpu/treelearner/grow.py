@@ -16,7 +16,9 @@ handled as:
 - **row->leaf partition**: a full-length ``leaf_of_row`` vector updated
   by a vectorized compare on the split feature's bin column (the
   analogue of the reference's DataPartition::Split,
-  src/treelearner/data_partition.hpp:21 / cuda_data_partition.cu:288).
+  src/treelearner/data_partition.hpp:21 / cuda_data_partition.cu:288),
+  read from a feature-major copy of the bins (``bins_by_feature``) that
+  every learner holding its bins on the device keeps beside them.
 - **rows ordered by leaf** (``_partition_order``; learners that compact,
   which is every learner on one device): ``order`` holds the row
   numbers with each leaf's rows contiguous and ascending, ``seg_begin``
@@ -39,6 +41,7 @@ using a device-resident per-leaf depth vector.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -49,6 +52,8 @@ from ..io.binning import MissingType
 from ..io.dataset import BinnedDataset
 from ..io.efb import member_bin
 from ..models.tree import Tree
+from ..obs import compile as obs_compile
+from ..obs.registry import registry as obs
 from ..ops.histogram import subtract_histogram
 from ..ops.split import SplitInfo, find_best_split, make_rand_bins
 
@@ -315,13 +320,14 @@ def _partition_rec(rec: SplitRecord, has_cat: bool) -> SplitRecord:
     return rec._replace(is_categorical=None, cat_mask=None)
 
 
-def _rows_go_left(bins, rec: SplitRecord, meta, btab, bundled: bool,
-                  has_cat: bool) -> jnp.ndarray:
-    """[R] bool: the rows that ``rec`` sends left, over all of ``bins``
-    (the caller masks by leaf)."""
+def _rows_go_left(bins_fm, rows: int, rec: SplitRecord, meta, btab,
+                  bundled: bool, has_cat: bool) -> jnp.ndarray:
+    """[rows] bool: the rows that ``rec`` sends left, over every row of
+    ``bins_fm`` (``bins_by_feature`` of the learner's bins; the caller
+    masks by leaf)."""
     rec = _partition_rec(rec, has_cat)
     f = jnp.maximum(rec.feature, 0)
-    col = _partition_col(bins, f, meta, btab, bundled)
+    col = _partition_col(bins_fm, rows, f, meta, btab, bundled)
     return _go_left_by_bin(col, rec.threshold_bin, rec.default_left,
                            meta.missing_type[f], meta.num_bin[f] - 1,
                            meta.zero_bin[f], rec.is_categorical,
@@ -364,14 +370,72 @@ def build_bundle_tables(dataset: BinnedDataset, Fp: int) -> BundleTables:
         num_bins=jnp.asarray(num_bins), zero_fix=jnp.asarray(zero_fix))
 
 
+_FM_TILE = (32, 128)   # rows x lanes of a u8 tile on the TPU
+
+
+def bins_by_feature(bins, shards: int = 1):
+    """The feature-major copy of the row-major bins ``[R, C]`` that the
+    partition reads the split column from: ``[C, shards, r' / 128,
+    128]``, each of the ``shards`` blocks of r = R / shards rows (the
+    rows of one device, where they are split over a mesh) padded to r'
+    rows of whole tiles, so that a column's bytes of one block are tiles
+    of that column and block alone. A column of the row-major table is
+    one byte in every 128-byte tile row, and the comparison pass read
+    them all: 128 MB a split to get 1 MB at 1M rows, 0.48 ms on the v5e
+    (chip traces of the Bosch cells)."""
+    R, C = bins.shape
+    r = R // shards
+    sub, lanes = _FM_TILE
+    pad = -r % (sub * lanes)
+    # one transpose of [tile rows, 128, C'] with C' a multiple of 128:
+    # the v5e's compiler took 25-31 s over a transpose of the plain
+    # table reshaped after it, or of C = 60 columns, 0.3-0.6 s over this
+    # at every cell's shape (host-side compiles for the v5e)
+    rows = jnp.pad(bins.reshape(shards, r, C),
+                   ((0, 0), (0, pad), (0, -C % lanes)))
+    return rows.reshape(-1, lanes, rows.shape[2]).transpose(2, 0, 1)[:C] \
+        .reshape(C, shards, (r + pad) // lanes, lanes)
+
+
+@functools.lru_cache(maxsize=None)
+def _bins_by_feature_fn():
+    return obs_compile.instrument_jit("io.bins_by_feature",
+                                      bins_by_feature, static_argnums=(1,))
+
+
+def stage_bins_by_feature(bins):
+    """``bins_by_feature`` of a learner's staged bins, a block for each
+    device its rows are split over, built on the device (each block
+    where its rows are) under the host span
+    ``io::stage_bins_by_feature``, whose readiness the stage timer
+    attributes as it does every stage's."""
+    shards = bins.shape[0] // bins.sharding.shard_shape(bins.shape)[0]
+    with obs.scope("io::stage_bins_by_feature"):
+        bins_fm = _bins_by_feature_fn()(bins, shards)
+        obs.watch_ready("io::stage_bins_by_feature", bins_fm)
+    return bins_fm
+
+
+def _feature_column(bins_fm, rows: int, c):
+    """Column ``c`` of the bins, ``[rows]``, from ``bins_by_feature``:
+    each block's rows, its pad left out. One block is read flat, which
+    the v5e's compiler lays out as it slices it; a ``[1, r']`` view of
+    it costs a second copy of the column in another tiling."""
+    col = jax.lax.dynamic_index_in_dim(bins_fm, c, 0, keepdims=False)
+    shards = col.shape[0]
+    if shards == 1:
+        return col.reshape(-1)[:rows]
+    return col.reshape(shards, -1)[:, :rows // shards].reshape(rows)
+
+
 @jax.named_scope("obs_partition")
-def _partition_col(bins, f, meta, btab, bundled: bool):
+def _partition_col(bins_fm, rows: int, f, meta, btab, bundled: bool):
     """The split feature's ORIGINAL bin value per row (unbundling a
     member's slots of its bundle column when bundled, by comparisons
-    alone; identity otherwise)."""
+    alone; identity otherwise), read from the feature-major copy."""
     if not bundled:
-        return jnp.take(bins, f, axis=1).astype(jnp.int32)
-    raw = jnp.take(bins, btab.group_of[f], axis=1).astype(jnp.int32)
+        return _feature_column(bins_fm, rows, f).astype(jnp.int32)
+    raw = _feature_column(bins_fm, rows, btab.group_of[f]).astype(jnp.int32)
     return member_bin(raw, btab.first_bin[f], btab.num_bins[f],
                       meta.zero_bin[f], btab.zero_fix[f], where=jnp.where)
 
@@ -629,8 +693,8 @@ def _subtract_child_hists(state: GrowState, rec: SplitRecord, leaf,
                              smaller_is_left, valid)
 
 
-def _split_step(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
-                valid, mask_left, mask_right, meta, params, btab,
+def _split_step(bins, bins_fm, state: GrowState, rec: SplitRecord, leaf,
+                new_leaf, valid, mask_left, mask_right, meta, params, btab,
                 child_hists, *, bundled: bool, has_cat: bool,
                 max_depth: int, extra_trees: bool, row_sharding=None,
                 children_allowed=None, rand_seed=0, pen_left=None,
@@ -639,6 +703,7 @@ def _split_step(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
     both children: the one split step of every whole-tree loop and
     host-stepped driver. ``valid`` guards every state write (loop steps
     after the no-more-splits point must leave state untouched).
+    ``bins_fm`` is ``bins_by_feature(bins)``, which the partition reads.
 
     ``child_hists(bins, state, rec, leaf, new_leaf, leaf_of_row,
     smaller_is_left, valid, mask_left, mask_right, qscale)`` is the
@@ -648,7 +713,8 @@ def _split_step(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
     rows ordered by the new leaves already. ``row_sharding`` pins the new
     partition to the mesh learners' row layout. ``children_allowed``
     None means: derive from the device-side leaf_depth."""
-    gl = _rows_go_left(bins, rec, meta, btab, bundled, has_cat)
+    gl = _rows_go_left(bins_fm, state.leaf_of_row.shape[0], rec, meta,
+                       btab, bundled, has_cat)
     with jax.named_scope("obs_partition"):
         on_leaf = state.leaf_of_row == leaf
         leaf_of_row = jnp.where(valid & on_leaf & ~gl, new_leaf,

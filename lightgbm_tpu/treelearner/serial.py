@@ -49,7 +49,8 @@ from .grow import (GrowState, SplitRecord, _compact_child_hist,
                    _grow_tree, _maybe_rand_bins, _record_at,
                    _rows_go_left, _split_step, _store_info,
                    _subtract_child_hists, apply_split_record,
-                   build_bundle_tables, make_root_state, record_is_valid)
+                   build_bundle_tables, make_root_state, record_is_valid,
+                   stage_bins_by_feature)
 
 
 # ----------------------------------------------------------------------
@@ -126,8 +127,8 @@ def _unbundled(bhist, meta, btab, totals, B: int):
             btab.zero_fix, meta.zero_bin, totals, B)
 
 
-def _split_body(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
-                valid, mask_left, mask_right, meta, params, btab, *,
+def _split_body(bins, bins_fm, state: GrowState, rec: SplitRecord, leaf,
+                new_leaf, valid, mask_left, mask_right, meta, params, btab, *,
                 B: int, Bg: int, bundled: bool,
                 hist_impl: tuple = ("auto", False), **step) -> GrowState:
     """grow.py's split step over this learner's histogram: the smaller
@@ -154,9 +155,9 @@ def _split_body(bins, state: GrowState, rec: SplitRecord, leaf, new_leaf,
             state, rec, leaf, new_leaf, leaf_of_row, smaller_is_left,
             valid, small_hist) + (mask_left, mask_right)
 
-    return _split_step(bins, state, rec, leaf, new_leaf, valid, mask_left,
-                       mask_right, meta, params, btab, child_hists,
-                       bundled=bundled, **step)
+    return _split_step(bins, bins_fm, state, rec, leaf, new_leaf, valid,
+                       mask_left, mask_right, meta, params, btab,
+                       child_hists, bundled=bundled, **step)
 
 
 @functools.lru_cache(maxsize=None)
@@ -195,11 +196,11 @@ def _step_fn_cached(B: int, Bg: int, bundled: bool,
     """Per-split step (host chooses the leaf): used when per-node feature
     masks (interaction constraints / bynode sampling) force a host
     round-trip per split."""
-    def step(bins, state: GrowState, leaf, new_leaf, children_allowed,
-             mask_left, mask_right, rand_seed, qscale, meta, params,
-             btab):
+    def step(bins, bins_fm, state: GrowState, leaf, new_leaf,
+             children_allowed, mask_left, mask_right, rand_seed, qscale,
+             meta, params, btab):
         rec = _record_at(state, leaf)
-        state = _split_body(bins, state, rec, leaf, new_leaf,
+        state = _split_body(bins, bins_fm, state, rec, leaf, new_leaf,
                             jnp.asarray(True), mask_left, mask_right,
                             meta, params, btab, B=B, Bg=Bg,
                             bundled=bundled, max_depth=0,
@@ -211,7 +212,7 @@ def _step_fn_cached(B: int, Bg: int, bundled: bool,
         return state, _record_at(state, best)
 
     return obs_compile.instrument_jit("serial.step", step,
-                                      donate_argnums=(1,))
+                                      donate_argnums=(2,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -257,9 +258,9 @@ def _cegb_step_fn_cached(B: int, Bg: int, bundled: bool,
     reference: candidates stored for *other* leaves are not retroactively
     refunded when a coupled feature first becomes used — they keep the
     penalty until re-scanned as children (pessimistic ordering only)."""
-    def step(bins, state: GrowState, leaf, new_leaf, children_allowed,
-             feature_mask, used, fetched, coupled, lazy, qscale, meta,
-             params, btab):
+    def step(bins, bins_fm, state: GrowState, leaf, new_leaf,
+             children_allowed, feature_mask, used, fetched, coupled, lazy,
+             qscale, meta, params, btab):
         rec = _record_at(state, leaf)
         f = jnp.maximum(rec.feature, 0)
         used2 = used.at[f].set(True)
@@ -272,7 +273,8 @@ def _cegb_step_fn_cached(B: int, Bg: int, bundled: bool,
                 on_leaf.astype(fetched.dtype)[:, None]
                 * jax.nn.one_hot(f, fetched.shape[1],
                                  dtype=fetched.dtype))
-            gl = _rows_go_left(bins, rec, meta, btab, bundled, has_cat)
+            gl = _rows_go_left(bins_fm, on_leaf.shape[0], rec, meta, btab,
+                               bundled, has_cat)
             unf = 1.0 - fetched2
             unf_left = jnp.einsum(
                 "r,rf->f", (on_leaf & gl).astype(jnp.float32), unf)
@@ -286,7 +288,7 @@ def _cegb_step_fn_cached(B: int, Bg: int, bundled: bool,
                               coupled, unf_left, lazy)
         pen_r = _cegb_penalty(params, rec.right_total_count, used2,
                               coupled, unf_right, lazy)
-        state = _split_body(bins, state, rec, leaf, new_leaf,
+        state = _split_body(bins, bins_fm, state, rec, leaf, new_leaf,
                             jnp.asarray(True), feature_mask, feature_mask,
                             meta, params, btab, B=B, Bg=Bg,
                             bundled=bundled, max_depth=0,
@@ -299,7 +301,7 @@ def _cegb_step_fn_cached(B: int, Bg: int, bundled: bool,
         return state, _record_at(state, best), used2, fetched2
 
     return obs_compile.instrument_jit("serial.cegb_step", step,
-                                      donate_argnums=(1,))
+                                      donate_argnums=(2,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -310,16 +312,16 @@ def _mono_step_fn_cached(B: int, Bg: int, bundled: bool,
     children's output bounds come from the host tracker (sibling-output
     based, monotone_constraints.hpp:543) instead of the mid-point rule
     baked into the stored candidate."""
-    def step(bins, state: GrowState, leaf, new_leaf, children_allowed,
-             feature_mask, lmin, lmax, rmin, rmax, qscale, meta, params,
-             btab):
+    def step(bins, bins_fm, state: GrowState, leaf, new_leaf,
+             children_allowed, feature_mask, lmin, lmax, rmin, rmax,
+             qscale, meta, params, btab):
         state = state._replace(
             cand_left_min=state.cand_left_min.at[leaf].set(lmin),
             cand_left_max=state.cand_left_max.at[leaf].set(lmax),
             cand_right_min=state.cand_right_min.at[leaf].set(rmin),
             cand_right_max=state.cand_right_max.at[leaf].set(rmax))
         rec = _record_at(state, leaf)
-        state = _split_body(bins, state, rec, leaf, new_leaf,
+        state = _split_body(bins, bins_fm, state, rec, leaf, new_leaf,
                             jnp.asarray(True), feature_mask, feature_mask,
                             meta, params, btab, B=B, Bg=Bg,
                             bundled=bundled, max_depth=0,
@@ -331,7 +333,7 @@ def _mono_step_fn_cached(B: int, Bg: int, bundled: bool,
         return state, _record_at(state, best), state.gain
 
     return obs_compile.instrument_jit("serial.mono_step", step,
-                                      donate_argnums=(1,))
+                                      donate_argnums=(2,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -396,7 +398,7 @@ def _forced_fn_cached(B: int, Bg: int, bundled: bool,
     leaf's stored histogram instead of a best-gain scan, then applied
     through the normal split body so the children get their candidate
     scans."""
-    def forced(bins, state: GrowState, leaf, new_leaf, f, tbin,
+    def forced(bins, bins_fm, state: GrowState, leaf, new_leaf, f, tbin,
                children_allowed, feature_mask, rand_seed, qscale, meta,
                params, btab):
         row = state.hists[leaf][f]                   # [B, 4]
@@ -425,7 +427,7 @@ def _forced_fn_cached(B: int, Bg: int, bundled: bool,
             right_count=right[2], right_total_count=right[3],
             right_output=out_r)
         ok = (left[3] > 0.5) & (right[3] > 0.5)
-        state = _split_body(bins, state, rec, leaf, new_leaf, ok,
+        state = _split_body(bins, bins_fm, state, rec, leaf, new_leaf, ok,
                             feature_mask, feature_mask, meta, params,
                             btab, B=B, Bg=Bg, bundled=bundled,
                             max_depth=0, extra_trees=extra_trees,
@@ -435,7 +437,7 @@ def _forced_fn_cached(B: int, Bg: int, bundled: bool,
         return state, rec, ok
 
     return obs_compile.instrument_jit("serial.forced", forced,
-                                      donate_argnums=(1,))
+                                      donate_argnums=(2,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -449,12 +451,12 @@ def _fused_fn_cached(L: int, B: int, Bg: int, bundled: bool,
     / XGBoost-GPU "whole pipeline on the accelerator" move; the serial
     twin of the mesh learner's ``_tree_impl``). ``start_leaf`` /
     ``max_splits`` continue after a forced-split preamble."""
-    def fused(bins, state: GrowState, start_leaf, max_splits,
+    def fused(bins, bins_fm, state: GrowState, start_leaf, max_splits,
               feature_mask, rand_seed, qscale, meta, params, btab):
         def step(state, rec, leaf, new_leaf, valid):
-            return _split_body(bins, state, rec, leaf, new_leaf, valid,
-                               feature_mask, feature_mask, meta, params,
-                               btab, B=B, Bg=Bg, bundled=bundled,
+            return _split_body(bins, bins_fm, state, rec, leaf, new_leaf,
+                               valid, feature_mask, feature_mask, meta,
+                               params, btab, B=B, Bg=Bg, bundled=bundled,
                                max_depth=max_depth,
                                extra_trees=extra_trees, has_cat=has_cat,
                                hist_impl=hist_impl, rand_seed=rand_seed,
@@ -463,7 +465,7 @@ def _fused_fn_cached(L: int, B: int, Bg: int, bundled: bool,
         return _grow_tree(state, step, L, B, start_leaf, max_splits)
 
     return obs_compile.instrument_jit("serial.fused_tree", fused,
-                                      donate_argnums=(1,))
+                                      donate_argnums=(2,))
 
 
 class SerialTreeLearner(CapabilityMixin):
@@ -505,6 +507,7 @@ class SerialTreeLearner(CapabilityMixin):
         bins_host[:N, :ncols if self._bundled else F] = dataset.bins
         with obs.scope("io::stage_bins_device"):
             self.bins = jnp.asarray(bins_host)
+        self.bins_fm = stage_bins_by_feature(self.bins)
         self._leaf_of_row0 = jnp.concatenate([
             jnp.zeros(N, dtype=jnp.int32),
             jnp.full((self.R - N,), -1, dtype=jnp.int32)])
@@ -596,8 +599,9 @@ class SerialTreeLearner(CapabilityMixin):
                                    self._extra_trees, self._has_cat,
                                    self._hist_impl)
             allowed = self._splittable(int(tree.leaf_depth[leaf]) + 1)
-            state, rec, ok = fn(self.bins, state, jnp.int32(leaf),
-                                jnp.int32(next_leaf), jnp.int32(inner),
+            state, rec, ok = fn(self.bins, self.bins_fm, state,
+                                jnp.int32(leaf), jnp.int32(next_leaf),
+                                jnp.int32(inner),
                                 jnp.int32(tbin), jnp.asarray(allowed),
                                 feature_mask, rand_seed, self._qscale,
                                 self.meta, self.params, self._btab)
@@ -695,8 +699,9 @@ class SerialTreeLearner(CapabilityMixin):
             return state
         fn = self._fused_fn()
         with obs.scope("tree::split_batches"):
-            state, recs = fn(self.bins, state, dev_i32(next_leaf),
-                             dev_i32(max_splits), feature_mask,
+            state, recs = fn(self.bins, self.bins_fm, state,
+                             dev_i32(next_leaf), dev_i32(max_splits),
+                             feature_mask,
                              rand_seed, self._qscale, self.meta,
                              self.params, self._btab)
             # jaxlint: disable=JLT001 -- THE per-tree host sync: the
@@ -730,7 +735,7 @@ class SerialTreeLearner(CapabilityMixin):
                                   self._cegb_has_lazy,
                                   self._has_cat, self._hist_impl)
         state, rec, self._cegb_used, self._cegb_fetched = fn(
-            self.bins, state, jnp.int32(leaf), jnp.int32(k),
+            self.bins, self.bins_fm, state, jnp.int32(leaf), jnp.int32(k),
             jnp.asarray(allowed), feature_mask,
             self._cegb_used, self._cegb_fetched, self._cegb_coupled,
             self._cegb_lazy, self._qscale, self.meta, self.params,
@@ -749,8 +754,8 @@ class SerialTreeLearner(CapabilityMixin):
     def _mono_step(self, state, leaf, k, allowed, feature_mask, bounds):
         fn = _mono_step_fn_cached(self.B, self.Bg, self._bundled,
                                   self._has_cat, self._hist_impl)
-        return fn(self.bins, state, jnp.int32(leaf), jnp.int32(k),
-                  jnp.asarray(allowed), feature_mask,
+        return fn(self.bins, self.bins_fm, state, jnp.int32(leaf),
+                  jnp.int32(k), jnp.asarray(allowed), feature_mask,
                   jnp.float32(bounds[0]), jnp.float32(bounds[1]),
                   jnp.float32(bounds[2]), jnp.float32(bounds[3]),
                   self._qscale, self.meta, self.params, self._btab)
@@ -780,6 +785,6 @@ class SerialTreeLearner(CapabilityMixin):
     def _node_step(self, state, leaf, k, allowed, mask_left, mask_right,
                    rand_seed):
         return self._step_fn()(
-            self.bins, state, jnp.int32(leaf), jnp.int32(k),
+            self.bins, self.bins_fm, state, jnp.int32(leaf), jnp.int32(k),
             jnp.asarray(allowed), mask_left, mask_right, rand_seed,
             self._qscale, self.meta, self.params, self._btab)

@@ -23,6 +23,7 @@ from lightgbm_tpu.obs.registry import registry
 from lightgbm_tpu.ops import histogram
 from lightgbm_tpu.parallel.data_parallel import (DataParallelTreeLearner,
                                                  make_mesh)
+from lightgbm_tpu.treelearner.grow import bins_by_feature
 from lightgbm_tpu.treelearner.serial import SerialTreeLearner
 from lightgbm_tpu.utils import next_pow2
 
@@ -48,7 +49,7 @@ def _mesh_programs(hist_backend):
     args = (lrn.bins, jax.ShapeDtypeStruct((lrn.R, 4), jnp.float32),
             lrn._sample_features(), jnp.int32(1), lrn._qs_ones)
     state, _ = jax.eval_shape(lrn._root_fn, *args)
-    tree = lrn._tree_fn.lower(lrn.bins, state, *args[2:])
+    tree = lrn._tree_fn.lower(lrn.bins, lrn.bins_fm, state, *args[2:])
     return (lrn._root_fn.lower(*args).as_text(debug_info=True),
             tree.as_text(debug_info=True))
 
@@ -68,7 +69,8 @@ def lowered():
                  serial.params, serial._btab)
     state, _ = jax.eval_shape(serial._root_fn, *root_args)
     serial_tree = serial._fused_fn().lower(
-        root_args[0], state, sds((), jnp.int32), sds((), jnp.int32),
+        root_args[0], jax.eval_shape(bins_by_feature, root_args[0]), state,
+        sds((), jnp.int32), sds((), jnp.int32),
         root_args[3], sds((), jnp.int32), sds((2,), jnp.float32),
         serial.meta, serial.params, serial._btab)
     return {"root": root, "tree": tree, "tree_onehot": tree_onehot,

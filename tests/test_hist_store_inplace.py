@@ -36,7 +36,7 @@ from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.dataset import BinnedDataset
 from lightgbm_tpu.parallel import DataParallelTreeLearner, make_mesh
 from lightgbm_tpu.treelearner.grow import (_split_hist_store,
-                                           _window_sizes)
+                                           _window_sizes, bins_by_feature)
 
 
 def describe_v5e():
@@ -88,8 +88,9 @@ def compile_tree_program(topo, features: int, leaves: int,
                            row if a.shape == (rows,) else
                            learner.gh_sharding if a.shape == (rows, 4)
                            else rep), state)
-        lowered = jax.jit(learner._tree_impl, donate_argnums=(1,)).lower(
-            bins, state, fmask, seed, qscale)
+        fm = jax.eval_shape(bins_by_feature, bins)
+        lowered = jax.jit(learner._tree_impl, donate_argnums=(2,)).lower(
+            bins, spec(fm.shape, fm.dtype), state, fmask, seed, qscale)
         return lowered.compile().as_text(), state.hists.shape
 
 
@@ -203,8 +204,8 @@ def test_the_tile_loop_copies_no_whole_array_on_v5e(topo, quantized):
     """The smaller child's tile loop, inside the whole-tree loop: one
     ``hist_kernel`` call in the tree program (the ladder had one a
     bucket), continuing its accumulator in place, and no copy of the
-    bin matrix, of the rows ordered by leaf or of the store in a loop
-    body."""
+    bin matrix, of its feature-major copy, of the rows ordered by leaf
+    or of the store in a loop body."""
     rows, features = 65536, 64
     hlo, store = compile_tree_program(topo, features, 31, rows,
                                       quantized=quantized)
@@ -216,6 +217,7 @@ def test_the_tile_loop_copies_no_whole_array_on_v5e(topo, quantized):
         in kernels[0]
     assert "obs_bucket_" not in hlo
     shapes = ["u8[%d,%d]" % (rows, features),
+              "u8[%d,1,%d,128]" % (features, rows // 128),
               "s32[%d]" % (rows + _window_sizes(rows)[0]),
               "%s[%s]" % ("s32" if quantized else "f32",
                           ",".join(str(d) for d in store))]
@@ -335,7 +337,8 @@ def _data_parallel_step(valid: bool):
     before = np.asarray(state.hists)
     leaf, new_leaf = 0, 1
     out = jax.jit(learner._mesh_split_body)(
-        learner.bins, state, rec, jnp.int32(leaf), jnp.int32(new_leaf),
+        learner.bins, learner.bins_fm, state, rec, jnp.int32(leaf),
+        jnp.int32(new_leaf),
         jnp.asarray(valid), fmask, fmask, qscale=learner._qscale)
     part = np.asarray(out.leaf_of_row)
     bins, gh = np.asarray(learner.bins), np.asarray(gh)

@@ -14,6 +14,7 @@ import pytest
 
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.dataset import BinnedDataset
+from lightgbm_tpu.treelearner.grow import bins_by_feature
 from lightgbm_tpu.treelearner.serial import SerialTreeLearner
 
 N_BIG = 1_000_000
@@ -55,6 +56,10 @@ def _root_args(lrn):
     )
 
 
+def _fm_sds(bins_sds):
+    return jax.eval_shape(bins_by_feature, bins_sds)
+
+
 def _hlo_bytes(lowered) -> int:
     return len(lowered.compiler_ir("hlo").as_serialized_hlo_module_proto())
 
@@ -68,7 +73,8 @@ def test_fused_tree_hlo_small(learner):
     args = _root_args(learner)
     state_sds, _ = jax.eval_shape(learner._root_fn, *args)
     lowered = learner._fused_fn().lower(
-        args[0], state_sds, _sds((), jnp.int32), _sds((), jnp.int32),
+        args[0], _fm_sds(args[0]), state_sds, _sds((), jnp.int32),
+        _sds((), jnp.int32),
         args[3], _sds((), jnp.int32), _sds((2,), jnp.float32),
         learner.meta, learner.params, learner._btab)
     n = _hlo_bytes(lowered)
@@ -79,7 +85,8 @@ def test_stepwise_hlo_small(learner):
     args = _root_args(learner)
     state_sds, _ = jax.eval_shape(learner._root_fn, *args)
     fn = learner._step_fn()
-    lowered = fn.lower(args[0], state_sds, _sds((), jnp.int32),
+    lowered = fn.lower(args[0], _fm_sds(args[0]), state_sds,
+                       _sds((), jnp.int32),
                        _sds((), jnp.int32), _sds((), jnp.bool_),
                        args[3], args[3], _sds((), jnp.int32),
                        _sds((2,), jnp.float32),

@@ -308,8 +308,8 @@ def _lowered_grower(linear: bool):
     state = jax.eval_shape(lrn._root_impl, *args)[0]
     return (jax.jit(lrn._root_impl).lower(*args).as_text(),
             jax.jit(lrn._tree_impl).lower(
-                lrn.bins, state, lrn._sample_features(), jnp.int32(1),
-                lrn._qs_ones).as_text())
+                lrn.bins, lrn.bins_fm, state, lrn._sample_features(),
+                jnp.int32(1), lrn._qs_ones).as_text())
 
 
 def test_linear_tree_leaves_the_lowered_grower_as_it_was():

@@ -112,8 +112,10 @@ class TestVotingParallel:
         qs_sds = jax.ShapeDtypeStruct((2,), jnp.float32)
         state_sds, _ = jax.eval_shape(dist._root_impl, bins_sds, gh_sds,
                                       mask_sds, i32, qs_sds)
+        fm_sds = jax.ShapeDtypeStruct(dist.bins_fm.shape,
+                                      dist.bins_fm.dtype)
         lowered = jax.jit(dist._step_impl).lower(
-            bins_sds, state_sds, i32, i32, mask_sds, mask_sds, i32,
+            bins_sds, fm_sds, state_sds, i32, i32, mask_sds, mask_sds, i32,
             qs_sds)
         hlo = lowered.as_text()
         F, B, V = dist.F, dist.B, dist.n_voted

@@ -108,8 +108,8 @@ def _serial_step(categorical: bool, tmp_path):
         max_depth=ln.max_depth, extra_trees=False, has_cat=ln._has_cat,
         hist_impl=ln._hist_impl)
     jaxpr = jax.make_jaxpr(step)(
-        ln.bins, state, rec, jnp.int32(0), jnp.int32(1), jnp.asarray(True),
-        fmask, fmask, ln.meta, ln.params, ln._btab)
+        ln.bins, ln.bins_fm, state, rec, jnp.int32(0), jnp.int32(1),
+        jnp.asarray(True), fmask, fmask, ln.meta, ln.params, ln._btab)
     return jaxpr.jaxpr, ln.B
 
 
@@ -123,8 +123,8 @@ def _mesh_step(categorical: bool, tmp_path):
         ln._root_impl, ln.bins, gh, fmask, jnp.int32(0), ln._qscale)
     jaxpr = jax.make_jaxpr(
         functools.partial(ln._mesh_split_body, qscale=ln._qscale))(
-        ln.bins, state, rec, jnp.int32(0), jnp.int32(1), jnp.asarray(True),
-        fmask, fmask)
+        ln.bins, ln.bins_fm, state, rec, jnp.int32(0), jnp.int32(1),
+        jnp.asarray(True), fmask, fmask)
     return jaxpr.jaxpr, ln.B
 
 

@@ -263,7 +263,7 @@ def _lowered_tree(changed):
                            lrn._sample_features(), jnp.int32(1),
                            lrn._qs_ones)[0]
     return jax.jit(lrn._tree_impl).lower(
-        lrn.bins, state, lrn._sample_features(), jnp.int32(1),
+        lrn.bins, lrn.bins_fm, state, lrn._sample_features(), jnp.int32(1),
         lrn._qs_ones).as_text()
 
 

@@ -260,7 +260,8 @@ def test_invalid_step_leaves_the_store_and_the_order_as_they_were():
 
     def step(valid):
         return jax.jit(lambda s: _split_body(
-            lrn.bins, s, _record_at(s, 0), jnp.int32(0), jnp.int32(1),
+            lrn.bins, lrn.bins_fm, s, _record_at(s, 0), jnp.int32(0),
+            jnp.int32(1),
             jnp.asarray(valid), fmask, fmask, lrn.meta, lrn.params,
             lrn._btab, B=lrn.B, Bg=lrn.Bg, bundled=True, max_depth=0,
             extra_trees=False, has_cat=lrn._has_cat,

@@ -149,8 +149,8 @@ def main() -> None:
             learner.bins, gh, feature_mask, jnp.int32(k + 1),
             learner._qscale))
         state, recs = fenced("phase::tree_fn", lambda: learner._tree_fn(
-            learner.bins, state, feature_mask, jnp.int32(k + 1),
-            learner._qscale))
+            learner.bins, learner.bins_fm, state, feature_mask,
+            jnp.int32(k + 1), learner._qscale))
         with registry.scope("phase::readback"):
             jax.device_get(recs)
 
