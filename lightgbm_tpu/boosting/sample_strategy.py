@@ -10,31 +10,31 @@ rescaled (grad, hess) — the learner multiplies gradients by the indicator
 and counts in-bag rows via its histogram count channel, which is the same
 masked-row trick the CUDA learner's bagging path uses.
 
-What the mask costs, and where the in-bag counts live. The grower
-(treelearner/grow.py) ignores the bag: ``GrowState.order`` holds every
-row, the smaller child is the one with fewer rows in all, and each
-histogram pass, gather and window reorder visits the out-of-bag rows
-too, whose gradient and hessian are zero. On a TPU v5e at 1,000,000 x
-968 under GOSS (top 20% + 10% of the rest: the benchmark's cell
-``bosch-train-goss``, PERF.md, PR 35) a sampled iteration takes 3.11 s
-where an unsampled one takes 3.05 s: 13% of the rows the smaller
-children's histogram passes visit carry weight
-(``grow/hist_rows_in_bag`` over ``grow/hist_rows_bucketed``), and the
-sampling itself is 1.5 ms, 1.4 of them ``lax.top_k``, which is a full
-sort of the weights there (and 24 s of compiling). Users who set GOSS get
-no speed from it until the grower learns to skip rows that carry no
-weight. The in-bag indicator travels as channel 2 of ``gh``, so a split
-record's ``left_count``/``right_count`` and a tree's
-``internal_count``/``leaf_count`` (the model text's) are in-bag counts,
-while ``left_total_count``/``right_total_count`` count every row; the
-score update covers every row through ``leaf_of_row``. The sampling is
-named ``obs_goss`` on the device clock, and while the stage timer is on
-``sample/goss_trees`` counts the trees of the iterations GOSS sampled
-(docs/OBSERVABILITY.md). Plain bagging's draw is ``obs_bag`` there, its
+Where the in-bag counts live, and which rows the grower visits. The
+in-bag indicator travels as channel 2 of ``gh``, so a split record's
+``left_count``/``right_count`` and a tree's ``internal_count``/
+``leaf_count`` (the model text's) are in-bag counts, and the score
+update covers every row through ``leaf_of_row``. The learners that keep
+the rows ordered by leaf (one device: the serial learner and the mesh
+learner the benchmark's cells run) order the in-bag rows alone
+(treelearner/grow.py ``make_root_state``), as the reference's learner
+is handed ``bag_data_indices``: every window reorder and every tile of
+the smaller children's histograms visits rows that carry weight, the
+smaller child is the one with fewer in-bag rows, and ``gh``'s last
+channel, and so ``left_total_count``/``right_total_count``, counts the
+in-bag rows too. Only the root's histogram pass still reads every row.
+The learners that histogram the masked row space (a mesh across
+devices, the voting learner, the out-of-core learner) visit the
+out-of-bag rows, whose gradient and hessian are zero, and count every
+row in the last channel. GOSS's own sampling (top 20% + 10% of the
+rest at 1,000,000 x 968: the benchmark's cell ``bosch-train-goss``,
+PERF.md) is 1.5 ms of an iteration on a TPU v5e, 1.4 of them
+``lax.top_k``, which is a full sort of the weights there (and 24 s of
+compiling). The sampling is named ``obs_goss`` on the device clock,
+and while the stage timer is on ``sample/goss_trees`` counts the trees
+of the iterations GOSS sampled (docs/OBSERVABILITY.md). Plain bagging's draw is ``obs_bag`` there, its
 trees ``sample/bag_trees`` and its draws ``sample/bag_draws`` (one per
-``bagging_freq`` iterations); with ``feature_fraction`` 0.8 beside
-``bagging_fraction`` 0.8 (the cell ``bosch-train-subsample``, PR 37) 59%
-of the row-features the smaller children's passes visit carry weight.
+``bagging_freq`` iterations).
 
 Draws happen ON DEVICE, keyed by ``fold_in(PRNGKey(bagging_seed),
 draw_index)`` where the draw index is a pure function of the iteration

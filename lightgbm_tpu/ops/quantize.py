@@ -136,7 +136,7 @@ def published_levels(bins: int, stochastic: bool, bits: int,
     return QuantLevels(bins // 2, bins, 0, bool(stochastic))
 
 
-def _quantize_gh(grad, hess, ind, key, qmax, dtype) -> tuple:
+def _quantize_gh(grad, hess, ind, key, qmax, dtype, ordered=None) -> tuple:
     """Discretize per-row (grad, hess) to signed integers.
 
     Parameters
@@ -148,8 +148,11 @@ def _quantize_gh(grad, hess, ind, key, qmax, dtype) -> tuple:
     qmax : STATIC QuantLevels, or the target magnitude
         (effective_quant_max) of the symmetric scheme as an int
     dtype : STATIC row dtype (quant_dtype)
+    ordered : f32[N] last channel, the rows the grower orders by leaf
+        (``grow.make_root_state``'s bag where the learner compacts over
+        one); 1 for every row where None
 
-    Returns (gh int[N, 4] = (q_grad, q_hess, in-bag, 1),
+    Returns (gh int[N, 4] = (q_grad, q_hess, in-bag, ordered),
              qscale f32[2] = (g_scale, h_scale)).
     """
     lv = qmax if isinstance(qmax, QuantLevels) else symmetric_levels(qmax)
@@ -169,8 +172,9 @@ def _quantize_gh(grad, hess, ind, key, qmax, dtype) -> tuple:
                       jnp.float32(lv.grad))
         qh = jnp.clip(jnp.floor(h / hs + uh), jnp.float32(lv.hess_lo),
                       jnp.float32(lv.hess))
-        gh = jnp.stack([qg, qh, ind,
-                        jnp.ones_like(ind)], axis=1).astype(dtype)
+        if ordered is None:
+            ordered = jnp.ones_like(ind)
+        gh = jnp.stack([qg, qh, ind, ordered], axis=1).astype(dtype)
         return gh, jnp.stack([gs, hs]).astype(jnp.float32)
 
 

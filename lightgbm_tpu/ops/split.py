@@ -142,9 +142,15 @@ class SplitInfo(NamedTuple):
     (src/treelearner/split_info.hpp:22).
 
     ``*_count`` are in-bag row counts (what min_data_in_leaf and leaf_count
-    use, matching the reference under bagging); ``*_total_count`` count every
-    partitioned row including out-of-bag ones — the learner sizes its row
-    compaction buffers with these. For categorical winners
+    use, matching the reference under bagging); ``*_total_count`` count the
+    rows of ``gh``'s last channel: in the learners that keep the rows
+    ordered by leaf (one device), the rows the grower orders, which under a
+    bag are the in-bag rows alone (``treelearner/grow.py``
+    ``make_root_state``), so the smaller child is the one with fewer in-bag
+    rows; in those that do not (a mesh across devices, the voting learner,
+    the out-of-core ``treelearner/sharded.py``), every partitioned row
+    including out-of-bag ones, by which the out-of-core learner sizes its
+    row gathers. For categorical winners
     (``is_categorical``), ``cat_mask`` is the bool[B] set of bins routed
     left (the device analogue of the reference's ``cat_threshold`` bin
     list)."""

@@ -329,6 +329,14 @@ class DataParallelTreeLearner(CapabilityMixin):
         one device. Across a mesh it would be a global reshuffle."""
         return self.mesh.devices.size == 1
 
+    def _ordered_bag(self, bag):
+        """What the staged ``gh`` carries as its last channel, and so
+        the rows the grower orders by leaf (``grow.make_root_state``):
+        the tree's bag where the learner compacts; None, for 1 on every
+        row, where it histograms the masked row space or the tree has
+        no bag."""
+        return bag if self._compacts() else None
+
     def _mesh_split_body(self, bins, bins_fm, state: GrowState,
                          rec: SplitRecord, leaf, new_leaf, valid, mask_left,
                          mask_right, rand_seed=0, extra_trees=None,
@@ -628,11 +636,14 @@ class DataParallelTreeLearner(CapabilityMixin):
         return self.max_depth <= 0 or depth < self.max_depth
 
     def _make_gh(self, grad, hess, bag) -> jnp.ndarray:
-        """[N] grad/hess (+bag) → padded sharded [R, 4] gh matrix."""
+        """[N] grad/hess (+bag) → padded sharded [R, 4] gh matrix, its
+        last channel ``_ordered_bag``'s rows."""
         pad_n = self.R - self.N
-        ind = jnp.ones(self.N, dtype=jnp.float32) if bag is None else bag
+        ones = jnp.ones(self.N, dtype=jnp.float32)
+        ind = ones if bag is None else bag
+        ordered = self._ordered_bag(bag)
         gh = jnp.stack([grad * ind, hess * ind, ind,
-                        jnp.ones(self.N, dtype=jnp.float32)], axis=1)
+                        ones if ordered is None else ordered], axis=1)
         if pad_n:
             gh = jnp.concatenate(
                 [gh, jnp.zeros((pad_n, 4), dtype=jnp.float32)], axis=0)
@@ -645,7 +656,8 @@ class DataParallelTreeLearner(CapabilityMixin):
         rows. Returns (gh int[R, 4] sharded, qscale f32[2] replicated)."""
         ind = jnp.ones(self.N, dtype=jnp.float32) if bag is None else bag
         gh, qscale = self._quantize_stage(grad, hess, ind,
-                                          self._tree_idx + 1)
+                                          self._tree_idx + 1,
+                                          self._ordered_bag(bag))
         pad_n = self.R - self.N
         if pad_n:
             gh = jnp.concatenate(
@@ -723,7 +735,8 @@ class DataParallelTreeLearner(CapabilityMixin):
 
     def _count_hist_rows(self, recs_h, applied: int) -> None:
         """``grow/hist_rows_needed``: rows of the smaller child of each
-        applied split, which a histogram has to visit;
+        applied split, which a histogram has to visit (its in-bag rows
+        where the learner orders the bag's alone, ``_ordered_bag``);
         ``grow/hist_rows_bucketed``: rows the learner's passes visited
         for them; ``grow/hist_rows_kernel``: the rows of those passes
         and of the root's that went through the Pallas kernel (all or
@@ -732,7 +745,8 @@ class DataParallelTreeLearner(CapabilityMixin):
         as float32 rows, in three bf16 pieces (all where the rows are
         not quantized); ``grow/hist_rows_in_bag``: those of the smaller
         child's rows that are in the bag (all of them where nothing is
-        sampled), the only ones that carry weight."""
+        sampled or where the learner orders the bag's rows alone), the
+        only ones that carry weight."""
         left_total = recs_h.left_total_count[:applied]
         right_total = recs_h.right_total_count[:applied]
         small = np.minimum(left_total, right_total)
@@ -797,14 +811,17 @@ class DataParallelTreeLearner(CapabilityMixin):
 
     def _count_partition_rows(self, recs_h, applied: int) -> None:
         """Where the learner keeps the rows ordered by leaf:
-        ``grow/partition_parent_rows``, the rows of each applied split's
-        parent, which ``_partition_order`` has to reorder;
-        ``grow/partition_window_rows``: the windows it reordered for
-        them."""
+        ``grow/rows_ordered``, the rows the root's ``order`` holds (the
+        in-bag rows where the tree has a bag, ``_ordered_bag``), of
+        trees that split; ``grow/partition_parent_rows``, the rows of
+        each applied split's parent, which ``_partition_order`` has to
+        reorder; ``grow/partition_window_rows``: the windows it
+        reordered for them."""
         if not self._compacts():
             return
         parents = (recs_h.left_total_count[:applied]
                    + recs_h.right_total_count[:applied])
+        obs.inc("grow/rows_ordered", int(parents[:1].sum()))
         obs.inc("grow/partition_parent_rows", int(parents.sum()))
         obs.inc("grow/partition_window_rows",
                 int(_padded_to_ladder(parents, _window_sizes(self.R))))
@@ -845,7 +862,9 @@ class DataParallelTreeLearner(CapabilityMixin):
         if ind is None:
             gh = jnp.stack([grad, hess, ones, ones], axis=1)
         else:
-            gh = jnp.stack([grad * ind, hess * ind, ind, ones], axis=1)
+            ordered = self._ordered_bag(ind)
+            gh = jnp.stack([grad * ind, hess * ind, ind,
+                            ones if ordered is None else ordered], axis=1)
         if self.R - self.N:
             gh = jnp.concatenate(
                 [gh, jnp.zeros((self.R - self.N, 4), dtype=jnp.float32)],
@@ -866,7 +885,8 @@ class DataParallelTreeLearner(CapabilityMixin):
         if ind is None:
             ind = jnp.ones(self.N, dtype=jnp.float32)
         gh, qscale = barrier(_quantize_gh(grad, hess, ind, key,
-                                          self._qmax, self._qdtype))
+                                          self._qmax, self._qdtype,
+                                          self._ordered_bag(ind)))
         if self.R - self.N:
             gh = jnp.concatenate(
                 [gh, jnp.zeros((self.R - self.N, 4), dtype=gh.dtype)],

@@ -176,8 +176,10 @@ class CapabilityMixin:
         self._quant_ctr = dev_u32(0)
         self._quant_ctr_host = 0
 
-    def _quantize_stage(self, grad, hess, ind, tree_no: int):
-        """Discretize one tree's (grad, hess, in-bag) to integer rows.
+    def _quantize_stage(self, grad, hess, ind, tree_no: int,
+                        ordered=None):
+        """Discretize one tree's (grad, hess, in-bag) to integer rows
+        (``ordered``: the rows the grower orders, ``_quantize_gh``).
         The draw runs on the UNPADDED [N] vectors with a per-tree
         fold-in key, so learners with different row/feature padding
         (serial pads rows to 4096s, meshes to the device count) produce
@@ -196,7 +198,7 @@ class CapabilityMixin:
             "(%d != %d)" % (self._quant_ctr_host, tree_no)
         self._count_discretized(1, int(grad.shape[0]))
         return quantize_gh(grad, hess, ind, key, self._qmax,
-                           self._qdtype)
+                           self._qdtype, ordered)
 
     @staticmethod
     def _count_discretized(trees: int, rows: int) -> None:

@@ -26,7 +26,11 @@ handled as:
   ``leaf_begin_``, ``leaf_count_``). A split reorders its parent's
   segment only: the smallest window of a static ladder
   (``_window_sizes``) that holds the parent is sliced out, stably
-  partitioned into left rows then right rows, and written back.
+  partitioned into left rows then right rows, and written back. Where
+  the tree is grown over a bag (GOSS, bagging), the segments hold the
+  in-bag rows alone, as the reference's learner is handed the bag's
+  ``bag_data_indices``: no window or tile visits a row whose gradient,
+  hessian and count are 0 (``make_root_state``).
 - **per-leaf row gather** (``_compact_child_hist``): the smaller
   child's rows, a slice of ``order`` and so in ascending order, are
   histogrammed a tile at a time by a loop whose trip count is the
@@ -64,7 +68,7 @@ class GrowState(NamedTuple):
     """Device-resident per-tree state (the analogue of the CUDA learner's
     CUDALeafSplits + histogram + partition buffers)."""
     leaf_of_row: jnp.ndarray      # [R] i32 (R = N+1; last row is a dummy, -1)
-    gh: jnp.ndarray               # [R, 4] f32 (grad, hess, in-bag, total=1)
+    gh: jnp.ndarray               # [R, 4] (grad, hess, in-bag, ordered)
     hists: jnp.ndarray            # [L, F, B, 4] f32
     leaf_depth: jnp.ndarray       # [L] i32 — device-side max_depth gating
     # Per-leaf best-split candidates (SplitInfo fields, array-of-struct):
@@ -195,17 +199,27 @@ def make_root_state(gh, hist, leaf_of_row, info, L: int, F: int, B: int,
     voting learner re-votes per leaf instead of subtracting).
 
     ``ordered`` (the learner compacts) adds the rows ordered by leaf:
-    ``order[:R]`` is leaf 0's rows, ascending, then the pad rows (leaf
-    -1); ``order[R:]`` is W entries of row 0 that no segment owns, W
-    the largest window of ``_window_sizes``, there so that a window or
-    a tile sliced from inside any segment never passes the end
-    (``dynamic_slice`` would shift it left, and the rows would reach
-    the histogram at other positions)."""
+    ``order[:R]`` is leaf 0's rows, ascending, then the others (the pad
+    rows, leaf -1, and the rows out of the bag); ``order[R:]`` is W
+    entries of row 0 that no segment owns, W the largest window of
+    ``_window_sizes``, there so that a window or a tile sliced from
+    inside any segment never passes the end (``dynamic_slice`` would
+    shift it left, and the rows would reach the histogram at other
+    positions).
+
+    Leaf 0's rows are those of ``gh``'s last channel: every real row
+    where the tree has no bag, the in-bag rows alone where the learner
+    stages the bag's indicator there (GOSS, bagging), and so every
+    leaf's segment after it, while ``leaf_of_row`` still gives every
+    row its leaf for the score update. Every histogram of the tree (the
+    root's over all rows, a smaller child's over its segment, its
+    sibling's by subtraction) then counts in that channel the rows the
+    segments hold, and a record's ``*_total_count`` is that count."""
     hist_slots = hist_slots or L
     by_leaf = {}
     if ordered:
         R = leaf_of_row.shape[0]
-        on_root = leaf_of_row == 0
+        on_root = (leaf_of_row == 0) & (gh[:, 3] != 0)
         dest, n_root = _lefts_first(on_root, jnp.ones(R, dtype=bool))
         zl = jnp.zeros(L, dtype=jnp.int32)
         by_leaf = dict(

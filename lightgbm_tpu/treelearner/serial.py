@@ -63,15 +63,17 @@ from .grow import (GrowState, SplitRecord, _compact_child_hist,
 
 @functools.lru_cache(maxsize=None)
 def _stage_gh_fn_cached(R: int):
-    """One fused dispatch staging (grad, hess, ind) → padded [R, 4] gh.
-    The former eager jnp.ones/stack/concatenate chain launched ~5 tiny
-    dispatches per tree and performed implicit scalar transfers (each
-    fill constant became a device buffer per call) — the transfer-guard
-    sanitizer test pins this staging transfer-free."""
-    def stage(grad, hess, ind):
+    """One fused dispatch staging (grad, hess, ind, ordered) → padded
+    [R, 4] gh, ``ordered`` the rows the grower orders by leaf (the bag
+    where the learner compacts, ``grow.make_root_state``; ones where it
+    does not). The former eager jnp.ones/stack/concatenate chain
+    launched ~5 tiny dispatches per tree and performed implicit scalar
+    transfers (each fill constant became a device buffer per call) —
+    the transfer-guard sanitizer test pins this staging
+    transfer-free."""
+    def stage(grad, hess, ind, ordered):
         n = grad.shape[0]
-        gh = jnp.stack([grad * ind, hess * ind, ind,
-                        jnp.ones_like(ind)], axis=1)
+        gh = jnp.stack([grad * ind, hess * ind, ind, ordered], axis=1)
         return jnp.concatenate(
             [gh, jnp.zeros((R - n, 4), dtype=gh.dtype)], axis=0)
 
@@ -630,7 +632,8 @@ class SerialTreeLearner(CapabilityMixin):
               bag: Optional[jnp.ndarray] = None
               ) -> Tuple[Tree, jnp.ndarray]:
         """Grow one tree. ``grad``/``hess`` are f32[N] device arrays;
-        ``bag`` an optional f32[N] in-bag indicator (0/1). Returns the host
+        ``bag`` an optional f32[N] in-bag indicator (0/1), whose rows alone
+        the grower orders by leaf (``grow.make_root_state``). Returns the host
         Tree and the final [N] row→leaf assignment (device) for score
         updates (reference: GBDT::UpdateScore uses the learner's partition,
         src/boosting/gbdt.cpp:475)."""
@@ -638,14 +641,14 @@ class SerialTreeLearner(CapabilityMixin):
             ind = self._ones_ind if bag is None else bag
             if self._quantized:
                 gh, self._qscale = self._quantize_stage(
-                    grad, hess, ind, self._tree_idx + 1)
+                    grad, hess, ind, self._tree_idx + 1, bag)
                 gh = _pad_rows_fn_cached(self.R)(gh)
             else:
                 self._qscale = self._qs_ones
                 # one fused dispatch for stack+pad: the former eager
                 # jnp.ones/stack/concatenate chain performed implicit
                 # scalar transfers each tree (transfer-guard sanitizer)
-                gh = _stage_gh_fn_cached(self.R)(grad, hess, ind)
+                gh = _stage_gh_fn_cached(self.R)(grad, hess, ind, ind)
             # fencing mode blocks here so the staging cost lands in THIS
             # stage; sample/trace mode hands the output to the async
             # readiness drainer instead (no hot-path fence)

@@ -101,6 +101,25 @@ def _zero_hist_fn_cached(Fp: int, B: int, dtype_name: str):
 _sum_gh_fn = obs_compile.instrument_jit("sharded.sum_gh", sum_gh)
 
 
+def _smaller_is_left(rec):
+    """Whether the split's left child is the one histogrammed from its
+    rows, the other taken by subtraction: the child with fewer in-bag
+    rows, the choice of the learners that order the bag's rows alone
+    (their ``*_total_count`` is that count, ``grow.make_root_state``),
+    so that this learner subtracts the same sibling and its trees stay
+    theirs bit for bit. Here ``*_total_count`` counts every row of a
+    child, out-of-bag ones included, and sizes the gathers
+    (``_small_rows``)."""
+    return rec.left_count <= rec.right_count
+
+
+def _small_rows(rec_h) -> float:
+    """Rows of the child ``_smaller_is_left`` picks, every one of which
+    a shard's gather has to hold (host record)."""
+    return float(rec_h.left_total_count if _smaller_is_left(rec_h)
+                 else rec_h.right_total_count)
+
+
 @functools.lru_cache(maxsize=None)
 def _gh_seg_fn_cached(n_k: int, n_pad: int):
     """Slice one shard's [n_pad, 4] gh segment (trailing zero pad rows)
@@ -144,19 +163,19 @@ def _shard_step(shard_bins, leaf_seg, gh_seg, hist, rec, new_leaf, meta,
                 S: int):
     """One shard's slice of a split step: route the shard's rows of the
     split leaf left/right (the partition update of grow.py's
-    ``_split_step``, applied to this contiguous row segment), then gather the rows now
-    sitting on the SMALLER child and scatter them into the running
-    child histogram. Shard segments are disjoint contiguous row ranges,
+    ``_split_step``, applied to this contiguous row segment), then
+    gather the rows now sitting on the smaller child
+    (``_smaller_is_left``) and scatter them into the running child
+    histogram. Shard segments are disjoint contiguous row ranges,
     so sweeping them in order performs the identical per-row updates —
     and the identical ordered histogram adds — as the serial learner's
     full-array pass.
 
-    ``S`` is the STATIC gather width: a power-of-two bucket of the
-    smaller child's global row count (an upper bound on any shard's
-    share of it), chosen on the host, which steps this learner split
-    by split, to keep deep-tree steps from scanning all rows. Fill
-    rows hit the
-    shard's zero pad row (gh 0), so the bucket size changes compiled
+    ``S`` is the STATIC gather width: a power-of-two bucket of that
+    child's global row count (``_small_rows``; an upper bound on any
+    shard's share of it), chosen on the host, which steps this learner
+    split by split, to keep deep-tree steps from scanning all rows.
+    Fill rows hit the shard's zero pad row (gh 0), so the bucket size changes compiled
     variants, never values. ``rec`` comes through ``_partition_rec``:
     on data with no categorical feature its categorical fields are
     None and no table lookup is lowered."""
@@ -170,8 +189,7 @@ def _shard_step(shard_bins, leaf_seg, gh_seg, hist, rec, new_leaf, meta,
                          rec.cat_mask)
     on_leaf = leaf_seg == leaf
     leaf_seg = jnp.where(on_leaf & ~gl, new_leaf, leaf_seg)
-    smaller_is_left = rec.left_total_count <= rec.right_total_count
-    small_id = jnp.where(smaller_is_left, leaf, new_leaf)
+    small_id = jnp.where(_smaller_is_left(rec), leaf, new_leaf)
     (idx,) = jnp.nonzero(leaf_seg == small_id, size=S,
                          fill_value=n_pad - 1)
     hist = _accum_hist(hist, shard_bins[idx], gh_seg[idx])
@@ -196,7 +214,7 @@ def _finish_fn_cached(B: int, max_depth: int, extra_trees: bool,
     def finish(state, rec, new_leaf, hist_small, feature_mask,
                rand_seed, qscale, meta, params):
         leaf = rec.leaf
-        smaller_is_left = rec.left_total_count <= rec.right_total_count
+        smaller_is_left = _smaller_is_left(rec)
         hist_large = subtract_histogram(state.hists[leaf], hist_small)
         hist_left = jnp.where(smaller_is_left, hist_small, hist_large)
         hist_right = jnp.where(smaller_is_left, hist_large, hist_small)
@@ -300,8 +318,7 @@ def _shard_kstep(shard_bins, leaf_seg, gh_seg, hists, recs,
                              new_leaf_base + i, leaf_seg)
     for i in range(K):
         rec = _slot(recs, i)
-        smaller_is_left = rec.left_total_count <= rec.right_total_count
-        small_id = jnp.where(smaller_is_left, rec.leaf,
+        small_id = jnp.where(_smaller_is_left(rec), rec.leaf,
                              new_leaf_base + i)
         (idx,) = jnp.nonzero(leaf_seg == small_id, size=S,
                              fill_value=n_pad - 1)
@@ -373,8 +390,7 @@ def _kfinish_fn_cached(B: int, K: int, max_depth: int, extra_trees: bool,
             ok = alive & spec_valid[i] & is_next & rec_valid(rec)
             new_leaf = (new_leaf_base + i).astype(jnp.int32)
             leaf = rec.leaf
-            smaller_is_left = (rec.left_total_count
-                               <= rec.right_total_count)
+            smaller_is_left = _smaller_is_left(rec)
             hist_small = hists[i]
             hist_large = subtract_histogram(state.hists[leaf],
                                             hist_small)
@@ -572,7 +588,8 @@ class ShardedTreeLearner(CapabilityMixin):
                 gh = _pad_rows_fn_cached(self.R)(gh)
             else:
                 self._qscale = self._qs_ones
-                gh = _stage_gh_fn_cached(self.R)(grad, hess, ind)
+                gh = _stage_gh_fn_cached(self.R)(grad, hess, ind,
+                                                 self._ones_ind)
             obs.watch_ready("tree::stage_gh", gh)
         feature_mask = self._tree_feature_mask()
         tree = Tree(self.L)
@@ -657,8 +674,7 @@ class ShardedTreeLearner(CapabilityMixin):
         while next_leaf < self.L:
             if not record_is_valid(rec_h):
                 break
-            small_count = min(float(rec_h.left_total_count),
-                              float(rec_h.right_total_count))
+            small_count = _small_rows(rec_h)
             with obs.scope("tree::shard_sweep"):
                 hist_small = self._zero_hist()
                 new_leaf = dev_i32(next_leaf)
@@ -716,10 +732,7 @@ class ShardedTreeLearner(CapabilityMixin):
                 n_valid += 1
             if n_valid == 0:
                 break
-            small_max = max(
-                min(float(slots[i].left_total_count),
-                    float(slots[i].right_total_count))
-                for i in range(n_valid))
+            small_max = max(_small_rows(slots[i]) for i in range(n_valid))
             # explicit device staging of the round's control vectors
             # (transfer-guard discipline: one deliberate device_put
             # per round, never an implicit transfer)

@@ -197,9 +197,10 @@ def _counters():
 
 
 def _in_bag_rows_of_smaller_children(bst, X, k):
-    """In-bag rows of the child with fewer rows in all (ties: the left),
-    over the splits of tree ``k``: the tree's own in-bag counts, the sizes
-    in all from where every row lands."""
+    """``(in-bag rows, rows in all)`` of the child with fewer in-bag rows
+    (ties: the left), the one the grower histograms, over the splits of
+    tree ``k``: the tree's own in-bag counts, the sizes in all from where
+    every row lands."""
     tree = bst.inner.models[k]
     leaf_of_row = np.asarray(bst.predict(X, pred_leaf=True))[:, k]
     leaf_rows = np.bincount(leaf_of_row, minlength=tree.num_leaves)
@@ -212,12 +213,13 @@ def _in_bag_rows_of_smaller_children(bst, X, k):
         assert left[1] + right[1] == int(tree.internal_count[child])
         return left[0] + right[0], left[1] + right[1]
 
-    total = 0
+    in_bag = in_all = 0
     for node in range(tree.num_leaves - 1):
         left, right = (sizes(tree.left_child[node]),
                        sizes(tree.right_child[node]))
-        total += left[1] if left[0] <= right[0] else right[1]
-    return total
+        small = left if left[1] <= right[1] else right
+        in_bag, in_all = in_bag + small[1], in_all + small[0]
+    return in_bag, in_all
 
 
 def test_counters_follow_the_sampled_trees(timer_on):
@@ -232,10 +234,14 @@ def test_counters_follow_the_sampled_trees(timer_on):
     assert trees == SAMPLED
     assert in_bag == sum(int(bst.inner.models[k].internal_count[0])
                          for k in sampled)
-    assert hist_in_bag == sum(_in_bag_rows_of_smaller_children(bst, X, k)
-                              for k in range(WARM + SAMPLED))
-    # the passes visit the smaller child's rows in and out of the bag
-    assert 0.2 * needed < hist_in_bag < 0.8 * needed
+    small = [_in_bag_rows_of_smaller_children(bst, X, k)
+             for k in range(WARM + SAMPLED)]
+    assert hist_in_bag == sum(b for b, _ in small)
+    # the passes visit the smaller child's in-bag rows alone, the rows the
+    # grower orders, of which its rows in all hold a fifth to four fifths
+    assert hist_in_bag == needed
+    assert 0.2 * sum(a for _, a in small) < hist_in_bag \
+        < 0.8 * sum(a for _, a in small)
 
 
 @pytest.mark.parametrize("learner", sorted(LEARNERS))

@@ -168,22 +168,29 @@ def test_segments_hold_each_leafs_rows_after_a_tree(kind, mode):
     order = np.asarray(state.order)
     leaf_of_row = np.asarray(state.leaf_of_row)
     begin, count = np.asarray(state.seg_begin), np.asarray(state.seg_count)
+    # the rows the grower orders: the in-bag ones under a bag, every real
+    # row without (the pad rows carry 0 in every channel of gh)
+    in_bag = np.asarray(state.gh)[:, 2] != 0
+    assert (in_bag[:learner.N].all()) == (mode != "bagging")
+    assert not in_bag[learner.N:].any()
     np.testing.assert_array_equal(np.sort(order[:rows]), np.arange(rows))
     assert not order[rows:].any()
     assert (count[tree.num_leaves:] == 0).all()
     at = 0
-    # the segments tile the real rows; a split leaves its left child at
+    # the segments tile the ordered rows; a split leaves its left child at
     # the parent's begin, so the leaves are not in order of their number
     for leaf in np.argsort(begin[:tree.num_leaves], kind="stable"):
         assert begin[leaf] == at
         np.testing.assert_array_equal(
-            order[at:at + count[leaf]], np.flatnonzero(leaf_of_row == leaf))
+            order[at:at + count[leaf]],
+            np.flatnonzero((leaf_of_row == leaf) & in_bag))
         assert count[leaf] > 0
         at += count[leaf]
-    assert at == learner.N
-    # the pad rows stay behind every leaf
-    np.testing.assert_array_equal(order[at:rows],
-                                  np.flatnonzero(leaf_of_row == -1))
+    assert at == in_bag.sum()
+    # the rows out of the bag and the pad rows stay behind every leaf,
+    # though every real row has its leaf
+    assert (leaf_of_row[:learner.N] >= 0).all()
+    np.testing.assert_array_equal(order[at:rows], np.flatnonzero(~in_bag))
 
 
 @pytest.mark.parametrize("make", [
